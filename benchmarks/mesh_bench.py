@@ -27,13 +27,13 @@ OUT_PATH = os.path.join(os.path.dirname(os.path.dirname(
 
 
 def _ensure_virtual_devices(n: int) -> None:
-    """Append the device-count flag BEFORE jax initialises (a later
-    os.environ mutation silently no-ops once the backend exists)."""
+    """Append the host device-count flag BEFORE jax initialises (a later
+    os.environ mutation silently no-ops once the backend exists).  The flag
+    shapes only the CPU backend; the platform stays JAX's own choice."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={n}").strip()
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def run(smoke: bool = False, out_path: str = OUT_PATH,

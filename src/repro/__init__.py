@@ -1,20 +1,1 @@
-"""SPEC-RL reproduction package.
-
-One process-wide numerical contract is pinned here, at the root import
-every ``repro.*`` module shares, so it can never depend on WHICH submodule
-a given entry point happens to import:
-
-Partitionable threefry makes PRNG bit generation a pure function of
-(key, shape) regardless of how operands are sharded.  The legacy default
-derives bits from a device-layout-dependent global iota, so the same
-sampling call would return DIFFERENT tokens once its inputs carry a
-NamedSharding — silently breaking the token-identity contract between
-sharded and single-device rollouts (DESIGN.md §8, asserted in
-tests/distributed/test_mesh_rollout.py).  Flipping it uniformly at the
-package root also keeps single-device token streams identical across every
-entry point (engine-only scripts, serving, trainer, benches) instead of
-varying with the import graph.
-"""
-import jax
-
-jax.config.update("jax_threefry_partitionable", True)
+"""SPEC-RL reproduction package."""

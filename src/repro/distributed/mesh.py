@@ -9,11 +9,11 @@ serving stack, plus the helpers that place live arrays on the mesh:
 * batch rows over the ``data`` axis,
 * decode caches batch-over-``data`` and KV-heads-over-``model``.
 
-Everything degrades to single-device execution: ``MeshConfig.build()``
-returns ``None`` when the mesh is trivial (1×1) or the host exposes too few
-devices (unless ``require``), and every helper accepts ``mesh=None`` as a
-no-op.  Meshes may also lack an axis entirely (the per-data-shard serving
-submeshes carry only ``model``), so all axis lookups are presence-checked.
+A trivial (1×1) ``MeshConfig`` builds no mesh (``None``), and every helper
+accepts ``mesh=None`` as a no-op: that is the single-device path.  A mesh
+larger than the host raises.  Meshes may also lack an axis entirely (the
+per-data-shard serving submeshes carry only ``model``), so all axis lookups
+are presence-checked.
 """
 from __future__ import annotations
 
@@ -23,15 +23,21 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
 
 from .sharding import params_pspecs
 
-# NOTE: the partitionable-threefry flag this module's identity contract
-# relies on is pinned in repro/__init__.py — uniformly for every repro
-# entry point, not as a side effect of importing mesh support.
+
+def make_mesh(shape, names, devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto``: GSPMD propagates shardings
+    through plain jnp code (``jax.make_mesh`` defaults to ``Explicit``
+    axes, under which an unannotated gather such as the embedding lookup
+    is a type error)."""
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 @dataclass(frozen=True)
@@ -39,13 +45,12 @@ class MeshConfig:
     """Axis sizes for the runtime (data, model) mesh.
 
     ``build()`` materialises the mesh over the first ``data * model`` host
-    devices; a trivial (1, 1) config — or too few devices with
-    ``require=False`` — yields ``None``, the single-device fallback every
-    consumer treats as "run exactly the unsharded path".
+    devices, and raises when the host has fewer.  A trivial (1, 1) config
+    yields ``None``, which every consumer treats as "run exactly the
+    unsharded path".
     """
     data: int = 1
     model: int = 1
-    require: bool = False
 
     @property
     def size(self) -> int:
@@ -55,14 +60,12 @@ class MeshConfig:
         if self.size <= 1:
             return None
         if jax.device_count() < self.size:
-            if self.require:
-                raise RuntimeError(
-                    f"MeshConfig({self.data}x{self.model}) needs {self.size} "
-                    f"devices, found {jax.device_count()} (set "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count=N for "
-                    "virtual CPU devices)")
-            return None
-        return jax.make_mesh((self.data, self.model), ("data", "model"))
+            raise RuntimeError(
+                f"MeshConfig({self.data}x{self.model}) needs {self.size} "
+                f"devices, found {jax.device_count()} (set "
+                "XLA_FLAGS=--xla_force_host_platform_device_count=N for "
+                "virtual CPU devices)")
+        return make_mesh((self.data, self.model), ("data", "model"))
 
 
 # ------------------------------------------------------------------ axis info

@@ -22,8 +22,8 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -66,8 +66,8 @@ def model_axis(mesh: Mesh, *dims: int):
 
 def shard_map_call(mesh: Mesh, fn, in_specs, out_specs, *args):
     """One-shot shard_map application (per-shard shapes stay static)."""
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 # ------------------------------------------------------------ decode attention

@@ -75,7 +75,7 @@ def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, kpos_ref, q_ref,
         GT = q.shape[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kpos = kpos_ref[0].astype(jnp.int32)[None, :]    # (1, bk)
+        kpos = kpos_ref[0, 0].astype(jnp.int32)          # (1, bk)
         # sublane row r = g*T + t: query t of group g, at position qpos0 + t
         t_idx = jax.lax.broadcasted_iota(jnp.int32, (GT, block_k), 0) % T
         qpos = qpos0_ref[b] + t_idx
@@ -87,18 +87,28 @@ def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, kpos_ref, q_ref,
         s = jnp.where(mask, s, NEG_INF)
         m = jnp.max(s, axis=1, keepdims=True)            # (G*T, 1)
         p = jnp.where(mask, jnp.exp(s - m), 0.0)
-        m_ref[0, 0, 0] = m[:, 0]
-        l_ref[0, 0, 0] = jnp.sum(p, axis=1)
+        m_ref[0, 0, 0] = m
+        l_ref[0, 0, 0] = jnp.sum(p, axis=1, keepdims=True)
         acc_ref[0, 0, 0] = jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)    # (G*T, Dv)
+
+
+def _split_tiles(k_pos, nsplit: int, block: int):
+    """(B, nsplit * block) positions -> (B, nsplit, 1, block).
+
+    Each split's positions become one (1, block) tile whose last two dims
+    equal the array's, the block shape the TPU lowering accepts for any
+    block width (a (1, block) block over (B, S) is not (8, 128)-aligned)."""
+    return k_pos.astype(jnp.int32).reshape(k_pos.shape[0], nsplit, 1, block)
 
 
 def _combine(m, l, acc):
     """Second-stage split-K merge over axis 2 (the split axis).
 
-    m, l: (B, Hkv, nsplit, G*T); acc: (B, Hkv, nsplit, G*T, Dv).
+    m, l: (B, Hkv, nsplit, G*T, 1); acc: (B, Hkv, nsplit, G*T, Dv).
     Standard logsumexp rescale; fully-masked rows (every split neutral)
     come out exactly zero."""
+    m, l = m[..., 0], l[..., 0]
     m_glob = jnp.max(m, axis=2)                          # (B, Hkv, G*T)
     coef = jnp.exp(m - m_glob[:, :, None, :])
     l_tot = jnp.sum(coef * l, axis=2)                    # (B, Hkv, G*T)
@@ -155,20 +165,23 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
         return (jnp.where(live, table_ref[b, s], 0), h, 0, 0)
 
     def _kpos_block(b, h, s, len_ref, start_ref, *_):
-        return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0))
+        return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0),
+                0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
         grid=(B, Hkv, nb),
         in_specs=[
-            pl.BlockSpec((1, bs), _kpos_block),
+            pl.BlockSpec((1, 1, 1, bs), _kpos_block),
             pl.BlockSpec((1, 1, G * T, Dk), lambda b, h, s, *_: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, bs, Dk), _kv_block),
             pl.BlockSpec((1, 1, bs, Dv), _kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G * T), lambda b, h, s, *_: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, G * T), lambda b, h, s, *_: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, G * T, 1),
+                         lambda b, h, s, *_: (b, h, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, G * T, 1),
+                         lambda b, h, s, *_: (b, h, s, 0, 0)),
             pl.BlockSpec((1, 1, 1, G * T, Dv),
                          lambda b, h, s, *_: (b, h, s, 0, 0)),
         ],
@@ -178,14 +191,15 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
                           block_k=bs, T=T),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, nb, G * T), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, nb, G * T), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nb, G * T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nb, G * T, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, nb, G * T, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32), starts.astype(jnp.int32),
       q_pos0.astype(jnp.int32), q_len.astype(jnp.int32),
-      table.astype(jnp.int32), k_pos, qg, k_pool, v_pool)
+      table.astype(jnp.int32), _split_tiles(k_pos, nb, bs), qg, k_pool,
+      v_pool)
     out = _combine(m, l, acc)                            # (B, Hkv, G*T, Dv)
     return out.reshape(B, Hkv, G, T, Dv).reshape(B, Hq, T, Dv)
 
@@ -226,20 +240,23 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts, *,
                 0)
 
     def _kpos_block(b, h, s, len_ref, start_ref, *_):
-        return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0))
+        return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0),
+                0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B, Hkv, nsplit),
         in_specs=[
-            pl.BlockSpec((1, block_k), _kpos_block),
+            pl.BlockSpec((1, 1, 1, block_k), _kpos_block),
             pl.BlockSpec((1, 1, G * T, Dk), lambda b, h, s, *_: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_k, Dk), _kv_block),
             pl.BlockSpec((1, 1, block_k, Dv), _kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G * T), lambda b, h, s, *_: (b, h, s, 0)),
-            pl.BlockSpec((1, 1, 1, G * T), lambda b, h, s, *_: (b, h, s, 0)),
+            pl.BlockSpec((1, 1, 1, G * T, 1),
+                         lambda b, h, s, *_: (b, h, s, 0, 0)),
+            pl.BlockSpec((1, 1, 1, G * T, 1),
+                         lambda b, h, s, *_: (b, h, s, 0, 0)),
             pl.BlockSpec((1, 1, 1, G * T, Dv),
                          lambda b, h, s, *_: (b, h, s, 0, 0)),
         ],
@@ -249,12 +266,13 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts, *,
                           block_k=block_k, T=T),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((B, Hkv, nsplit, G * T), jnp.float32),
-            jax.ShapeDtypeStruct((B, Hkv, nsplit, G * T), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nsplit, G * T, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, Hkv, nsplit, G * T, 1), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, nsplit, G * T, Dv), jnp.float32),
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32), starts.astype(jnp.int32),
-      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32), k_pos, qg, k, v)
+      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32),
+      _split_tiles(k_pos, nsplit, block_k), qg, k, v)
     out = _combine(m, l, acc)                            # (B, Hkv, G*T, Dv)
     return out.reshape(B, Hkv, G, T, Dv).reshape(B, Hq, T, Dv)

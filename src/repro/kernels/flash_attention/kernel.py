@@ -41,8 +41,8 @@ def _flash_kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, o_ref,
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    qpos = qpos_ref[0].astype(jnp.int32)[:, None]       # (bq, 1)
-    kpos = kpos_ref[0].astype(jnp.int32)[None, :]       # (1, bk)
+    qpos = qpos_ref[0, 0]                               # (bq, 1)
+    kpos = kpos_ref[0, 0]                               # (1, bk)
     mask = kpos >= 0
     if causal:
         mask &= kpos <= qpos
@@ -89,16 +89,22 @@ def flash_attention_pallas(q, k, v, q_pos, k_pos, *, causal: bool = True,
         k_pos = jnp.pad(k_pos, ((0, 0), (0, pad_s)), constant_values=-1)
     Tp, Sp = q.shape[2], k.shape[2]
 
-    grid = (B, Hq, Tp // block_q, Sp // block_k)
+    nq, nk = Tp // block_q, Sp // block_k
+    grid = (B, Hq, nq, nk)
     scale = 1.0 / (D ** 0.5)
+    # one position tile per q / kv block, shaped so the block's last two
+    # dims equal the array's: (bq, 1) column / (1, bk) row tiles are
+    # accepted by the TPU lowering at any block size
+    q_pos = q_pos.astype(jnp.int32).reshape(B, nq, block_q, 1)
+    k_pos = k_pos.astype(jnp.int32).reshape(B, nk, 1, block_k)
 
     out = pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, window=window,
                           causal=causal),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, h, t, s: (b, t)),
-            pl.BlockSpec((1, block_k), lambda b, h, t, s: (b, s)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, t, s: (b, t, 0, 0)),
+            pl.BlockSpec((1, 1, 1, block_k), lambda b, h, t, s: (b, s, 0, 0)),
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, t, s: (b, h, t, 0)),
             pl.BlockSpec((1, 1, block_k, D),
                          lambda b, h, t, s, g=group: (b, h // g, s, 0)),

@@ -29,18 +29,28 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sout_ref,
     def _init():
         s_scr[...] = s0_ref[0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                    # (hd,)
+    u = u_ref[0].astype(jnp.float32)                    # (1, hd)
+    hd = u.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (hd, hd), 1))
+
+    def col(row):
+        # (1, hd) -> (hd, 1) by a masked lane reduction (exact: one nonzero
+        # term per row), which the TPU lowering supports where a transpose
+        # of a single row is not
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
     def body(i, _):
-        r = r_ref[0, i, :].astype(jnp.float32)          # (hd,)
-        k = k_ref[0, i, :].astype(jnp.float32)
-        v = v_ref[0, i, :].astype(jnp.float32)
-        w = w_ref[0, i, :].astype(jnp.float32)
+        # one timestep as (1, hd) rows: the TPU matmul needs 2-D operands
+        r = r_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        k = k_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        v = v_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
+        w = w_ref[0, pl.ds(i, 1), :].astype(jnp.float32)
         s = s_scr[...]                                  # (hd, hd)
-        bonus = jnp.sum(r * u * k)
-        y = r @ s + bonus * v                           # (hd,)
-        y_ref[0, i, :] = y.astype(y_ref.dtype)
-        s_scr[...] = w[:, None] * s + k[:, None] * v[None, :]
+        bonus = jnp.sum(r * u * k, axis=1, keepdims=True)
+        y = jax.lax.dot(r, s, preferred_element_type=jnp.float32) + bonus * v
+        y_ref[0, pl.ds(i, 1), :] = y.astype(y_ref.dtype)
+        s_scr[...] = col(w) * s + col(k) * v
         return 0
 
     jax.lax.fori_loop(0, block_t, body, 0)
@@ -78,7 +88,8 @@ def wkv_pallas(r, k, v, w, u, s0, *, block_t: int = 256,
             pl.BlockSpec((1, block_t, hd), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_t, hd), lambda b, t: (b, t, 0)),
             pl.BlockSpec((1, block_t, hd), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, hd), lambda b, t, H=H: (b % H, 0)),
+            # (H, 1, hd): the block's last two dims equal the array's
+            pl.BlockSpec((1, 1, hd), lambda b, t, H=H: (b % H, 0, 0)),
             pl.BlockSpec((1, hd, hd), lambda b, t: (b, 0, 0)),
         ],
         out_specs=[
@@ -91,5 +102,5 @@ def wkv_pallas(r, k, v, w, u, s0, *, block_t: int = 256,
         ],
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
+    )(r, k, v, w, u.reshape(H, 1, hd), s0)
     return y[:, :T, :], s_final

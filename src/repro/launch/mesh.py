@@ -5,25 +5,23 @@ touches jax device state.
 
 The *runtime* mesh — the one the trainer / rollout / serving stack actually
 executes on — is configured with ``repro.distributed.mesh.MeshConfig``
-(re-exported here), which falls back to single-device when the host cannot
-fit the axes (DESIGN.md §8).
+(re-exported here), which raises when the host cannot fit the axes
+(DESIGN.md §8).
 """
 from __future__ import annotations
 
-import jax
-
-from repro.distributed.mesh import MeshConfig  # noqa: F401  (re-export)
+from repro.distributed.mesh import MeshConfig, make_mesh  # noqa: F401
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(model: int = 2, data: int = 2):
     """Tiny mesh for unit tests (requires >= model*data host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))
 
 
 # TPU v5e hardware constants used by the roofline analysis (benchmarks/roofline.py)

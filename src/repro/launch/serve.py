@@ -4,8 +4,8 @@ stream, speculative-prefix admission, and latency/throughput stats
 the slot engine does not cover (recurrent state, encoder/vision extras).
 
     PYTHONPATH=src python -m repro.launch.serve --smoke
-    PYTHONPATH=src python -m repro.launch.serve --no-smoke --arch qwen3-1.7b \
-        --requests 64 --slots 8 --spec-prefix
+    PYTHONPATH=src python -m repro.launch.serve --no-smoke --arch qwen3-0.6b \
+        --requests 64 --slots 8 --spec-prefix   # full published width
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m repro.launch.serve --smoke \
         --mesh-data 2 --mesh-model 2      # one scheduler per data shard (§8)
@@ -26,6 +26,7 @@ from repro.core.cache import RolloutCache
 from repro.data.dataset import PromptDataset
 from repro.data.tokenizer import VOCAB_SIZE, decode
 from repro.distributed.mesh import MeshConfig, data_size, shard_params
+from repro.launch.compile_cache import enable_compile_cache
 from repro.engine.generate import GenerateConfig, generate
 from repro.models import model as M
 from repro.rewards.mathgen import MathTaskConfig, generate_problems
@@ -95,12 +96,18 @@ def serve_fixed(params, cfg, gen, reqs, prompt_width, slots):
     return outs, total
 
 
-def main(argv=None):
+def serve(argv=None) -> dict:
+    """Run the serving launcher.  Returns what it served: ``stats`` (the
+    engine's ``stats()``, None for the fixed-batch engine), ``responses``
+    ({request_id: Response}, or token arrays for the fixed-batch engine),
+    ``requests`` (the number submitted), ``request_list``, ``interrupted``,
+    and the ``engine``, ``cfg`` and ``params`` that served them."""
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", choices=sorted(ARCH_IDS), default="qwen3-0.6b")
     p.add_argument("--smoke", action=argparse.BooleanOptionalAction,
                    default=True,
-                   help="tiny reduced run (default); --no-smoke serves the "
+                   help="tiny reduced config and budget (default); "
+                        "--no-smoke serves the published config with the "
                         "full request/token budget")
     p.add_argument("--engine", choices=["auto", "slots", "fixed"],
                    default="auto")
@@ -124,9 +131,6 @@ def main(argv=None):
                    help="data shards — one slot scheduler per shard (§8)")
     p.add_argument("--mesh-model", type=int, default=1,
                    help="model-parallel axis size per shard")
-    p.add_argument("--require-mesh", action="store_true",
-                   help="fail instead of silently serving single-device "
-                        "when the host has fewer devices than the mesh")
     p.add_argument("--deadline-steps", type=int, default=0,
                    help="§10 per-request decode-step deadline (0 = none): "
                         "expired requests are reclaimed and retried once")
@@ -175,11 +179,14 @@ def main(argv=None):
                    help="paged KV block size in tokens (0 = config default)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     n_requests = args.requests or (8 if args.smoke else 64)
     max_new = args.max_new_tokens or (12 if args.smoke else 64)
 
-    cfg = get_config(args.arch).reduced(vocab_size=max(VOCAB_SIZE, 64))
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = cfg.reduced(vocab_size=max(VOCAB_SIZE, 64))
     if cfg.vocab_size < VOCAB_SIZE:
         cfg = cfg.replace(vocab_size=VOCAB_SIZE)
     if args.cache_layout != cfg.cache_layout:
@@ -188,8 +195,7 @@ def main(argv=None):
         cfg = cfg.replace(kv_block_size=args.kv_block_size)
     params = M.init_lm(jax.random.PRNGKey(args.seed), cfg)
     gen = GenerateConfig(max_new_tokens=max_new)
-    mesh = MeshConfig(data=args.mesh_data, model=args.mesh_model,
-                      require=args.require_mesh).build()
+    mesh = MeshConfig(data=args.mesh_data, model=args.mesh_model).build()
     if mesh is not None and data_size(mesh) <= 1:
         # model-only mesh: shard params here; the slot engine head-shards
         # its caches from the same mesh
@@ -256,7 +262,9 @@ def main(argv=None):
               f"{n_gen} tokens in {dt:.2f}s ({n_gen / max(dt, 1e-9):.0f} tok/s)")
         for i in range(min(n_requests, 4)):
             print(f"  req{i}: {decode(outs[i])!r}")
-        return 0
+        return {"stats": None, "responses": outs, "requests": n_requests,
+                "request_list": reqs, "interrupted": False, "engine": None,
+                "cfg": cfg, "params": params}
 
     drafts = None
 
@@ -433,6 +441,13 @@ def main(argv=None):
                              f"identical replay: {grew}")
         print(f"compile-stability: {sum(baseline.values())} compiles total, "
               "0 new on identical replay")
+    return {"stats": s, "responses": resps, "requests": n_requests,
+            "request_list": reqs, "interrupted": interrupted,
+            "engine": engine, "cfg": cfg, "params": params}
+
+
+def main(argv=None):
+    serve(argv)
     return 0
 
 

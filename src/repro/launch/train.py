@@ -3,13 +3,15 @@
 On real hardware this runs the RLVR trainer with parameters laid out by the
 partition rules over the production mesh; ``--mesh-data/--mesh-model`` build
 the runtime mesh (DESIGN.md §8) and the whole rollout → verify → train loop
-executes SPMD on it.  A (1, 1) mesh — or too few devices — falls back to
-single-device execution, token-identical by the §8 contract.  On a CPU
-container virtual devices come from
+executes SPMD on it.  A (1, 1) mesh is single-device execution,
+token-identical by the §8 contract; a mesh larger than the host raises.  On
+a CPU container virtual devices come from
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
         --smoke --steps 4          # reduced variant, CPU, single device
+    PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
+        --steps 4 --prompts-per-batch 2 --max-new-tokens 96   # full width
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m repro.launch.train --smoke --steps 4 \
         --mesh-data 4 --mesh-model 2
@@ -28,12 +30,13 @@ from repro.data.dataset import PromptDataset
 from repro.drafting import DraftConfig
 from repro.data.tokenizer import VOCAB_SIZE
 from repro.distributed.mesh import MeshConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.rewards.mathgen import MathTaskConfig, generate_problems
 from repro.rl.trainer import RLConfig, Trainer
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", choices=sorted(ARCH_IDS), default="qwen3-1.7b")
     p.add_argument("--algo", choices=["grpo", "ppo", "dapo"], default="grpo")
@@ -45,15 +48,16 @@ def main(argv=None):
                    help="reduced config (CPU-sized) of the same family")
     p.add_argument("--group-size", type=int, default=4)
     p.add_argument("--prompts-per-batch", type=int, default=4)
+    p.add_argument("--problems", type=int, default=16,
+                   help="size of the generated problem set; at most "
+                        "--prompts-per-batch makes every step revisit its "
+                        "prompts, so speculation engages from step 1")
     p.add_argument("--max-new-tokens", type=int, default=10)
     p.add_argument("--lr", type=float, default=5e-7)
     p.add_argument("--mesh-data", type=int, default=1,
                    help="data-parallel axis size (1 = off)")
     p.add_argument("--mesh-model", type=int, default=1,
                    help="model-parallel axis size (1 = off)")
-    p.add_argument("--require-mesh", action="store_true",
-                   help="fail instead of falling back when the host has "
-                        "fewer devices than the mesh needs")
     p.add_argument("--draft", type=int, default=0, metavar="K",
                    help="continuation draft engine (§9): draft up to K "
                         "tokens per decode forward from n-gram/sibling "
@@ -111,7 +115,50 @@ def main(argv=None):
                    help="serve Prometheus text exposition on "
                         "http://localhost:PORT/metrics during the run "
                         "(0 = off)")
-    args = p.parse_args(argv)
+    return p.parse_args(argv)
+
+
+def build_trainer(args: argparse.Namespace) -> Trainer:
+    """The trainer ``main`` runs, built from parsed arguments.  Tracer,
+    ledger and decision log are process-global: configure them first."""
+    cfg = get_config(args.arch)
+    if args.smoke:
+        cfg = cfg.reduced(vocab_size=max(VOCAB_SIZE, 64))
+    if cfg.vocab_size < VOCAB_SIZE:
+        cfg = cfg.replace(vocab_size=VOCAB_SIZE)
+
+    problems = generate_problems(MathTaskConfig(num_problems=args.problems,
+                                                max_operand=9))
+    ds = PromptDataset(problems, max_prompt_len=10)
+    rl = RLConfig(algo=args.algo, group_size=args.group_size,
+                  prompts_per_batch=args.prompts_per_batch,
+                  max_new_tokens=args.max_new_tokens,
+                  optim=AdamWConfig(lr=args.lr))
+    draft = DraftConfig(kind="ngram", draft_k=args.draft,
+                        adaptive=not args.draft_fixed) if args.draft > 0 \
+        else DraftConfig()
+    spec = SpecConfig(variant=args.variant, lenience=args.lenience,
+                      verify_impl="auto", draft=draft)
+    mesh_cfg = MeshConfig(data=args.mesh_data, model=args.mesh_model)
+    watchdog = None
+    if args.watchdog_dir:
+        from repro.rl.watchdog import TrainWatchdog, WatchdogConfig
+        watchdog = TrainWatchdog(WatchdogConfig(
+            checkpoint_dir=args.watchdog_dir,
+            snapshot_every=args.watchdog_every,
+            max_collect_time=args.watchdog_max_collect_time))
+    alerts = None
+    if args.alerts:
+        from repro.obs import get_tracer
+        from repro.obs.alerts import AlertManager
+        alerts = AlertManager(tracer=get_tracer())
+    return Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(0), mesh=mesh_cfg,
+                   watchdog=watchdog, alerts=alerts)
+
+
+def main(argv=None):
+    args = parse_args(argv)
+    enable_compile_cache()
 
     # §11: install the process-global tracer/registry BEFORE the trainer is
     # built so the rollout, drafting and trainer stage hooks all land in it
@@ -134,41 +181,9 @@ def main(argv=None):
         from repro.obs.ledger import DecisionLog
         configure(decisions=DecisionLog(args.decision_log, enabled=True))
 
-    cfg = get_config(args.arch)
-    if args.smoke:
-        cfg = cfg.reduced(vocab_size=max(VOCAB_SIZE, 64))
-    if cfg.vocab_size < VOCAB_SIZE:
-        cfg = cfg.replace(vocab_size=VOCAB_SIZE)
-
-    problems = generate_problems(MathTaskConfig(num_problems=16,
-                                                max_operand=9))
-    ds = PromptDataset(problems, max_prompt_len=10)
-    rl = RLConfig(algo=args.algo, group_size=args.group_size,
-                  prompts_per_batch=args.prompts_per_batch,
-                  max_new_tokens=args.max_new_tokens,
-                  optim=AdamWConfig(lr=args.lr))
-    draft = DraftConfig(kind="ngram", draft_k=args.draft,
-                        adaptive=not args.draft_fixed) if args.draft > 0 \
-        else DraftConfig()
-    spec = SpecConfig(variant=args.variant, lenience=args.lenience,
-                      verify_impl="auto", draft=draft)
-    mesh_cfg = MeshConfig(data=args.mesh_data, model=args.mesh_model,
-                          require=args.require_mesh)
-    watchdog = None
-    if args.watchdog_dir:
-        from repro.rl.watchdog import TrainWatchdog, WatchdogConfig
-        watchdog = TrainWatchdog(WatchdogConfig(
-            checkpoint_dir=args.watchdog_dir,
-            snapshot_every=args.watchdog_every,
-            max_collect_time=args.watchdog_max_collect_time))
-    alerts = None
-    if args.alerts:
-        from repro.obs import get_tracer
-        from repro.obs.alerts import AlertManager
-        alerts = AlertManager(tracer=tracer if tracer is not None
-                              else get_tracer())
-    tr = Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(0), mesh=mesh_cfg,
-                 watchdog=watchdog, alerts=alerts)
+    tr = build_trainer(args)
+    cfg = tr.cfg
+    alerts = tr.alerts
     metrics_srv = None
     if args.metrics:
         from repro.obs import get_registry

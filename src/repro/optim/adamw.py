@@ -39,8 +39,13 @@ def lr_at(cfg: AdamWConfig, step) -> jnp.ndarray:
 
 
 def init(params) -> Dict[str, Any]:
-    zeros = jax.tree.map(jnp.zeros_like, params)
-    return {"mu": zeros, "nu": jax.tree.map(jnp.zeros_like, params),
+    """Moments are float32 whatever the parameter dtype: ``update`` returns
+    them in float32, so any other start would give the update step a second
+    signature (and a second compile) on step 2."""
+    def zeros(p):
+        return jnp.zeros(jnp.shape(p), jnp.float32)
+    return {"mu": jax.tree.map(zeros, params),
+            "nu": jax.tree.map(zeros, params),
             "step": jnp.zeros((), jnp.int32)}
 
 

@@ -8,6 +8,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
+from repro.distributed.mesh import make_mesh
 from repro.launch.specs import INPUT_SHAPES, input_specs, shape_applicable
 from repro.launch.steps import (_ce_chunked, _ce_naive, _score_chunked,
                                 make_train_step, make_verify_step)
@@ -16,7 +17,7 @@ from repro.optim import adamw
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("shape_name", sorted(INPUT_SHAPES))

@@ -144,7 +144,8 @@ SUBPROC_SNIPPET = textwrap.dedent("""\
     from repro.launch import analysis
     from repro.optim import adamw
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    from repro.distributed.mesh import make_mesh
+    mesh = make_mesh((4, 2), ("data", "model"))
     cfg = get_config("qwen3-0.6b").reduced(num_layers=2, d_model=128,
                                             vocab_size=256)
     cfg = cfg.replace(dtype="float32", param_dtype="float32")
@@ -192,3 +193,12 @@ def test_small_mesh_lower_compile():
     assert out["flops"] > 0
     assert out["coll"] > 0
     assert out["mem"] > 0
+
+
+def test_mesh_larger_than_host_raises():
+    """Only a trivial mesh means one device; a mesh the host cannot hold
+    is an error, never a quiet single-device run."""
+    from repro.distributed.mesh import MeshConfig
+    assert MeshConfig().build() is None
+    with pytest.raises(RuntimeError, match="needs"):
+        MeshConfig(data=jax.device_count() + 1).build()

@@ -1,0 +1,119 @@
+"""Compile the Pallas kernels of the main path for a described TPU v5e at
+real widths (qwen3-0.6b: GQA 16/8, head dim 128; a 4096-slot cache; 16
+rows).  Nothing runs: the chip's compiler is asked whether it accepts each
+kernel, which interpret mode cannot tell (tile-aligned blocks, lowerable
+ops, VMEM).  Each test skips where no v5e topology can be described."""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.cache_gather.kernel import (cache_roll_pallas,
+                                               paged_gather_pallas)
+from repro.kernels.cache_slot_write.kernel import cache_slot_write_pallas
+from repro.kernels.decode_attention.kernel import (
+    decode_attention_pallas, paged_decode_attention_pallas)
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.rwkv6_wkv.kernel import wkv_pallas
+from repro.kernels.spec_verify.kernel import spec_verify_pallas
+
+CFG = get_config("qwen3-0.6b")
+B, HQ, HKV, D = 16, CFG.num_heads, CFG.num_kv_heads, CFG.resolved_head_dim
+S, BS = 4096, CFG.kv_block_size
+BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def make(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    return make
+
+
+def _compile(fn, *args):
+    """Compile for the described chip; the result must hold a Mosaic call."""
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("T", [1, 5])
+def test_decode_attention(spec, T):
+    """Dense decode: T=1 plain decode, T=5 a draft block of k+1 = 5."""
+    _compile(decode_attention_pallas,
+             spec((B, HQ, T, D), BF), spec((B, HKV, S, D), BF),
+             spec((B, HKV, S, D), BF), spec((B,), I32), spec((B,), I32),
+             spec((B, S), I32), spec((B,), I32), spec((B,), I32))
+
+
+def test_paged_decode_attention(spec):
+    nb = S // BS
+    NB = B * nb + 1
+    _compile(paged_decode_attention_pallas,
+             spec((B, HQ, 1, D), BF), spec((NB, HKV, BS, D), BF),
+             spec((NB, HKV, BS, D), BF), spec((B, nb), I32), spec((B,), I32),
+             spec((B,), I32), spec((B, S), I32), spec((B,), I32),
+             spec((B,), I32))
+
+
+def test_spec_verify(spec):
+    _compile(lambda a, b, u, n: spec_verify_pallas(a, b, u, n, 0.0),
+             spec((B, 512), F32), spec((B, 512), F32), spec((B, 512), F32),
+             spec((B,), I32))
+
+
+@pytest.mark.parametrize("seq", [S, 202])
+def test_cache_roll(spec, seq):
+    """One roll program per (row, KV head); 202 is a trainer cache width
+    (prompt plus budget) that is not a whole number of sublane tiles."""
+    R = B * HKV
+    _compile(cache_roll_pallas, spec((R, seq, D), BF), spec((R,), I32))
+
+
+def test_paged_gather(spec):
+    nb = S // BS
+    _compile(paged_gather_pallas, spec((B * nb + 1, HKV * BS, D), BF),
+             spec((B, nb), I32))
+
+
+def test_cache_slot_write(spec):
+    R = B * HKV
+    _compile(cache_slot_write_pallas, spec((R, S, D), BF),
+             spec((R // 2, S, D), BF), spec((R,), I32))
+
+
+def test_flash_attention(spec):
+    T = 1024
+    _compile(flash_attention_pallas,
+             spec((2, HQ, T, D), BF), spec((2, HKV, T, D), BF),
+             spec((2, HKV, T, D), BF), spec((2, T), I32), spec((2, T), I32))
+
+
+def test_rwkv6_wkv(spec):
+    """rwkv6-3b heads: 40 of size 64, two sequences of 512 steps."""
+    H, hd, T = 40, 64, 512
+    _compile(wkv_pallas, *[spec((2 * H, T, hd), F32)] * 4,
+             spec((H, hd), F32), spec((2 * H, hd, hd), F32))

@@ -15,10 +15,11 @@ from repro.rewards.mathgen import MathTaskConfig, generate_problems
 from repro.rl.trainer import RLConfig, Trainer
 
 
-def _make_trainer(algo, variant="spec", steps_cfg=None, seed=0):
+def _make_trainer(algo, variant="spec", steps_cfg=None, seed=0,
+                  dtype="float32"):
     cfg = ModelConfig(name="tiny", num_layers=2, d_model=64, num_heads=4,
                       num_kv_heads=2, d_ff=128, vocab_size=VOCAB_SIZE,
-                      max_seq_len=128)
+                      max_seq_len=128, dtype=dtype, param_dtype=dtype)
     problems = generate_problems(MathTaskConfig(num_problems=8, max_operand=4))
     ds = PromptDataset(problems, max_prompt_len=10)
     rl = RLConfig(algo=algo, group_size=2, prompts_per_batch=4,
@@ -51,6 +52,18 @@ def test_spec_rl_reduces_generated_tokens():
         tr_spec.train_step()
         tr_off.train_step()
     assert tr_spec.total_generated_tokens < tr_off.total_generated_tokens
+
+
+def test_update_step_compiles_once_with_bf16_params():
+    """AdamW moments start in float32, the dtype the update returns them
+    in, so the update step keeps one signature from its first step on."""
+    from repro.obs.alerts import jit_cache_size
+    from repro.rl.trainer import _update_actor
+    tr = _make_trainer("grpo", dtype="bfloat16")
+    before = jit_cache_size(_update_actor)
+    for _ in range(2):
+        assert np.isfinite(tr.train_step()["loss"])
+    assert jit_cache_size(_update_actor) - before == 1
 
 
 def test_kl_ref_tracked_for_grpo():
