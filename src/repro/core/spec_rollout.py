@@ -161,29 +161,18 @@ def use_drafting(cfg: ModelConfig, spec: SpecConfig, model_kwargs) -> bool:
     return spec.draft.enabled and M.supports_drafting(cfg, model_kwargs)
 
 
-def _emit_rollout_obs(spec, metrics, t0, stages, n=None):
-    """§11 per-epoch rollout telemetry: stage spans on the 'rollout' lane
-    plus registry histograms/counters for the paper's headline diagnostics
-    (reuse length, acceptance, lenience).  Pure host side — the stage
-    endpoints reuse the perf_counter stamps the metrics dict already took
-    at existing block_until_ready boundaries, so with the default
-    NULL_TRACER and an idle registry this adds no syncs and no clock reads
-    beyond a few dict ops."""
-    from repro.obs import get_registry, get_tracer
-    tr = get_tracer()
+def _emit_rollout_obs(spec, metrics, stages, n=None):
+    """§11 per-epoch rollout telemetry: registry histograms/counters for
+    the stage durations and the paper's headline diagnostics (reuse length,
+    acceptance, lenience).  The stage durations are the perf_counter stamps
+    the metrics dict already took at existing block_until_ready boundaries,
+    so with an idle registry this adds no syncs and no clock reads beyond
+    a few dict ops.  The stage spans are scoped around the stages."""
+    from repro.obs import get_registry
     reg = get_registry()
     step = int(metrics.get("step", 0))
-    t_end = max((ts + dur) for _, ts, dur in stages)
-    if tr.enabled:
-        tr.complete("rollout", "rollout", t0, t_end, cat="rollout",
-                    step=step, n_reused=metrics.get("n_reused", 0),
-                    accept_rate=metrics.get("accept_rate", 0.0))
-        for name, ts, dur in stages:
-            tr.complete(name, "rollout", ts, ts + dur, cat="rollout",
-                        step=step)
-    for name, ts, dur in stages:
+    for name, dur in stages:
         reg.observe(f"rollout.{name}_s", dur)
-    reg.observe("rollout.step_s", t_end - t0)
     reg.observe("rollout.accept_rate", metrics.get("accept_rate", 0.0))
     reg.set("rollout.lenience", float(spec.lenience)
             if math.isfinite(spec.lenience) else 0.0)
@@ -259,14 +248,53 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
     the data axes, params are expected pre-sharded by the caller, and every
     device stage — verify, compact, resume/generate — runs the same SPMD
     program, so the output is token-identical to the single-device path.
+
+    Observability (DESIGN.md §11): the whole call is the ``rollout.rollout``
+    span and ``metrics["rollout_time"]``; its stages are child spans
+    (``cache_get``, ``verify``, ``compact``, ``decode`` or ``generate``,
+    ``assembly``, ``cache_put``, ``to_host``), each device stage ending at
+    a sync on its outputs.  ``metrics["decode_steps"]`` is the decode
+    loop's trip count.
     """
     assert spec.variant in VARIANTS, spec.variant
-    if spec.backfill == "slots":
-        from repro.serving.rl_adapter import rollout_via_slots
-        return rollout_via_slots(params, cfg, gen, spec, prompts, prompt_mask,
-                                 prompt_ids, cache, key, step, mesh=mesh,
-                                 **model_kwargs)
-    assert spec.backfill == "none", spec.backfill
+    from repro.obs import get_registry, get_tracer
+    t0 = time.perf_counter()
+    with get_tracer().span("rollout", "rollout", cat="rollout", step=step):
+        if spec.backfill == "slots":
+            from repro.serving.rl_adapter import rollout_via_slots
+            rb = rollout_via_slots(params, cfg, gen, spec, prompts,
+                                   prompt_mask, prompt_ids, cache, key, step,
+                                   mesh=mesh, **model_kwargs)
+        else:
+            assert spec.backfill == "none", spec.backfill
+            rb = _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask,
+                                prompt_ids, cache, key, step, mesh,
+                                model_kwargs)
+        rollout_time = time.perf_counter() - t0
+    rb.metrics["rollout_time"] = rollout_time
+    reg = get_registry()
+    reg.observe("rollout.step_s", rollout_time)
+    reg.inc("rollout.decode_steps", rb.metrics["decode_steps"])
+    return rb
+
+
+def _to_host(prompts, prompt_mask, resp, resp_mask, lp, length, metrics):
+    from repro.obs import get_tracer
+    with get_tracer().span("to_host", "rollout", cat="rollout"):
+        return RolloutBatch(
+            prompt=np.asarray(prompts), prompt_mask=np.asarray(prompt_mask),
+            response=np.asarray(resp), response_mask=np.asarray(resp_mask),
+            behaviour_logprobs=np.asarray(lp), length=np.asarray(length),
+            metrics=metrics)
+
+
+def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
+                   cache, key, step, mesh, model_kwargs) -> RolloutBatch:
+    """``rollout`` on the fixed decode batch (every ``backfill='none'``
+    path): fresh ``generate``, or verify → compact → resume / re-prefill."""
+    from repro.obs import get_ledger, get_tracer
+    from repro.obs.ledger import FRESH, REUSED_PREFIX
+    tr = get_tracer()
     if mesh is not None:
         from repro.distributed.mesh import shard_batch
         prompts, prompt_mask = shard_batch(mesh, (jnp.asarray(prompts),
@@ -275,15 +303,14 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
             key = shard_batch(mesh, key)
     B, P = prompts.shape
     N = gen.max_new_tokens
-    t0 = time.perf_counter()
     metrics: Dict[str, float] = {"step": step}
-    from repro.obs import get_ledger
-    from repro.obs.ledger import FRESH, REUSED_PREFIX
     led = get_ledger()
 
     use_cache = spec.variant != "off" and cache is not None
-    drafts = cache.batch_get(prompt_ids, N, spec.cache_lag) if use_cache else None
-    have_drafts = use_cache and int(drafts["draft_len"].sum()) > 0
+    with tr.span("cache_get", "rollout", cat="rollout"):
+        drafts = cache.batch_get(prompt_ids, N, spec.cache_lag) \
+            if use_cache else None
+        have_drafts = use_cache and int(drafts["draft_len"].sum()) > 0
 
     drafting = use_drafting(cfg, spec, model_kwargs)
 
@@ -292,37 +319,45 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
         rows = p_np = None
         if led.enabled:
             rows, p_np = _ledger_rows(led, B, prompt_mask)
-        if drafting:
-            from repro.drafting import drafted_generate
-            corpus = cache.batch_siblings(prompt_ids, spec.cache_lag) \
-                if use_cache else None
-            # bind the rollout's rows so _DraftLoop's per-macro-step
-            # provenance appends land on them instead of fresh rows
-            if rows is not None:
-                led.bind(rows)
-            try:
-                out = drafted_generate(params, cfg, gen, prompts, prompt_mask,
-                                       sub, spec.draft, corpus=corpus,
-                                       verify_impl=spec.verify_impl, mesh=mesh)
-            finally:
+        with tr.span("generate", "rollout", cat="rollout"):
+            tg0 = time.perf_counter()
+            if drafting:
+                from repro.drafting import drafted_generate
+                corpus = cache.batch_siblings(prompt_ids, spec.cache_lag) \
+                    if use_cache else None
+                # bind the rollout's rows so _DraftLoop's per-macro-step
+                # provenance appends land on them instead of fresh rows
                 if rows is not None:
-                    led.unbind()
-        else:
-            out = _vanilla(params, cfg, gen, prompts, prompt_mask, sub,
-                           model_kwargs, mesh=mesh)
-        resp, lp, length = out["tokens"], out["logprobs"], out["length"]
-        resp_mask = jnp.arange(N)[None, :] < length[:, None]
-        rollout_time = time.perf_counter() - t0
+                    led.bind(rows)
+                try:
+                    out = drafted_generate(params, cfg, gen, prompts,
+                                           prompt_mask, sub, spec.draft,
+                                           corpus=corpus,
+                                           verify_impl=spec.verify_impl,
+                                           mesh=mesh)
+                finally:
+                    if rows is not None:
+                        led.unbind()
+            else:
+                out = _vanilla(params, cfg, gen, prompts, prompt_mask, sub,
+                               model_kwargs, mesh=mesh)
+            resp, lp, length = out["tokens"], out["logprobs"], out["length"]
+            # dispatched before the sync, so its launch hides behind the loop
+            resp_mask = jnp.arange(N)[None, :] < length[:, None]
+            # the loop's scalar outputs, read in one transfer: the sync that
+            # ends the stage (a program's outputs are ready together)
+            n_generated, decode_steps = jax.device_get(
+                (out["n_generated"], out["steps"]))
+            decode_time = time.perf_counter() - tg0
         metrics.update(
-            n_generated=int(out["n_generated"]), n_reused=0,
+            n_generated=int(n_generated),
+            decode_steps=int(decode_steps), n_reused=0,
             verified_prefix_mean=0.0, full_reuse_ratio=0.0,
             accept_rate=0.0, draft_coverage=0.0,
-            verify_time=0.0, rollout_time=rollout_time,
-            assembly_time=0.0, compact_time=0.0, decode_time=rollout_time,
-            one_pass=0.0, prefill_passes=1.0,
+            verify_time=0.0, assembly_time=0.0, compact_time=0.0,
+            decode_time=decode_time, one_pass=0.0, prefill_passes=1.0,
             **_draft_metrics(out.get("stats")))
-        _emit_rollout_obs(spec, metrics, t0,
-                          [("generate", t0, rollout_time)])
+        _emit_rollout_obs(spec, metrics, [("generate", decode_time)])
         _update_cache(cache, prompt_ids, resp, lp, length, step, gen.eos_id)
         if rows is not None:
             len_np = np.asarray(length)
@@ -330,11 +365,8 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
                 if not drafting:   # drafted rows were filled by _DraftLoop
                     led.append(rows[b], FRESH, int(len_np[b]))
                 led.finalize(rows[b], int(p_np[b]) + int(len_np[b]))
-        return RolloutBatch(
-            prompt=np.asarray(prompts), prompt_mask=np.asarray(prompt_mask),
-            response=np.asarray(resp), response_mask=np.asarray(resp_mask),
-            behaviour_logprobs=np.asarray(lp), length=np.asarray(length),
-            metrics=metrics)
+        return _to_host(prompts, prompt_mask, resp, resp_mask, lp, length,
+                        metrics)
 
     draft_tokens = jnp.asarray(drafts["draft_tokens"])
     draft_lp = jnp.asarray(drafts["draft_logprobs"])
@@ -349,136 +381,153 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
     if led.enabled:
         led_rows, led_p = _ledger_rows(led, B, prompt_mask)
 
-    tv0 = time.perf_counter()
     if one_pass:
         # ---- fused path: ONE forward over prompt ⊕ draft -----------------
-        key, sub = split_key(key)
-        ver = verify_and_prefill(params, cfg, prompts, prompt_mask,
-                                 draft_tokens, draft_lp, draft_len, sub,
-                                 spec.log_lenience, temperature=gen.temperature,
-                                 top_p=gen.top_p, impl=spec.verify_impl,
-                                 mesh=mesh, **model_kwargs)
-        n = ver["n"]
-        prefix_lp = ver["lp_curr"]
-        accept_rate = float(ver["accept_rate"])
-        jax.block_until_ready(n)
-        verify_time = time.perf_counter() - tv0
+        with tr.span("verify", "rollout", cat="rollout"):
+            tv0 = time.perf_counter()
+            key, sub = split_key(key)
+            ver = verify_and_prefill(params, cfg, prompts, prompt_mask,
+                                     draft_tokens, draft_lp, draft_len, sub,
+                                     spec.log_lenience,
+                                     temperature=gen.temperature,
+                                     top_p=gen.top_p, impl=spec.verify_impl,
+                                     mesh=mesh, **model_kwargs)
+            n = ver["n"]
+            prefix_lp = ver["lp_curr"]
+            accept_rate = float(ver["accept_rate"])
+            jax.block_until_ready(n)
+            verify_time = time.perf_counter() - tv0
 
         # compact the caches to [prompt | draft[:n]], left-aligned at W
         W = P + N
-        tc0 = time.perf_counter()
-        p_len = jnp.sum(prompt_mask, axis=1).astype(jnp.int32)
-        caches = M.realign_decode_cache(cfg, ver["caches"],
-                                        (N - n).astype(jnp.int32),
-                                        p_len + n, W, impl=spec.compact_impl,
-                                        mesh=mesh)
-        jax.block_until_ready(jax.tree.leaves(caches)[0])
-        compact_time = time.perf_counter() - tc0
+        with tr.span("compact", "rollout", cat="rollout"):
+            tc0 = time.perf_counter()
+            p_len = jnp.sum(prompt_mask, axis=1).astype(jnp.int32)
+            caches = M.realign_decode_cache(cfg, ver["caches"],
+                                            (N - n).astype(jnp.int32),
+                                            p_len + n, W,
+                                            impl=spec.compact_impl, mesh=mesh)
+            # the decode stage's inputs, dispatched before the sync so their
+            # launch hides behind the rolls
+            full_reuse = (n == draft_len) & draft_eos
+            key, sub = split_key(key)
+            jax.block_until_ready(caches)       # every buffer's roll
+            compact_time = time.perf_counter() - tc0
 
         # resume decoding from the compacted cache — zero redundant prefill
-        full_reuse = (n == draft_len) & draft_eos
-        td0 = time.perf_counter()
-        key, sub = split_key(key)
-        if drafting:
-            # §9: draft the continuation too — the n-gram index is seeded
-            # with prompt ⊕ accepted prefix and the sibling corpus, so the
-            # decode loop keeps speculating past the verified prefix
-            from repro.drafting import drafted_resume
-            n_np = np.asarray(n)
-            mask_np = np.asarray(prompt_mask)
-            prompts_np = np.asarray(prompts)
-            dt_np = np.asarray(draft_tokens)
-            contexts = [np.concatenate([prompts_np[b][mask_np[b]],
-                                        dt_np[b, :int(n_np[b])]])
-                        for b in range(B)]
-            corpus = cache.batch_siblings(prompt_ids, spec.cache_lag)
-            # §14: the verified prefix is reused provenance; bind the rows
-            # so the drafted continuation extends them in place
-            if led_rows is not None:
-                for b in range(B):
-                    led.append(led_rows[b], REUSED_PREFIX, int(n_np[b]))
-                led.bind(led_rows)
-            try:
-                cont = drafted_resume(params, cfg, gen, caches,
-                                      ver["seed_logits"], p_len + n, W, sub,
-                                      spec.draft, contexts, corpus=corpus,
-                                      initial_done=full_reuse,
-                                      row_budget=N - n,
-                                      verify_impl=spec.verify_impl, mesh=mesh)
-            finally:
+        with tr.span("decode", "rollout", cat="rollout"):
+            td0 = time.perf_counter()
+            if drafting:
+                # §9: draft the continuation too — the n-gram index is
+                # seeded with prompt ⊕ accepted prefix and the sibling
+                # corpus, so the decode loop keeps speculating past the
+                # verified prefix
+                from repro.drafting import drafted_resume
+                n_np = np.asarray(n)
+                mask_np = np.asarray(prompt_mask)
+                prompts_np = np.asarray(prompts)
+                dt_np = np.asarray(draft_tokens)
+                contexts = [np.concatenate([prompts_np[b][mask_np[b]],
+                                            dt_np[b, :int(n_np[b])]])
+                            for b in range(B)]
+                corpus = cache.batch_siblings(prompt_ids, spec.cache_lag)
+                # §14: the verified prefix is reused provenance; bind the
+                # rows so the drafted continuation extends them in place
                 if led_rows is not None:
-                    led.unbind()
-        else:
-            cont = resume_from_cache(params, cfg, gen, caches,
-                                     ver["seed_logits"], p_len + n, W, sub,
-                                     initial_done=full_reuse,
-                                     row_budget=N - n, mesh=mesh,
-                                     **model_kwargs)
-        jax.block_until_ready(cont["tokens"])
-        decode_time = time.perf_counter() - td0
-        rollout_time = compact_time + decode_time
+                    for b in range(B):
+                        led.append(led_rows[b], REUSED_PREFIX, int(n_np[b]))
+                    led.bind(led_rows)
+                try:
+                    cont = drafted_resume(params, cfg, gen, caches,
+                                          ver["seed_logits"], p_len + n, W,
+                                          sub, spec.draft, contexts,
+                                          corpus=corpus,
+                                          initial_done=full_reuse,
+                                          row_budget=N - n,
+                                          verify_impl=spec.verify_impl,
+                                          mesh=mesh)
+                finally:
+                    if led_rows is not None:
+                        led.unbind()
+            else:
+                cont = resume_from_cache(params, cfg, gen, caches,
+                                         ver["seed_logits"], p_len + n, W,
+                                         sub, initial_done=full_reuse,
+                                         row_budget=N - n, mesh=mesh,
+                                         **model_kwargs)
+            jax.block_until_ready(cont["tokens"])
+            decode_time = time.perf_counter() - td0
         prefill_passes = 1.0
     else:
         # ---- two-pass path: rejection positions then re-prefill ----------
-        if spec.variant in ("spec", "delayed"):
-            key, sub = split_key(key)
-            ver = verify_drafts(params, cfg, prompts, prompt_mask, draft_tokens,
-                                draft_lp, draft_len, sub, spec.log_lenience,
-                                temperature=gen.temperature, top_p=gen.top_p,
-                                impl=spec.verify_impl, mesh=mesh,
-                                **model_kwargs)
-            n = ver["n"]
-            prefix_lp = ver["lp_curr"]      # current-policy probs (exact)
-            accept_rate = float(ver["accept_rate"])
-            prefill_passes = 2.0            # score fwd + continuation prefill
-        elif spec.variant == "random":
-            key, sub = split_key(key)
-            frac = (jax.vmap(lambda k: jax.random.uniform(k))(sub)
-                    if jnp.ndim(sub) == 2 else jax.random.uniform(sub, (B,)))
-            n = jnp.floor(frac * (draft_len + 1)).astype(jnp.int32)
-            n = jnp.minimum(n, draft_len)
-            prefix_lp = draft_lp            # stale behaviour probs (biased)
-            accept_rate = float(jnp.where(draft_len.sum() > 0,
-                                          n.sum() / jnp.maximum(draft_len.sum(), 1),
-                                          0.0))
-            prefill_passes = 1.0
-        else:  # full
-            n = draft_len
-            prefix_lp = draft_lp
-            accept_rate = 1.0
-            prefill_passes = 1.0
-        jax.block_until_ready(n)
-        verify_time = time.perf_counter() - tv0
+        with tr.span("verify", "rollout", cat="rollout"):
+            tv0 = time.perf_counter()
+            if spec.variant in ("spec", "delayed"):
+                key, sub = split_key(key)
+                ver = verify_drafts(params, cfg, prompts, prompt_mask,
+                                    draft_tokens, draft_lp, draft_len, sub,
+                                    spec.log_lenience,
+                                    temperature=gen.temperature,
+                                    top_p=gen.top_p, impl=spec.verify_impl,
+                                    mesh=mesh, **model_kwargs)
+                n = ver["n"]
+                prefix_lp = ver["lp_curr"]      # current-policy probs (exact)
+                accept_rate = float(ver["accept_rate"])
+                prefill_passes = 2.0            # score fwd + re-prefill
+            elif spec.variant == "random":
+                key, sub = split_key(key)
+                frac = (jax.vmap(lambda k: jax.random.uniform(k))(sub)
+                        if jnp.ndim(sub) == 2
+                        else jax.random.uniform(sub, (B,)))
+                n = jnp.floor(frac * (draft_len + 1)).astype(jnp.int32)
+                n = jnp.minimum(n, draft_len)
+                prefix_lp = draft_lp            # stale behaviour probs
+                accept_rate = float(jnp.where(
+                    draft_len.sum() > 0,
+                    n.sum() / jnp.maximum(draft_len.sum(), 1), 0.0))
+                prefill_passes = 1.0
+            else:  # full
+                n = draft_len
+                prefix_lp = draft_lp
+                accept_rate = 1.0
+                prefill_passes = 1.0
+            jax.block_until_ready(n)
+            verify_time = time.perf_counter() - tv0
 
         full_reuse = (n == draft_len) & draft_eos
-        tc0 = time.perf_counter()
-        j = jnp.arange(N, dtype=jnp.int32)[None, :]
-        prefix_mask = j < n[:, None]
-        combined = jnp.concatenate(
-            [prompts, jnp.where(prefix_mask, draft_tokens, gen.pad_id)], axis=1)
-        combined_mask = jnp.concatenate([prompt_mask, prefix_mask], axis=1)
-        align_impl = "gather" if spec.variant in ("spec", "delayed") else "roll"
-        aligned_tokens, aligned_mask = left_align(combined, combined_mask,
-                                                  impl=align_impl)
-        jax.block_until_ready(aligned_tokens)
-        compact_time = time.perf_counter() - tc0
+        with tr.span("compact", "rollout", cat="rollout"):
+            tc0 = time.perf_counter()
+            j = jnp.arange(N, dtype=jnp.int32)[None, :]
+            prefix_mask = j < n[:, None]
+            combined = jnp.concatenate(
+                [prompts, jnp.where(prefix_mask, draft_tokens, gen.pad_id)],
+                axis=1)
+            combined_mask = jnp.concatenate([prompt_mask, prefix_mask],
+                                            axis=1)
+            align_impl = "gather" if spec.variant in ("spec", "delayed") \
+                else "roll"
+            aligned_tokens, aligned_mask = left_align(combined, combined_mask,
+                                                      impl=align_impl)
+            jax.block_until_ready(aligned_tokens)
+            compact_time = time.perf_counter() - tc0
 
-        td0 = time.perf_counter()
-        key, sub = split_key(key)
-        cont = generate(params, cfg, gen, aligned_tokens, aligned_mask, sub,
-                        initial_done=full_reuse, row_budget=N - n, mesh=mesh,
-                        **model_kwargs)
-        jax.block_until_ready(cont["tokens"])
-        decode_time = time.perf_counter() - td0
-        rollout_time = compact_time + decode_time
+        with tr.span("decode", "rollout", cat="rollout"):
+            td0 = time.perf_counter()
+            key, sub = split_key(key)
+            cont = generate(params, cfg, gen, aligned_tokens, aligned_mask,
+                            sub, initial_done=full_reuse, row_budget=N - n,
+                            mesh=mesh, **model_kwargs)
+            jax.block_until_ready(cont["tokens"])
+            decode_time = time.perf_counter() - td0
 
     # ---- assembly ----------------------------------------------------------
-    ta0 = time.perf_counter()
-    resp, lp, resp_mask, length = assemble(
-        draft_tokens, prefix_lp, n, cont["tokens"], cont["logprobs"],
-        cont["length"], pad_id=gen.pad_id)
-    jax.block_until_ready(resp)
-    assembly_time = time.perf_counter() - ta0
+    with tr.span("assembly", "rollout", cat="rollout"):
+        ta0 = time.perf_counter()
+        resp, lp, resp_mask, length = assemble(
+            draft_tokens, prefix_lp, n, cont["tokens"], cont["logprobs"],
+            cont["length"], pad_id=gen.pad_id)
+        jax.block_until_ready(resp)
+        assembly_time = time.perf_counter() - ta0
 
     _update_cache(cache, prompt_ids, resp, lp, length, step, gen.eos_id)
 
@@ -493,35 +542,34 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
                            int(len_fin[b]) - int(n_fin[b]))
             led.finalize(led_rows[b], int(led_p[b]) + int(len_fin[b]))
 
+    n_generated, decode_steps = jax.device_get(    # one transfer
+        (cont["n_generated"], cont["steps"]))
     metrics.update(
-        n_generated=int(cont["n_generated"]),
+        n_generated=int(n_generated),
+        decode_steps=int(decode_steps),
         n_reused=int(n.sum()),
         verified_prefix_mean=float(n.mean()),
         full_reuse_ratio=float(full_reuse.mean()),
         accept_rate=accept_rate,
         draft_coverage=float((draft_len > 0).mean()),
-        verify_time=verify_time, rollout_time=rollout_time,
-        assembly_time=assembly_time, compact_time=compact_time,
-        decode_time=decode_time, one_pass=float(one_pass),
-        prefill_passes=prefill_passes,
+        verify_time=verify_time, assembly_time=assembly_time,
+        compact_time=compact_time, decode_time=decode_time,
+        one_pass=float(one_pass), prefill_passes=prefill_passes,
         **_draft_metrics(cont.get("stats") if isinstance(cont, dict)
                          else None))
-    _emit_rollout_obs(spec, metrics, t0,
-                      [("verify", tv0, verify_time),
-                       ("compact", tc0, compact_time),
-                       ("decode", td0, decode_time),
-                       ("assembly", ta0, assembly_time)],
+    _emit_rollout_obs(spec, metrics,
+                      [("verify", verify_time), ("compact", compact_time),
+                       ("decode", decode_time), ("assembly", assembly_time)],
                       n=np.asarray(n))
-    return RolloutBatch(
-        prompt=np.asarray(prompts), prompt_mask=np.asarray(prompt_mask),
-        response=np.asarray(resp), response_mask=np.asarray(resp_mask),
-        behaviour_logprobs=np.asarray(lp), length=np.asarray(length),
-        metrics=metrics)
+    return _to_host(prompts, prompt_mask, resp, resp_mask, lp, length,
+                    metrics)
 
 
 def _update_cache(cache: Optional[RolloutCache], prompt_ids, resp, lp, length,
                   step, eos_id):
     if cache is None:
         return
-    cache.batch_put(prompt_ids, np.asarray(resp), np.asarray(lp),
-                    np.asarray(length), step, eos_id)
+    from repro.obs import get_tracer
+    with get_tracer().span("cache_put", "rollout", cat="rollout"):
+        cache.batch_put(prompt_ids, np.asarray(resp), np.asarray(lp),
+                        np.asarray(length), step, eos_id)

@@ -244,9 +244,9 @@ class _DraftLoop:
                             proposed=n_prop, accepted=n_acc,
                             emitted=int(emitted.sum()))
             macro_step += 1
-        return self._pack()
+        return self._pack(macro_step)
 
-    def _pack(self) -> Dict[str, jnp.ndarray]:
+    def _pack(self, steps: int) -> Dict[str, jnp.ndarray]:
         tokens = np.full((self.B, self.N), self.gen.pad_id, np.int32)
         lps = np.zeros((self.B, self.N), np.float32)
         length = np.zeros((self.B,), np.int32)
@@ -262,7 +262,7 @@ class _DraftLoop:
         return {"tokens": jnp.asarray(tokens), "logprobs": jnp.asarray(lps),
                 "length": jnp.asarray(length),
                 "n_generated": jnp.asarray(length.sum()),
-                "stats": self.stats}
+                "steps": steps, "stats": self.stats}
 
 
 def drafted_generate(params, cfg: ModelConfig, gen: GenerateConfig, prompt,

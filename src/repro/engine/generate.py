@@ -21,9 +21,10 @@ themselves ``jax.jit`` programs, so the §11 tracer deliberately does NOT
 reach inside them — host-side tracer calls traced into the jit graph would
 either fail or bake ops into the compiled program, violating the
 zero-overhead contract.  Their timings are spanned at the call sites
-(core/spec_rollout emits the 'decode'/'generate' stage spans around its
-existing ``block_until_ready`` boundaries), and the §9 drafted loops —
-which ARE host-driven — carry their own per-macro-step spans.
+(core/spec_rollout opens the 'decode'/'generate' stage spans around its
+existing ``block_until_ready`` boundaries), the decode loop's trip count
+comes back as the ``steps`` output, and the §9 drafted loops — which ARE
+host-driven — carry their own per-macro-step spans.
 """
 from __future__ import annotations
 
@@ -82,6 +83,7 @@ def generate(params, cfg: ModelConfig, gen: GenerateConfig, prompt, prompt_mask,
       logprobs   (B, N) behaviour log-probs of generated tokens
       length     (B,)   #generated tokens per row (including eos)
       n_generated ()    total generated tokens (the paper's "Tokens" metric)
+      steps      ()     iterations the decode while_loop ran
     """
     B, P = prompt.shape
     N = gen.max_new_tokens
@@ -180,12 +182,13 @@ def _decode_loop(params, cfg: ModelConfig, gen: GenerateConfig, caches,
     state = (jnp.array(0), done0, tok0, lp0, next_pos, caches,
              tokens_buf, lp_buf, jnp.zeros((B,), jnp.int32), key)
     final = jax.lax.while_loop(cond, body, state)
-    _, _, _, _, _, _, tokens_buf, lp_buf, length, _ = final
+    steps, _, _, _, _, _, tokens_buf, lp_buf, length, _ = final
     return {
         "tokens": tokens_buf,
         "logprobs": lp_buf,
         "length": length,
         "n_generated": length.sum(),
+        "steps": steps,
     }
 
 

@@ -40,8 +40,11 @@ def configure(tracer: Tracer = None,
               registry: MetricsRegistry = None,
               ledger: TokenLedger = None,
               decisions: DecisionLog = None) -> None:
-    """Install process-global observability sinks (launch scripts)."""
+    """Install process-global observability sinks (launch scripts), and
+    the compile-seconds listener (once per process, obs/alerts.py)."""
     global _TRACER, _REGISTRY, _LEDGER, _DECISIONS
+    from .alerts import install_compile_listener
+    install_compile_listener()
     if tracer is not None:
         _TRACER = tracer
     if registry is not None:

@@ -26,6 +26,15 @@ cumulative per-signature compile count for the process.
 registry, which the ``recompile_steady_state`` trend rule then watches.
 A healthy engine compiles during warmup and never again — any upward
 trend after that is a shape leak.
+
+Compile seconds: ``install_compile_listener`` (called once per process by
+``obs.configure``) adds a ``jax.monitoring`` duration listener that sums
+the seconds of jaxpr tracing, lowering, backend compilation and
+persistent-cache loads into the ``compiles.{trace,lower,backend,
+cache_load}_s`` counters of the process-global registry, with a
+``compiles.<kind>_count`` beside each, and, when the global tracer is
+enabled, onto the innermost open span's args (``compile.<kind>_s``), so
+the stage that recompiled is named.  It fires only when JAX compiles.
 """
 from __future__ import annotations
 
@@ -239,6 +248,59 @@ def record_compile_gauges(reg: MetricsRegistry) -> None:
     for name, n in counts.items():
         reg.set(f"compiles.{name}", float(n), agg="max")
     reg.set("compiles.total", float(sum(counts.values())), agg="max")
+
+
+#: jax.monitoring duration events -> compile-seconds kind
+COMPILE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
+class CompileSeconds:
+    """The duration listener behind ``install_compile_listener``.
+
+    JAX times a persistent-cache load inside the backend-compile event that
+    encloses it, so the load's seconds are taken off that event's: the
+    four kinds then add up to the compile time without counting a load
+    twice."""
+
+    def __init__(self):
+        self._loaded = 0.0           # cache-load seconds inside the open
+                                     # backend-compile event
+
+    def __call__(self, event: str, duration: float, **kw) -> None:
+        kind = COMPILE_EVENTS.get(event)
+        if kind is None:
+            return
+        if kind == "cache_load":
+            self._loaded += duration
+        elif kind == "backend":
+            duration, self._loaded = max(0.0, duration - self._loaded), 0.0
+        from . import get_registry, get_tracer
+        reg = get_registry()
+        reg.inc(f"compiles.{kind}_s", duration)
+        reg.inc(f"compiles.{kind}_count")
+        sp = get_tracer().current()          # None unless enabled
+        if sp is not None:
+            key = f"compile.{kind}_s"
+            sp.args[key] = sp.args.get(key, 0.0) + duration
+
+
+_COMPILE_LISTENER: Optional[CompileSeconds] = None
+
+
+def install_compile_listener() -> None:
+    """Register the compile-seconds listener with ``jax.monitoring`` once
+    per process (idempotent)."""
+    global _COMPILE_LISTENER
+    if _COMPILE_LISTENER is None:
+        import jax.monitoring
+        _COMPILE_LISTENER = CompileSeconds()
+        jax.monitoring.register_event_duration_secs_listener(
+            _COMPILE_LISTENER)
 
 
 def record_device_memory(reg: MetricsRegistry) -> None:

@@ -6,6 +6,14 @@ lanes in the Chrome-trace export (obs/export.py): the serving engine emits
 one lane per engine plus one per sampled request; the trainer and the
 SPEC-RL rollout emit stage lanes.
 
+Scoped spans (``span``/``begin``…``end``) also reach the JAX profiler: while
+a span of an enabled tracer is open, a ``jax.profiler.TraceAnnotation``
+named ``<track>.<name>`` (``rollout.verify``) is open too, so a profiler
+trace holds the program's own spans in its host plane, on the same clock as
+the device's op and program events.  Each scoped span records its
+``parent`` (the innermost span open when it began) and inherits the
+parent's ``batch`` arg, so the spans of one collection step share one id.
+
 Zero-overhead contract (the §11 hard rule, enforced by
 tests/obs/test_zero_overhead.py):
 
@@ -16,8 +24,8 @@ tests/obs/test_zero_overhead.py):
   ``block_until_ready`` points, the drafted loop's per-step harvest) — a
   disabled tracer adds **no host syncs** to any hot loop;
 * every recording method early-returns on ``enabled=False`` before touching
-  the clock, and instrumented code guards arg construction behind
-  ``tracer.enabled`` — clean runs stay bit-identical (PR 6 discipline).
+  the clock or importing JAX, and instrumented code guards arg construction
+  behind ``tracer.enabled`` — clean runs stay bit-identical.
 
 The clock is injected (``clock=``) so tests drive a fake monotonic clock and
 golden-file exports are deterministic.  ``sample_rate`` keeps per-request
@@ -43,6 +51,8 @@ class Span:
     t1: Optional[float] = None
     depth: int = 0
     args: Dict = field(default_factory=dict)
+    handle: Optional[int] = None     # begin()'s handle; None for complete()
+    parent: Optional[int] = None     # handle of the enclosing open span
 
     @property
     def dur(self) -> float:
@@ -58,6 +68,9 @@ class Event:
     ts: float
     args: Dict = field(default_factory=dict)
 
+
+# args a scoped span takes from its parent unless given its own
+INHERITED_ARGS = ("batch",)
 
 # Knuth multiplicative hash — deterministic request sampling, identical on
 # every shard/process (no PRNG state, no host randomness in the hot loop)
@@ -79,7 +92,8 @@ class Tracer:
         self.events: deque = deque(maxlen=self.capacity)
         self.dropped_spans = 0          # ring evictions (bounded memory)
         self.dropped_events = 0
-        self._open: Dict[int, Span] = {}
+        self._open: Dict[int, Span] = {}       # insertion order = nesting
+        self._annotations: Dict[int, object] = {}
         self._depth: Dict[str, int] = {}
         self._next = 0
 
@@ -101,15 +115,25 @@ class Tracer:
 
     def begin(self, name: str, track: str = "main", cat: str = "",
               **args) -> int:
-        """Open a span; returns a handle for ``end``.  −1 when disabled."""
+        """Open a span and its profiler annotation; returns a handle for
+        ``end``.  −1 when disabled."""
         if not self.enabled:
             return -1
+        from jax.profiler import TraceAnnotation
+        parent = self.current()
+        if parent is not None:
+            for k in INHERITED_ARGS:
+                if k in parent.args:
+                    args.setdefault(k, parent.args[k])
         h = self._next
         self._next += 1
         d = self._depth.get(track, 0)
         self._depth[track] = d + 1
+        ann = TraceAnnotation(f"{track}.{name}")
+        ann.__enter__()
+        self._annotations[h] = ann
         self._open[h] = Span(name, track, cat, self._clock(), None, d,
-                             dict(args))
+                             args, h, next(reversed(self._open), None))
         return h
 
     def end(self, handle: int, **args) -> None:
@@ -120,6 +144,7 @@ class Tracer:
             return
         self._depth[sp.track] = max(0, self._depth.get(sp.track, 1) - 1)
         sp.t1 = self._clock()
+        self._annotations.pop(handle).__exit__(None, None, None)
         if args:
             sp.args.update(args)
         self._push_span(sp)
@@ -136,6 +161,12 @@ class Tracer:
         finally:
             self.end(h)
 
+    def current(self) -> Optional[Span]:
+        """The innermost open scoped span, or None."""
+        if not self._open:
+            return None
+        return self._open[next(reversed(self._open))]
+
     def complete(self, name: str, track: str, t0: float, t1: float,
                  cat: str = "", **args) -> None:
         """Record a span with explicit endpoints — the engine path.
@@ -144,6 +175,7 @@ class Tracer:
         takes for its time accounting, so tracing never adds a clock call
         (let alone a sync) to a hot loop; retroactive spans (a request's
         whole lifecycle, emitted at finish) are only expressible this way.
+        Written after the fact, such a span never reaches the profiler.
         """
         if not self.enabled:
             return
@@ -180,6 +212,9 @@ class Tracer:
     def clear(self) -> None:
         self.spans.clear()
         self.events.clear()
+        for ann in self._annotations.values():
+            ann.__exit__(None, None, None)
+        self._annotations.clear()
         self._open.clear()
         self._depth.clear()
         self.dropped_spans = self.dropped_events = 0

@@ -188,23 +188,27 @@ class Collector:
         self.gen_steps = 0            # DAPO: generation steps consumed
         self.total_generated_tokens = 0
         self._py_rng = random.Random(1234)
-        from repro.obs import get_tracer
-        self.tracer = tracer if tracer is not None else get_tracer()
+        self._tracer = tracer
+        self.batches = 0              # collect calls: the spans' batch id
 
     # ---------------------------------------------------------------- §11
 
-    def _stage(self, name: str, t0: float, times: Dict[str, float],
-               key: str, step: int) -> float:
-        """Close a collect stage: record its duration under ``key``, emit a
-        'trainer'-lane span and a train.* histogram sample."""
+    @property
+    def tracer(self):
+        """The tracer given at construction, else the process-global one
+        at the time of the call (so ``obs.configure`` reaches a live
+        collector)."""
+        from repro.obs import get_tracer
+        return self._tracer if self._tracer is not None else get_tracer()
+
+    @staticmethod
+    def _stage(name: str, t0: float, times: Dict[str, float],
+               key: str) -> None:
+        """Close a collect stage: record its duration under ``key`` and a
+        train.* histogram sample (its span is scoped around the stage)."""
         from repro.obs import get_registry
-        t1 = time.perf_counter()
-        times[key] = t1 - t0
-        if self.tracer.enabled:
-            self.tracer.complete(name, "trainer", t0, t1, cat="train",
-                                 step=step)
-        get_registry().observe(f"train.{name}_s", t1 - t0)
-        return t1
+        times[key] = time.perf_counter() - t0
+        get_registry().observe(f"train.{name}_s", times[key])
 
     # -------------------------------------------------------------- rollout
 
@@ -235,36 +239,46 @@ class Collector:
     def collect(self, params, batch: PromptBatch, epoch: int
                 ) -> Tuple[PromptBatch, RolloutBatch, np.ndarray,
                            Dict[str, float]]:
-        """Rollout + reward (+ DAPO dynamic sampling) under ``params``."""
-        t0 = time.perf_counter()
-        rb = self.rollout_once(params, batch, epoch)
-        t_reward0 = time.perf_counter()
-        rewards = batch_rewards(rb.response, rb.length, batch.answers)
-        rtimes: Dict[str, float] = {}
-        self._stage("reward", t_reward0, rtimes, "reward_time", epoch)
-        reward_time = rtimes["reward_time"]
+        """Rollout + reward (+ DAPO dynamic sampling) under ``params``:
+        the ``trainer.collect`` span, whose children (``rollout.*``,
+        ``trainer.reward``) share its ``batch`` id."""
+        tr = self.tracer
+        self.batches += 1
+        with tr.span("collect", "trainer", cat="train", step=epoch,
+                     batch=self.batches):
+            t0 = time.perf_counter()
+            rb = self.rollout_once(params, batch, epoch)
+            with tr.span("reward", "trainer", cat="train"):
+                t_reward0 = time.perf_counter()
+                rewards = batch_rewards(rb.response, rb.length,
+                                        batch.answers)
+                rtimes: Dict[str, float] = {}
+                self._stage("reward", t_reward0, rtimes, "reward_time")
+            reward_time = rtimes["reward_time"]
 
-        if self.rl.algo == "dapo" and self.rl.dynamic_sampling:
-            G = self.rl.group_size
-            for _ in range(self.rl.max_resample_rounds):
-                g = rewards.reshape(-1, G)
-                degenerate = (g.std(axis=1) == 0.0)
-                if not degenerate.any():
-                    break
-                # resample the degenerate prompt groups with fresh rollouts
-                keep = ~degenerate
-                idxs = np.where(degenerate)[0]
-                sub_batch = _subset_batch(batch, idxs, G)
-                rb2 = self.rollout_once(params, sub_batch, epoch)
-                r2 = batch_rewards(rb2.response, rb2.length, sub_batch.answers)
-                rb = _merge_rollouts(rb, rb2, idxs, G)
-                rewards = rewards.copy()
-                for j, gi in enumerate(idxs):
-                    rewards[gi * G:(gi + 1) * G] = r2[j * G:(j + 1) * G]
+            if self.rl.algo == "dapo" and self.rl.dynamic_sampling:
+                G = self.rl.group_size
+                for _ in range(self.rl.max_resample_rounds):
+                    g = rewards.reshape(-1, G)
+                    degenerate = (g.std(axis=1) == 0.0)
+                    if not degenerate.any():
+                        break
+                    # resample the degenerate prompt groups with fresh
+                    # rollouts
+                    idxs = np.where(degenerate)[0]
+                    sub_batch = _subset_batch(batch, idxs, G)
+                    rb2 = self.rollout_once(params, sub_batch, epoch)
+                    with tr.span("reward", "trainer", cat="train"):
+                        r2 = batch_rewards(rb2.response, rb2.length,
+                                           sub_batch.answers)
+                    rb = _merge_rollouts(rb, rb2, idxs, G)
+                    rewards = rewards.copy()
+                    for j, gi in enumerate(idxs):
+                        rewards[gi * G:(gi + 1) * G] = r2[j * G:(j + 1) * G]
 
-        stage_times = dict(rb.metrics)
-        stage_times["reward_time"] = reward_time
-        self._stage("collect", t0, stage_times, "collect_time", epoch)
+            stage_times = dict(rb.metrics)
+            stage_times["reward_time"] = reward_time
+            self._stage("collect", t0, stage_times, "collect_time")
         return batch, rb, rewards, stage_times
 
 
@@ -472,6 +486,7 @@ class Trainer:
             ref_lp, _ = _old_logprobs(self.ref_params, self.cfg, full_tokens,
                                       full_mask, P, self.rl.temperature,
                                       self.rl.top_p)
+            ref_lp = jax.block_until_ready(ref_lp)
             self._stage("ref", t0, times, "ref_time")
 
         # ---- advantages ----------------------------------------------------

@@ -185,6 +185,7 @@ def rollout_via_slots(params, cfg: ModelConfig, gen: GenerateConfig,
         prefill_passes=1.0,
         backfill_slots=float(num_slots),
         engine_steps=sched["engine_steps"],
+        decode_steps=int(sched["engine_steps"]),
         slot_occupancy=sched["occupancy"],
         admissions=sched["admitted"],
         # §9 draft telemetry, gathered from the engine's DraftStats

@@ -79,6 +79,24 @@ def test_initial_done_skips_rows(setup):
     assert lens[0] == 0 and lens[2] == 0 and lens[1] > 0
 
 
+@pytest.mark.parametrize("eos_id, budget, done", [
+    (31, [4, 0, 9], None),                   # budgets end the rows
+    (2, None, None),                         # eos or the token budget
+    (31, None, [True, True, True]),          # nothing to decode
+], ids=["budget", "eos", "all_done"])
+def test_steps_is_the_decode_loops_trip_count(setup, eos_id, budget, done):
+    """``steps`` counts while_loop iterations: the loop stops once every
+    row is done, so it runs as many steps as the longest row generates."""
+    cfg, params = setup
+    prompt, mask = _prompt(cfg)
+    gen = GenerateConfig(max_new_tokens=16, eos_id=eos_id)
+    out = generate(params, cfg, gen, prompt, mask, jax.random.PRNGKey(5),
+                   row_budget=None if budget is None else jnp.array(
+                       budget, jnp.int32),
+                   initial_done=None if done is None else jnp.array(done))
+    assert int(out["steps"]) == int(np.asarray(out["length"]).max())
+
+
 def test_left_padding_invariance(setup):
     """Extra left padding must not change greedy generation."""
     cfg, params = setup

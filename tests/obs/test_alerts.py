@@ -157,6 +157,54 @@ def test_recompile_rule_fires_on_cache_growth():
 # ---------------------------------------------------------------- gauges
 
 
+def test_compile_listener_counts_a_recompile_on_the_open_span():
+    """``configure`` installs the compile-seconds listener once per
+    process; a forced recompile adds to the ``compiles.*`` counters and
+    to the innermost open span's args."""
+    from repro.obs import configure, get_registry, reset
+    try:
+        tr = Tracer()
+        configure(tracer=tr, registry=MetricsRegistry())
+        configure(registry=MetricsRegistry())       # installs only once
+        f = jax.jit(lambda x: x * 3 + 1)
+        f(jnp.ones((3,))).block_until_ready()
+        x5 = jnp.ones((5,))
+        before = get_registry().as_dict()
+        with tr.span("collect", "trainer"):
+            with tr.span("decode", "rollout"):
+                f(x5).block_until_ready()                # new shape
+        after = get_registry().as_dict()
+    finally:
+        reset()
+    for kind in ("trace", "lower", "backend"):
+        assert after[f"compiles.{kind}_count"] > \
+            before[f"compiles.{kind}_count"]
+        assert after[f"compiles.{kind}_s"] > before[f"compiles.{kind}_s"]
+    sp = {s.name: s for s in tr.spans}
+    assert sp["decode"].args["compile.backend_s"] > 0
+    assert not any(k.startswith("compile.") for k in sp["collect"].args)
+
+
+def test_compile_seconds_take_a_cache_load_off_its_backend_event():
+    from repro.obs import get_registry, reset
+    from repro.obs.alerts import COMPILE_EVENTS, CompileSeconds
+    ev = {kind: name for name, kind in COMPILE_EVENTS.items()}
+    listen = CompileSeconds()
+    try:
+        reset()
+        listen(ev["cache_load"], 0.25)       # inside the backend event
+        listen(ev["backend"], 0.5)
+        listen(ev["backend"], 2.0)           # a compile: no load inside
+        listen("/jax/other/event", 9.0)      # not a compile event
+        d = get_registry().as_dict()
+    finally:
+        reset()
+    assert d["compiles.cache_load_s"] == 0.25
+    assert d["compiles.backend_s"] == 0.25 + 2.0
+    assert d["compiles.backend_count"] == 2.0
+    assert not any("other" in k for k in d)
+
+
 def test_record_device_memory_never_raises():
     reg = MetricsRegistry()
     record_device_memory(reg)            # CPU: memory_stats() is None/empty

@@ -128,3 +128,105 @@ def test_unbalanced_end_is_harmless():
     tr.end(h)
     tr.end(h)                           # double-end: no-op
     assert len(tr.spans) == 1
+
+
+class FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter/exit."""
+    log = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        FakeAnnotation.log.append(("enter", self.name))
+
+    def __exit__(self, *exc):
+        FakeAnnotation.log.append(("exit", self.name))
+
+
+def test_enabled_span_enters_and_leaves_a_profiler_annotation(monkeypatch):
+    import jax.profiler
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", FakeAnnotation)
+    FakeAnnotation.log = []
+    tr = Tracer(clock=FakeClock())
+    with tr.span("collect", "trainer"):
+        h = tr.begin("verify", "rollout")
+        tr.end(h)
+    assert FakeAnnotation.log == [("enter", "trainer.collect"),
+                                  ("enter", "rollout.verify"),
+                                  ("exit", "rollout.verify"),
+                                  ("exit", "trainer.collect")]
+    # an after-the-fact span never reaches the profiler
+    tr.complete("request", "req/1", 0.0, 1.0)
+    assert len(FakeAnnotation.log) == 4
+
+
+def test_spans_record_parent_and_inherit_the_batch_id():
+    tr = Tracer(clock=FakeClock())
+    with tr.span("collect", "trainer", batch=7):
+        with tr.span("rollout", "rollout", step=3):
+            with tr.span("verify", "rollout"):
+                assert tr.current().name == "verify"
+        with tr.span("reward", "trainer", batch=8):     # own id wins
+            pass
+    with tr.span("other", "trainer"):
+        pass
+    sp = {s.name: s for s in tr.spans}
+    assert sp["collect"].parent is None and sp["other"].parent is None
+    assert sp["verify"].args == {"batch": 7}
+    assert sp["rollout"].args == {"step": 3, "batch": 7}
+    assert sp["reward"].args == {"batch": 8}
+    assert "batch" not in sp["other"].args
+    # handles count up in open order: collect 0, rollout 1, verify 2,
+    # reward 3; a parent is the enclosing span's handle, across tracks
+    assert (sp["rollout"].parent, sp["verify"].parent,
+            sp["reward"].parent) == (0, 1, 0)
+    assert tr.current() is None
+
+
+def test_disabled_tracer_imports_nothing(monkeypatch):
+    import builtins
+    seen = []
+    real = builtins.__import__
+
+    def spy(name, *a, **kw):
+        seen.append(name)
+        return real(name, *a, **kw)
+
+    tr = Tracer(enabled=False)
+    monkeypatch.setattr(builtins, "__import__", spy)
+    h = tr.begin("a", "t")
+    tr.end(h)
+    with tr.span("b", "t"):
+        pass
+    tr.complete("c", "t", 0.0, 1.0)
+    monkeypatch.undo()
+    assert seen == [] and tr.current() is None
+
+
+def test_spans_land_in_the_profilers_host_plane(tmp_path):
+    """A real profiler trace holds each scoped span as a host event named
+    ``<track>.<name>``, nested as the spans were."""
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+    f = jax.jit(lambda x: (x * 2).sum())
+    x = jnp.ones((8,))
+    f(x).block_until_ready()
+    tr = Tracer()
+    jax.profiler.start_trace(str(tmp_path))
+    with tr.span("collect", "trainer", batch=1):
+        with tr.span("verify", "rollout"):
+            f(x).block_until_ready()
+    jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("**/*.xplane.pb")
+    got = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for e in line.events:
+                    if e.name in ("trainer.collect", "rollout.verify"):
+                        got[e.name] = (e.start_ns, e.start_ns + e.duration_ns)
+    assert set(got) == {"trainer.collect", "rollout.verify"}
+    (c0, c1), (v0, v1) = got["trainer.collect"], got["rollout.verify"]
+    assert c0 <= v0 < v1 <= c1
