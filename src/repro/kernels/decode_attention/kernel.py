@@ -31,6 +31,14 @@ empty.  This kernel is specialised for the decode shape instead:
   (already resident — no HBM traffic) and skips the matmul entirely,
   writing the softmax-neutral partial (m=-inf, l=0, acc=0).
 
+* **Stacked cache, read in place.**  ``k``/``v`` may be a whole run's
+  stacked cache ``(L, B, Hkv, S, D)`` with the layer as one more scalar
+  prefetch: the K/V index maps address ``(layer, b, h, split)`` with the
+  layer axis squeezed, so a decode step reads its layer's live tiles
+  straight out of the stack instead of a copied layer slice (DESIGN.md §3).
+  A width that is not a whole number of blocks pads one layer's slice,
+  never the stack (``reads_in_place``).
+
 * **Query-block contract.**  Query positions arrive as two scalars per
   row — ``q_pos0[b]`` (position of query 0) and ``q_len[b]`` (number of
   valid queries) — so query t sits at position ``q_pos0 + t`` when
@@ -52,9 +60,10 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, kpos_ref, q_ref,
-                   k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale: float,
-                   window: int, block_k: int, T: int):
+def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
+                   kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
+                   scale: float, window: int, block_k: int, T: int):
+    del layer_ref                    # read by the K/V index maps only
     b = pl.program_id(0)
     s_i = pl.program_id(2)
     start = s_i * block_k
@@ -93,6 +102,21 @@ def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, kpos_ref, q_ref,
             p, v, preferred_element_type=jnp.float32)    # (G*T, Dv)
 
 
+def reads_in_place(S: int, block_k: int = 128) -> bool:
+    """Whether a stacked cache of width S is read in place: a width that is
+    not a whole number of ``block_k`` tiles is padded, and only one layer's
+    slice of it may be (padding the stack would copy every layer)."""
+    return S % min(block_k, S) == 0
+
+
+def _as_stack(k, v, layer):
+    """(k, v, layer) with a leading layer axis: a single layer's cache is
+    a stack of one."""
+    if layer is None:
+        return k[None], v[None], jnp.zeros((1,), jnp.int32)
+    return k, v, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _split_tiles(k_pos, nsplit: int, block: int):
     """(B, nsplit * block) positions -> (B, nsplit, 1, block).
 
@@ -116,20 +140,20 @@ def _combine(m, l, acc):
     return acc_tot / jnp.where(l_tot > 0, l_tot, 1.0)[..., None]
 
 
-def _paged_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, table_ref,
-                  kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
-                  scale: float, window: int, block_k: int, T: int):
+def _paged_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
+                  table_ref, kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
+                  acc_ref, *, scale: float, window: int, block_k: int, T: int):
     # identical math to the dense kernel — the block table only redirects
     # the K/V DMAs (see the index maps in paged_decode_attention_pallas)
     del table_ref
-    _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, kpos_ref, q_ref,
-                   k_ref, v_ref, m_ref, l_ref, acc_ref, scale=scale,
-                   window=window, block_k=block_k, T=T)
+    _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
+                   kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                   scale=scale, window=window, block_k=block_k, T=T)
 
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
-                                  k_pos, lengths, starts, *, window: int = 0,
-                                  interpret: bool = False):
+                                  k_pos, lengths, starts, layer=None, *,
+                                  window: int = 0, interpret: bool = False):
     """Flash-decode over a paged KV cache (DESIGN.md §13).
 
     Same split-K schedule and kernel body as ``decode_attention_pallas``,
@@ -142,10 +166,12 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
     physical block 0 — the allocator's pinned sink — exactly as the dense
     kernel redirects to its own block 0.  ``k_pos`` stays dense (B, S =
     nb*bs), so masking is untouched: outputs are bit-identical to running
-    the dense kernel on the gathered cache.
+    the dense kernel on the gathered cache.  With ``layer`` the pools are a
+    run's stacked pools ``(L, NB, Hkv, bs, D)``, read in place at that layer.
     """
     B, Hq, T, Dk = q.shape
-    NB, Hkv, bs, _ = k_pool.shape
+    k_pool, v_pool, layer = _as_stack(k_pool, v_pool, layer)
+    _, NB, Hkv, bs, _ = k_pool.shape
     Dv = v_pool.shape[-1]
     nb = table.shape[1]
     S = nb * bs
@@ -157,25 +183,26 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
     def _live_split(s, len_ref, start_ref, b):
         return (s * bs < len_ref[b]) & ((s + 1) * bs > start_ref[b])
 
-    def _kv_block(b, h, s, len_ref, start_ref, qp_ref, ql_ref, table_ref):
-        # live split s of row b reads physical block table[b, s]; dead
-        # splits re-fetch the sink (block 0) instead of streaming recycled
-        # blocks (same-block DMA is elided)
+    def _kv_block(b, h, s, len_ref, start_ref, qp_ref, ql_ref, layer_ref,
+                  table_ref):
+        # live split s of row b reads physical block table[b, s] of the
+        # layer's pool; dead splits re-fetch the sink (block 0) instead of
+        # streaming recycled blocks (same-block DMA is elided)
         live = _live_split(s, len_ref, start_ref, b)
-        return (jnp.where(live, table_ref[b, s], 0), h, 0, 0)
+        return (layer_ref[0], jnp.where(live, table_ref[b, s], 0), h, 0, 0)
 
     def _kpos_block(b, h, s, len_ref, start_ref, *_):
         return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0),
                 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,
+        num_scalar_prefetch=6,
         grid=(B, Hkv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, 1, bs), _kpos_block),
             pl.BlockSpec((1, 1, G * T, Dk), lambda b, h, s, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bs, Dk), _kv_block),
-            pl.BlockSpec((1, 1, bs, Dv), _kv_block),
+            pl.BlockSpec((None, 1, 1, bs, Dk), _kv_block),   # layer squeezed
+            pl.BlockSpec((None, 1, 1, bs, Dv), _kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, G * T, 1),
@@ -197,34 +224,41 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32), starts.astype(jnp.int32),
-      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32),
+      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32), layer,
       table.astype(jnp.int32), _split_tiles(k_pos, nb, bs), qg, k_pool,
       v_pool)
     out = _combine(m, l, acc)                            # (B, Hkv, G*T, Dv)
     return out.reshape(B, Hkv, G, T, Dv).reshape(B, Hq, T, Dv)
 
 
-def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts, *,
-                            window: int = 0, block_k: int = 128,
+def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
+                            layer=None, *, window: int = 0, block_k: int = 128,
                             interpret: bool = False):
     """q: (B, Hq, T, Dk); k: (B, Hkv, S, Dk); v: (B, Hkv, S, Dv);
     q_pos0/q_len: (B,) int32 query-block descriptors (query t lives at
     position q_pos0 + t iff t < q_len); k_pos: (B, S) int32;
     lengths/starts: (B,) int32 live bounds (slot j live iff
-    starts[b] <= j < lengths[b]).
+    starts[b] <= j < lengths[b]).  With ``layer`` (a scalar int32), k/v
+    are a run's stacked cache (L, B, Hkv, S, D), read in place at that
+    layer; k_pos is that layer's positions.
 
     Returns (B, Hq, T, Dv) float32.  Dk and Dv may differ (MLA)."""
     B, Hq, T, Dk = q.shape
-    Hkv, S = k.shape[1], k.shape[2]
+    k, v, layer = _as_stack(k, v, layer)
+    Hkv, S = k.shape[2], k.shape[3]
     Dv = v.shape[-1]
     G = Hq // Hkv
     block_k = min(block_k, S)
     pad_s = (-S) % block_k
     if pad_s:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_s), (0, 0)))
+        # a partial last tile: pad the layer's slice, never the stack
+        k = jax.lax.dynamic_slice_in_dim(k, layer[0], 1, 0)
+        v = jax.lax.dynamic_slice_in_dim(v, layer[0], 1, 0)
+        layer = jnp.zeros((1,), jnp.int32)
+        width = ((0, 0), (0, 0), (0, 0), (0, pad_s), (0, 0))
+        k, v = jnp.pad(k, width), jnp.pad(v, width)
         k_pos = jnp.pad(k_pos, ((0, 0), (0, pad_s)), constant_values=-1)
-    Sp = k.shape[2]
+    Sp = k.shape[3]
     nsplit = Sp // block_k
     # pack (G, T) into the sublane dim: row g*T + t
     qg = q.reshape(B, Hkv, G, T, Dk).reshape(B, Hkv, G * T, Dk)
@@ -233,24 +267,24 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts, *,
     def _live_split(s, len_ref, start_ref, b):
         return (s * block_k < len_ref[b]) & ((s + 1) * block_k > start_ref[b])
 
-    def _kv_block(b, h, s, len_ref, start_ref, *_):
+    def _kv_block(b, h, s, len_ref, start_ref, qp_ref, ql_ref, layer_ref):
         # early exit: dead splits re-fetch block 0 instead of streaming the
         # dead left-pad / empty tail (same-block DMA is elided)
-        return (b, h, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0),
-                0)
+        return (layer_ref[0], b, h,
+                jnp.where(_live_split(s, len_ref, start_ref, b), s, 0), 0)
 
     def _kpos_block(b, h, s, len_ref, start_ref, *_):
         return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0),
                 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(B, Hkv, nsplit),
         in_specs=[
             pl.BlockSpec((1, 1, 1, block_k), _kpos_block),
             pl.BlockSpec((1, 1, G * T, Dk), lambda b, h, s, *_: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, Dk), _kv_block),
-            pl.BlockSpec((1, 1, block_k, Dv), _kv_block),
+            pl.BlockSpec((None, 1, 1, block_k, Dk), _kv_block),  # layer squeezed
+            pl.BlockSpec((None, 1, 1, block_k, Dv), _kv_block),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, 1, G * T, 1),
@@ -272,7 +306,7 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts, *,
         ],
         interpret=interpret,
     )(lengths.astype(jnp.int32), starts.astype(jnp.int32),
-      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32),
+      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32), layer,
       _split_tiles(k_pos, nsplit, block_k), qg, k, v)
     out = _combine(m, l, acc)                            # (B, Hkv, G*T, Dv)
     return out.reshape(B, Hkv, G, T, Dv).reshape(B, Hq, T, Dv)

@@ -24,7 +24,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import decode_attention_pallas, paged_decode_attention_pallas
+from .kernel import (decode_attention_pallas, paged_decode_attention_pallas,
+                     reads_in_place)
 from .ref import decode_attention_blocked, decode_attention_ref
 
 # Below this cache width a single naive score pass beats the blocked
@@ -33,8 +34,8 @@ NAIVE_MAX_S = 128
 
 
 @functools.partial(jax.jit, static_argnames=("window", "impl", "block_k"))
-def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None, *,
-                     window: int = 0, impl: str = "auto",
+def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None,
+                     layer=None, *, window: int = 0, impl: str = "auto",
                      block_k: int = 128):
     """Short-query decode attention over a dense cache.
 
@@ -42,17 +43,20 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None, *,
     block); k: (B, Hkv, S, Dk); v: (B, Hkv, S, Dv) (Dk may differ from Dv —
     MLA); q_pos: (B,), (B, 1) or (B, T); k_pos: (B, S); lengths/starts:
     optional (B,) int32 live bounds — slot j of row b is attended only when
-    starts[b] <= j < lengths[b] (None = [0, S)).  Returns (B, Hq, T, Dv)
-    float32.
+    starts[b] <= j < lengths[b] (None = [0, S)).  layer: optional scalar
+    int32, for the kernel impls only — k/v are then a run's stacked cache
+    (L, B, Hkv, S, D), read in place at that layer, and k_pos that layer's
+    positions.  Returns (B, Hq, T, Dv) float32.
 
     impl: 'auto' (pallas on TPU; elsewhere naive for S <= NAIVE_MAX_S,
     length-bounded blocked beyond) | 'pallas' | 'interpret' | 'blocked' |
     'naive'.
     """
+    S = k_pos.shape[1]
     if impl == "auto":
         if jax.default_backend() == "tpu":
             impl = "pallas"
-        elif k.shape[2] <= NAIVE_MAX_S:
+        elif S <= NAIVE_MAX_S:
             impl = "naive"
         else:
             impl = "blocked"
@@ -64,7 +68,6 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None, *,
                                         starts, window=window,
                                         block_k=block_k)
     B, _, T = q.shape[:3]
-    S = k.shape[2]
     if lengths is None:
         lengths = jnp.full((B,), S, jnp.int32)
     lengths = jnp.minimum(lengths.reshape(B).astype(jnp.int32), S)
@@ -81,7 +84,7 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None, *,
     q_pos0 = q_pos[:, 0]
     q_len = jnp.sum((q_pos >= 0).astype(jnp.int32), axis=1)
     return decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos,
-                                   lengths, starts, window=window,
+                                   lengths, starts, layer, window=window,
                                    block_k=block_k,
                                    interpret=(impl == "interpret"))
 
@@ -108,14 +111,17 @@ def gather_paged_kv(pool, table):
 
 @functools.partial(jax.jit, static_argnames=("window", "impl"))
 def paged_decode_attention(q, k_pool, v_pool, table, q_pos, k_pos,
-                           lengths=None, starts=None, *, window: int = 0,
-                           impl: str = "auto"):
+                           lengths=None, starts=None, layer=None, *,
+                           window: int = 0, impl: str = "auto"):
     """Short-query decode attention over a paged cache (DESIGN.md §13).
 
     q: (B, Hq, T, Dk); k_pool/v_pool: (NB, Hkv, bs, D) physical block
     pools; table: (B, nb) int32 block table (logical slot j of row b lives
     at ``pool[table[b, j // bs], :, j % bs]``); k_pos: (B, nb*bs) dense
-    positions; lengths/starts as in ``decode_attention``.
+    positions; lengths/starts as in ``decode_attention``.  layer: optional
+    scalar int32, for the kernel impls only — the pools are then a run's
+    stacked pools (L, NB, Hkv, bs, D), read in place at that layer (table
+    is that layer's).
 
     impl: 'pallas' | 'interpret' run the paged flash kernel (split axis ==
     block axis, table-redirected DMAs); 'naive' | 'blocked' | 'auto'-on-CPU
@@ -155,4 +161,4 @@ def paged_decode_attention(q, k_pool, v_pool, table, q_pos, k_pos,
     q_len = jnp.sum((q_pos >= 0).astype(jnp.int32), axis=1)
     return paged_decode_attention_pallas(
         q, k_pool, v_pool, table, q_pos0, q_len, k_pos, lengths, starts,
-        window=window, interpret=(impl == "interpret"))
+        layer, window=window, interpret=(impl == "interpret"))

@@ -24,6 +24,12 @@ KV caches come in two layouts (``cfg.cache_layout``, DESIGN.md §13):
   (serving/block_table.py).  Both layouts stay statically shaped, which is
   what XLA/TPU wants; paging only redirects which tiles the decode kernel
   DMAs.
+
+Inside the trunk (models/blocks.py) each cache leaf is a run's *stack*, one
+layer per leading index, carried through the layer scan: a layer writes its
+new K/V into the stack at ``[layer, ..., cache_start]`` in place, and the
+flash-decode kernel reads its layer straight out of the stack (DESIGN.md
+§3).  Every other read takes that layer's slice.
 """
 from __future__ import annotations
 
@@ -148,9 +154,23 @@ def _decode_shaped(cache, kv_x, causal, T: int, kv_length) -> bool:
     return T == 1 or (kv_length is not None and T <= DECODE_BLOCK_MAX_T)
 
 
+def _layer(stack, layer):
+    """Layer ``layer`` of a stacked cache leaf: one dynamic slice."""
+    return jax.lax.dynamic_index_in_dim(stack, layer, 0, keepdims=False)
+
+
+def _layer_view(stack, layer, table, width: int):
+    """One layer's dense (B, [Hkv,] width, D) view of a stacked K/V leaf:
+    its slice, or for paged pools (``table`` = the layer's block table) the
+    gather of its blocks."""
+    buf = _layer(stack, layer)
+    return buf if table is None else _paged_gather(buf, table, width)
+
+
 def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
                       window: int, cache_start, kv_length, kv_start,
-                      use_pallas: bool, mesh=None, paged=None) -> jnp.ndarray:
+                      use_pallas: bool, mesh=None, layer=None,
+                      table=None) -> jnp.ndarray:
     """Route a decode-shaped (short-T, cached) call to the flash-decode op.
 
     ``kv_length`` is the per-row live cache extent.  When the caller does
@@ -162,10 +182,20 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
     callers that know their layout is contiguous from that slot may thread
     it — None means start at 0, which is always safe.
 
+    With ``layer``, k/v are the run's stacked cache (L, B, Hkv, S, D), or
+    with ``table`` (this layer's block table) its stacked paged pools: the
+    flash-decode kernel reads the layer in place; every other path (the
+    jnp impls, the mesh wrapper, a width that is not a whole number of
+    kernel tiles) reads the layer's slice.  ``OP_COUNTS`` counts each.
+
     ``mesh`` routes the call through the shard_map boundary (DESIGN.md §8):
     each device runs the kernel on its local (batch, head) block with a
     static per-shard shape instead of leaving a Pallas black box to GSPMD.
     """
+    from repro.kernels.decode_attention.ops import (decode_attention,
+                                                    paged_decode_attention,
+                                                    reads_in_place)
+    from .model import OP_COUNTS
     B, _, T = q.shape[:3]
     if kv_length is None:
         kv_length = jnp.asarray(cache_start, jnp.int32) + T
@@ -185,28 +215,35 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
     if impl == "auto" and use_pallas:
         impl = "pallas" if _default_backend() == "tpu" else "interpret"
     # remaining "auto" resolves in the op: pallas on TPU, else naive for
-    # tiny caches / length-bounded blocked beyond (DESIGN.md §7)
+    # tiny caches / length-bounded blocked beyond (DESIGN.md §7); the paged
+    # kernel is taken only when named, "auto" reads the gathered view
+    kernel = impl in ("pallas", "interpret")
+    if table is None:
+        kernel = ((kernel or (impl == "auto" and _default_backend() == "tpu"))
+                  and reads_in_place(kv_pos.shape[-1]))
+    in_place = layer is not None and mesh is None and kernel
+    OP_COUNTS["decode_attn_inplace" if in_place else "decode_attn_sliced"] += 1
+    if layer is not None and not in_place:
+        k = _layer_view(k, layer, table, kv_pos.shape[-1])
+        v = _layer_view(v, layer, table, kv_pos.shape[-1])
+        layer = table = None
     if mesh is not None:
         # paged + mesh reuses the dense shard_map path on the gathered
-        # logical view the caller already built (k/v here) — the gather is
-        # a per-shard-local permutation once pools stay unsharded on batch
+        # logical view — the gather is a per-shard-local permutation once
+        # pools stay unsharded on batch
         from repro.distributed.shard_wrap import sharded_decode_attention
         if starts is None:
             starts = jnp.zeros((B,), jnp.int32)
         return sharded_decode_attention(
             mesh, q, k.astype(q.dtype), v.astype(q.dtype), q_pos,
             kv_pos, lengths, starts, window=window, impl=impl)
-    if paged is not None and impl in ("pallas", "interpret"):
-        # the paged flash kernel consumes the block pools directly (the
-        # gathered k/v above become dead code under jit)
-        from repro.kernels.decode_attention.ops import paged_decode_attention
-        k_pool, v_pool, table = paged
+    if table is not None:
+        # the paged flash kernel consumes the layer's block pools directly
         return paged_decode_attention(
-            q, k_pool.astype(q.dtype), v_pool.astype(q.dtype), table,
-            q_pos, kv_pos, lengths, starts, window=window, impl=impl)
-    from repro.kernels.decode_attention.ops import decode_attention
+            q, k.astype(q.dtype), v.astype(q.dtype), table, q_pos, kv_pos,
+            lengths, starts, layer, window=window, impl=impl)
     return decode_attention(q, k.astype(q.dtype), v.astype(q.dtype),
-                            q_pos, kv_pos, lengths, starts,
+                            q_pos, kv_pos, lengths, starts, layer,
                             window=window, impl=impl)
 
 
@@ -271,8 +308,10 @@ def init_paged_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype,
     }
 
 
-def _cache_write(buf, update, start, axis: int = -2):
-    """Write ``update`` (length T) into ``buf`` at slot ``start`` on ``axis``.
+def _cache_write(buf, update, start, layer, axis: int = -2):
+    """Write ``update`` (length T) into layer ``layer`` of the stacked cache
+    leaf ``buf`` (L, B, ...) at slot ``start`` on ``axis`` — in place, one
+    dynamic_update_slice; nothing else of the stack is touched.
 
     start: scalar — one slot for the whole batch (prefill / lockstep decode)
     — or (B,) int32 — per-row slots, required by the serving slot scheduler
@@ -280,19 +319,26 @@ def _cache_write(buf, update, start, axis: int = -2):
     form is a vmap'd dynamic_update_slice (a scatter), writing the same
     values at the same indices as the scalar form does row by row.
     """
-    update = update.astype(buf.dtype)
-    if jnp.ndim(start) == 0:
-        return jax.lax.dynamic_update_slice_in_dim(buf, update, start, axis)
-    return jax.vmap(
-        lambda b, u, s: jax.lax.dynamic_update_slice_in_dim(b, u, s, axis)
-    )(buf, update, start.astype(jnp.int32))
+    update = update.astype(buf.dtype)[None]
+    axis = axis % buf.ndim
+
+    def write(b, u, s):
+        idx = [layer] + [0] * (b.ndim - 1)
+        idx[axis] = s
+        return jax.lax.dynamic_update_slice(b, u, idx)
+    start = jnp.asarray(start, jnp.int32)
+    if start.ndim == 0:
+        return write(buf, update, start)
+    axis -= 1                                    # inside the per-row vmap
+    return jax.vmap(write, in_axes=(1, 1, 0), out_axes=1)(buf, update, start)
 
 
-def _paged_write(pool, update, start, table, s_logical: int):
+def _paged_write(pool, update, start, table, s_logical: int, layer):
     """Paged counterpart of ``_cache_write``: scatter a T-token update into
-    the physical block pool through the row's block table.
+    layer ``layer`` of the stacked physical block pools through the row's
+    block table.
 
-    pool: (NB, Hkv, bs, D) or (NB, bs, D); update: (B, Hkv, T, D) /
+    pool: (L, NB, Hkv, bs, D) or (L, NB, bs, D); update: (B, Hkv, T, D) /
     (B, T, D); start: scalar or (B,) int32; s_logical: the logical cache
     width (the ``pos`` array's, which may be short of ``nb * bs`` by the
     block-rounding slack).  Slot mapping matches the dense DUS semantics
@@ -310,7 +356,7 @@ def _paged_write(pool, update, start, table, s_logical: int):
     bs = pool.shape[-2]
     B, nb = table.shape
     S = s_logical                     # clamp like dense DUS at this width
-    gqa = pool.ndim == 4
+    gqa = pool.ndim == 5
     T = update.shape[2] if gqa else update.shape[1]
     start = jnp.asarray(start, jnp.int32)
     s0 = jnp.clip(jnp.broadcast_to(start.reshape(-1), (B,)), 0, S - T)
@@ -332,12 +378,12 @@ def _paged_write(pool, update, start, table, s_logical: int):
             chunks = update.reshape(B, update.shape[1], nbw, bs, -1)
             for i in range(nbw):
                 blk = table[rows, b0 + i]
-                pool = pool.at[blk].set(chunks[:, :, i])
+                pool = pool.at[layer, blk].set(chunks[:, :, i])
         else:
             chunks = update.reshape(B, nbw, bs, -1)
             for i in range(nbw):
                 blk = table[rows, b0 + i]
-                pool = pool.at[blk].set(chunks[:, i])
+                pool = pool.at[layer, blk].set(chunks[:, i])
         return pool
     rows = jnp.arange(B)
     for t in range(T):
@@ -345,9 +391,9 @@ def _paged_write(pool, update, start, table, s_logical: int):
         blk = table[rows, idx // bs]
         off = idx % bs
         if gqa:
-            pool = pool.at[blk, :, off].set(update[:, :, t])
+            pool = pool.at[layer, blk, :, off].set(update[:, :, t])
         else:
-            pool = pool.at[blk, off].set(update[:, t])
+            pool = pool.at[layer, blk, off].set(update[:, t])
     return pool
 
 
@@ -388,18 +434,20 @@ def make_gqa(key, cfg: ModelConfig, dtype):
 
 
 def apply_gqa(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None,
-              causal=True, kv_x=None, kv_positions=None,
+              layer=None, causal=True, kv_x=None, kv_positions=None,
               use_pallas: bool = False, kv_length=None, kv_start=None,
               mesh=None):
     """GQA attention.
 
     x: (B, T, d).  With ``cache`` given, writes K/V at ``cache_start`` and
-    attends over the whole cache (decode / incremental prefill).  With
-    ``kv_x`` given, performs cross-attention (no causal mask, no rope on kv
-    unless positions supplied).  ``kv_length`` (scalar or (B,) int32) bounds
-    the live cache extent for decode-shaped calls (T == 1 with cache): those
-    are dispatched to the flash-decode kernel / length-bounded blocked path
-    instead of full-S attention.
+    attends over the whole cache (decode / incremental prefill).  ``cache``
+    is the layer run's stacked cache (leaves with a leading layer axis) and
+    ``layer`` (scalar int32) this layer's index in it: K/V are written into
+    that layer in place and the whole stack is returned.  With ``kv_x`` given, performs cross-attention (no causal
+    mask, no rope on kv unless positions supplied).  ``kv_length`` (scalar
+    or (B,) int32) bounds the live cache extent for decode-shaped calls
+    (T == 1 with cache): those are dispatched to the flash-decode kernel /
+    length-bounded blocked path instead of full-S attention.
     """
     B, T, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -422,32 +470,36 @@ def apply_gqa(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None
         kv_pos = kv_positions
         # cross-attention: no rope (whisper style learned enc positions)
 
-    new_cache = None
-    paged = None
+    decode = _decode_shaped(cache, kv_x, causal, T, kv_length)
+    new_cache = table = None
     if cache is not None:
+        S_log = cache["pos"].shape[-1]
         if "table" in cache:
-            table = cache["table"]
-            S_log = cache["pos"].shape[-1]
-            k_pool = _paged_write(cache["k"], k, cache_start, table, S_log)
-            v_pool = _paged_write(cache["v"], v, cache_start, table, S_log)
-            pos_all = _cache_write(cache["pos"], kv_pos.astype(jnp.int32),
-                                   cache_start, axis=-1)
-            new_cache = {"k": k_pool, "v": v_pool, "pos": pos_all,
-                         "table": table}
-            paged = (k_pool, v_pool, table)
-            # dense logical view for the non-kernel paths; DCE'd when the
-            # paged kernel consumes the pools directly
-            k, v, kv_pos = (_paged_gather(k_pool, table, S_log),
-                            _paged_gather(v_pool, table, S_log), pos_all)
+            table = _layer(cache["table"], layer)
+            k_st = _paged_write(cache["k"], k, cache_start, table, S_log, layer)
+            v_st = _paged_write(cache["v"], v, cache_start, table, S_log, layer)
         else:
-            k_all = _cache_write(cache["k"], k, cache_start)
-            v_all = _cache_write(cache["v"], v, cache_start)
-            pos_all = _cache_write(cache["pos"], kv_pos.astype(jnp.int32),
-                                   cache_start, axis=-1)
-            new_cache = {"k": k_all, "v": v_all, "pos": pos_all}
-            k, v, kv_pos = k_all, v_all, pos_all
+            k_st = _cache_write(cache["k"], k, cache_start, layer)
+            v_st = _cache_write(cache["v"], v, cache_start, layer)
+        pos_st = _cache_write(cache["pos"], kv_pos.astype(jnp.int32),
+                              cache_start, layer, axis=-1)
+        new_cache = dict(cache, k=k_st, v=v_st, pos=pos_st)
+        kv_pos = _layer(pos_st, layer)
+        if decode:
+            k, v = k_st, v_st                # the decode op reads the stack
+        elif table is None:
+            # the layer's slice from before the write, written alike: read
+            # from the written stack, the attention's operand layout would
+            # spread to the stack and cost a relayout copy of all of it
+            k = _cache_write(_layer(cache["k"], layer)[None], k,
+                             cache_start, 0)[0]
+            v = _cache_write(_layer(cache["v"], layer)[None], v,
+                             cache_start, 0)[0]
+        else:
+            k = _layer_view(k_st, layer, table, S_log)
+            v = _layer_view(v_st, layer, table, S_log)
 
-    if _decode_shaped(cache, kv_x, causal, T, kv_length):
+    if decode:
         # short-query decode (single token, or a k+1 draft-verify block):
         # flash-decode kernel with split-K and per-row cache-length early
         # exit (or the length-bounded blocked fallback)
@@ -455,7 +507,7 @@ def apply_gqa(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None
                                 window=cfg.sliding_window,
                                 cache_start=cache_start, kv_length=kv_length,
                                 kv_start=kv_start, use_pallas=use_pallas,
-                                mesh=mesh, paged=paged)
+                                mesh=mesh, layer=layer, table=table)
     elif use_pallas and kv_x is None and T > 1:
         # Pallas flash kernel (TPU; interpret mode in tests).  Same schedule
         # as _blocked_attention but with MXU-aligned VMEM tiles.  The decode
@@ -508,7 +560,9 @@ def make_mla(key, cfg: ModelConfig, dtype):
 
 
 def apply_mla(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None,
-              causal=True, kv_length=None, kv_start=None, mesh=None):
+              layer=None, causal=True, kv_length=None, kv_start=None,
+              mesh=None):
+    """DeepSeek-V3 MLA; ``cache``/``layer`` as in ``apply_gqa``."""
     B, T, _ = x.shape
     H = cfg.num_heads
     nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -530,31 +584,25 @@ def apply_mla(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None
     kv_pos = positions
     new_cache = None
     if cache is not None:
+        S_log = cache["pos"].shape[-1]
         if "table" in cache:
-            # paged MLA: latents live in block pools; reads always go
-            # through the dense gather (decompression needs the full
-            # logical view anyway, DESIGN.md §7)
-            table = cache["table"]
-            S_log = cache["pos"].shape[-1]
-            ckv_pool = _paged_write(cache["ckv"], ckv, cache_start, table,
-                                    S_log)
-            krope_pool = _paged_write(cache["krope"], k_rope[:, 0],
-                                      cache_start, table, S_log)
-            pos_all = _cache_write(cache["pos"], positions.astype(jnp.int32),
-                                   cache_start, axis=-1)
-            new_cache = {"ckv": ckv_pool, "krope": krope_pool,
-                         "pos": pos_all, "table": table}
-            ckv = _paged_gather(ckv_pool, table, S_log)
-            k_rope = _paged_gather(krope_pool, table, S_log)[:, None]
-            kv_pos = pos_all
+            table = _layer(cache["table"], layer)
+            ckv_st = _paged_write(cache["ckv"], ckv, cache_start, table,
+                                  S_log, layer)
+            krope_st = _paged_write(cache["krope"], k_rope[:, 0],
+                                    cache_start, table, S_log, layer)
         else:
-            ckv_all = _cache_write(cache["ckv"], ckv, cache_start, axis=-2)
-            krope_all = _cache_write(cache["krope"], k_rope[:, 0],
-                                     cache_start, axis=-2)
-            pos_all = _cache_write(cache["pos"], positions.astype(jnp.int32),
-                                   cache_start, axis=-1)
-            new_cache = {"ckv": ckv_all, "krope": krope_all, "pos": pos_all}
-            ckv, k_rope, kv_pos = ckv_all, krope_all[:, None], pos_all
+            table = None
+            ckv_st = _cache_write(cache["ckv"], ckv, cache_start, layer)
+            krope_st = _cache_write(cache["krope"], k_rope[:, 0],
+                                    cache_start, layer)
+        pos_st = _cache_write(cache["pos"], positions.astype(jnp.int32),
+                              cache_start, layer, axis=-1)
+        new_cache = dict(cache, ckv=ckv_st, krope=krope_st, pos=pos_st)
+        # decompression needs the layer's whole logical view (DESIGN.md §7)
+        ckv = _layer_view(ckv_st, layer, table, S_log)
+        k_rope = _layer_view(krope_st, layer, table, S_log)[:, None]
+        kv_pos = _layer(pos_st, layer)
 
     # decompress latent -> per-head K_nope and V
     kv = apply_dense(p["wkv_b"], ckv.astype(x.dtype))

@@ -6,7 +6,10 @@ the lowered HLO stays small even for 88-layer trunks; heterogeneous trunks
 (jamba) become a short python loop over signature runs, each run scanned.
 
 Caches mirror the run structure: ``cache[run_idx]`` is a pytree whose leaves
-have a leading ``run_len`` axis, scanned alongside the parameters.
+have a leading ``run_len`` axis.  A run's cache stack is part of the layer
+scan's *carry*: layer ``l`` writes its slot of the stack in place and the
+flash-decode kernel reads it there, so a decode step moves one token's K/V
+per layer, never a whole layer slice or the whole stack (DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -85,11 +88,26 @@ def init_block_cache(cfg: ModelConfig, sig: BlockSig, batch: int, max_len: int, 
     return cache
 
 
+def _layer_state(stack, layer):
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        stack)
+
+
+def _write_state(stack, new, layer):
+    return {k: jax.lax.dynamic_update_index_in_dim(
+        a, new[k].astype(a.dtype), layer, 0) if k in new else a
+        for k, a in stack.items()}
+
+
 def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
-                cache=None, cache_start=None, encoder_out=None,
+                cache=None, layer=None, cache_start=None, encoder_out=None,
                 encoder_positions=None, use_pallas: bool = False,
                 causal: bool = True, kv_length=None, kv_start=None,
                 mesh=None):
+    """One block.  ``cache`` is the run's stacked cache, read and written at
+    ``layer``: attention K/V in place, a recurrent layer's small state as
+    one slice written back.  Returns (x, new stacked cache, aux)."""
     kind, is_moe, cross = sig
     norm = apply_layernorm if kind == RWKV else functools.partial(
         apply_rmsnorm, eps=cfg.norm_eps)
@@ -100,22 +118,24 @@ def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
     if kind == ATTN:
         out, c = apply_attention(p["attn"], cfg, h, positions,
                                  cache=None if cache is None else cache["self"],
-                                 cache_start=cache_start, causal=causal,
+                                 layer=layer, cache_start=cache_start,
+                                 causal=causal,
                                  use_pallas=use_pallas, kv_length=kv_length,
                                  kv_start=kv_start, mesh=mesh)
         if c is not None:
             new_cache["self"] = c
     elif kind == MAMBA:
         out, c = apply_mamba(p["mamba"], cfg, h, positions,
-                             cache=None if cache is None else cache["mamba"])
+                             cache=None if cache is None else
+                             _layer_state(cache["mamba"], layer))
         if c is not None:
-            new_cache["mamba"] = c
+            new_cache["mamba"] = _write_state(cache["mamba"], c, layer)
     else:  # RWKV time mix
+        rwkv = None if cache is None else _layer_state(cache["rwkv"], layer)
         out, c = apply_rwkv_time_mix(p["time_mix"], cfg, h, positions,
-                                     cache=None if cache is None else cache["rwkv"],
-                                     use_pallas=use_pallas)
+                                     cache=rwkv, use_pallas=use_pallas)
         if c is not None:
-            new_cache["rwkv"] = dict(c)
+            new_cache["rwkv"] = _write_state(cache["rwkv"], c, layer)
     x = x + out
 
     if cross:
@@ -128,9 +148,9 @@ def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
     h = norm(p["norm2"], x)
     if kind == RWKV:
         out, c = apply_rwkv_channel_mix(p["channel_mix"], cfg, h, positions,
-                                        cache=None if cache is None else cache["rwkv"])
+                                        cache=rwkv)
         if c is not None:
-            new_cache["rwkv"].update(c)
+            new_cache["rwkv"] = _write_state(new_cache["rwkv"], c, layer)
     elif is_moe:
         out, moe_aux = apply_moe(p["moe"], cfg, h)
         aux.update(moe_aux)
@@ -179,39 +199,44 @@ def apply_trunk(trunk_params, cfg: ModelConfig, x, positions, *,
                 encoder_positions=None, use_pallas: bool = False,
                 causal: bool = True, kv_length=None, kv_start=None,
                 mesh=None):
-    """Run all layers.  Returns (x, new_caches, aux_mean)."""
+    """Run all layers.  Returns (x, new_caches, aux_mean).
+
+    With ``caches``, each run's cache stack rides in the scan's carry and
+    the scanned inputs are (params, layer index): every layer writes and
+    reads its own slot of the stack in place, and no cache is emitted as
+    scan output (which would copy each layer's slice into a fresh stack)."""
     runs = signature_runs(cfg)
     new_caches = [] if caches is not None else None
     aux_sums: Dict[str, jnp.ndarray] = {}
     aux_counts: Dict[str, int] = {}
+    block_kw = dict(cache_start=cache_start, encoder_out=encoder_out,
+                    encoder_positions=encoder_positions,
+                    use_pallas=use_pallas, causal=causal,
+                    kv_length=kv_length, kv_start=kv_start, mesh=mesh)
 
     for run_idx, (sig, run_len) in enumerate(runs):
         params = trunk_params[run_idx]
-        cache = caches[run_idx] if caches is not None else None
+        if caches is None:
+            def body(h, layer_p):
+                h, _, aux = apply_block(layer_p, cfg, sig, h, positions,
+                                        **block_kw)
+                return h, aux
 
-        def body(carry, xs):
-            h = carry
-            if cache is not None:
-                layer_p, layer_c = xs
-            else:
-                layer_p, layer_c = xs, None
-            h, new_c, aux = apply_block(
-                layer_p, cfg, sig, h, positions,
-                cache=layer_c, cache_start=cache_start,
-                encoder_out=encoder_out, encoder_positions=encoder_positions,
-                use_pallas=use_pallas, causal=causal, kv_length=kv_length,
-                kv_start=kv_start, mesh=mesh)
-            outs = (new_c, aux) if cache is not None else aux
-            return h, outs
-
-        body = _maybe_remat(body, cfg)
-        xs = (params, cache) if cache is not None else params
-        x, outs = jax.lax.scan(body, x, xs)
-        if cache is not None:
-            stacked_c, auxs = outs
-            new_caches.append(stacked_c)
+            x, auxs = jax.lax.scan(_maybe_remat(body, cfg), x, params)
         else:
-            auxs = outs
+            # cached calls are never differentiated: no remat
+            def body(carry, xs):
+                h, stack = carry
+                layer_p, layer = xs
+                h, stack, aux = apply_block(layer_p, cfg, sig, h, positions,
+                                            cache=stack, layer=layer,
+                                            **block_kw)
+                return (h, stack), aux
+
+            (x, stack), auxs = jax.lax.scan(
+                body, (x, caches[run_idx]),
+                (params, jnp.arange(run_len, dtype=jnp.int32)))
+            new_caches.append(stack)
         for k, v in auxs.items():           # v: (run_len, ...) from scan ys
             aux_sums[k] = aux_sums.get(k, 0.0) + jnp.sum(v, axis=0)
             aux_counts[k] = aux_counts.get(k, 0) + run_len

@@ -36,7 +36,11 @@ def _dt(cfg: ModelConfig):
 # jit they count *traces*; wrap a region in ``jax.disable_jit()`` to count the
 # actual forwards executed — that is how the one-pass SPEC-RL benchmark/tests
 # assert "prompt ⊕ accepted prefix is forwarded exactly once per step".
-OP_COUNTS = {"forward": 0, "prefill": 0, "decode_step": 0}
+# ``decode_attn_inplace`` / ``decode_attn_sliced`` count decode-attention
+# calls that read the stacked K/V cache in place / read one layer's slice
+# of it (models/attention._decode_attention): which path a config takes.
+OP_COUNTS = {"forward": 0, "prefill": 0, "decode_step": 0,
+             "decode_attn_inplace": 0, "decode_attn_sliced": 0}
 
 
 def reset_op_counts() -> None:

@@ -276,3 +276,48 @@ def test_block_query_t1_matches_legacy_shapes():
         b = decode_attention(q, k, v, q_pos[:, None], kpos, lengths, starts,
                              impl=impl, block_k=16)
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _pad_operand_shapes(fn, *args):
+    """Operand shapes of every ``pad`` in fn's jaxpr, nested ones too."""
+    shapes = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pad":
+                shapes.append(tuple(eqn.invars[0].aval.shape))
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return shapes
+
+
+@pytest.mark.parametrize("S", [1280, 202])
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize("window", [0, 16])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_stacked_layer_read_is_bit_identical(S, T, window, layer):
+    """The kernel on a stacked cache (L=5) at layer l gives the same bits as
+    the kernel on that layer's slice: first, middle and last layer, decode
+    and draft blocks, a width of whole tiles (1280) and a trainer width
+    that is not (202, whose padding must touch one layer, not the stack)."""
+    L = 5
+    q, k, v, q_pos, kpos, lengths, starts = _block_case(3, 4, 2, S, 16, T,
+                                                        seed=S + T)
+    ks = jax.random.split(jax.random.PRNGKey(layer), 2)
+    k_st = jax.random.normal(ks[0], (L,) + k.shape).at[layer].set(k)
+    v_st = jax.random.normal(ks[1], (L,) + v.shape).at[layer].set(v)
+    want = decode_attention(q, k, v, q_pos, kpos, lengths, starts,
+                            window=window, impl="interpret")
+
+    def stacked(k_st, v_st, layer):
+        return decode_attention(q, k_st, v_st, q_pos, kpos, lengths, starts,
+                                layer, window=window, impl="interpret")
+    got = stacked(k_st, v_st, jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    pads = _pad_operand_shapes(stacked, k_st, v_st, jnp.int32(layer))
+    assert all(s[0] == 1 for s in pads if len(s) == 5), pads
+    assert bool(pads) == (S % 128 != 0)

@@ -150,3 +150,23 @@ def test_paged_slot_write_roundtrip(gqa):
     untouched = sorted(set(range(NB)) - set(blocks.tolist()))
     np.testing.assert_array_equal(flat[:, untouched],
                                   np.asarray(pool)[:, untouched])
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("window", [0, 16])
+def test_paged_kernel_reads_stacked_pools_in_place(layer, window):
+    """Stacked pools (L=3) read at layer l give the same bits as the
+    kernel on that layer's pools."""
+    L = 3
+    q, kp, vp, table, q_pos, kpos, lengths, starts = _paged_case(
+        4, 4, 2, 64, 16, 16, seed=7)
+    ks = jax.random.split(jax.random.PRNGKey(layer), 2)
+    kp_st = jax.random.normal(ks[0], (L,) + kp.shape).at[layer].set(kp)
+    vp_st = jax.random.normal(ks[1], (L,) + vp.shape).at[layer].set(vp)
+    want = paged_decode_attention(q, kp, vp, table, q_pos, kpos, lengths,
+                                  starts=starts, window=window,
+                                  impl="interpret")
+    got = paged_decode_attention(q, kp_st, vp_st, table, q_pos, kpos,
+                                 lengths, starts, jnp.int32(layer),
+                                 window=window, impl="interpret")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -2,7 +2,11 @@
 real widths (qwen3-0.6b: GQA 16/8, head dim 128; a 4096-slot cache; 16
 rows).  Nothing runs: the chip's compiler is asked whether it accepts each
 kernel, which interpret mode cannot tell (tile-aligned blocks, lowerable
-ops, VMEM).  Each test skips where no v5e topology can be described."""
+ops, VMEM).  The decode loop is compiled whole too, to check that a step
+moves no whole K/V cache.  Each test skips where no v5e topology can be
+described."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -67,6 +71,96 @@ def test_decode_attention(spec, T):
              spec((B, HQ, T, D), BF), spec((B, HKV, S, D), BF),
              spec((B, HKV, S, D), BF), spec((B,), I32), spec((B,), I32),
              spec((B, S), I32), spec((B,), I32), spec((B,), I32))
+
+
+@pytest.mark.parametrize("T", [1, 5])
+def test_decode_attention_stacked(spec, T):
+    """The whole stacked cache of qwen3-0.6b (28 layers, 8 rows, 1280
+    slots), read at a layer given as a scalar."""
+    stack = (CFG.num_layers, 8, HKV, 1280, D)
+    _compile(decode_attention_pallas,
+             spec((8, HQ, T, D), BF), spec(stack, BF), spec(stack, BF),
+             spec((8,), I32), spec((8,), I32), spec((8, 1280), I32),
+             spec((8,), I32), spec((8,), I32), spec((), I32))
+
+
+def test_paged_decode_attention_stacked(spec):
+    nb = S // BS
+    NB = B * nb + 1
+    pools = (CFG.num_layers, NB, HKV, BS, D)
+    _compile(paged_decode_attention_pallas,
+             spec((B, HQ, 1, D), BF), spec(pools, BF), spec(pools, BF),
+             spec((B, nb), I32), spec((B,), I32), spec((B,), I32),
+             spec((B, S), I32), spec((B,), I32), spec((B,), I32),
+             spec((), I32))
+
+
+# an HLO instruction line: (name, result shape without layout, opcode)
+INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = ([a-z0-9]+\[[\d,]*\])\S* "
+                   r"([\w\-]+)\(")
+
+
+def _loop_computations(hlo: str):
+    """{computation: instruction lines} of every computation a ``while``
+    body reaches (the loop bodies and what they call)."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        m = re.match(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif name is not None and line.startswith("  "):
+            comps[name].append(line)
+    called = re.compile(r"(?:calls|body|condition|to_apply|"
+                        r"branch_computations)=\{?%?([\w.\-]+)")
+    todo = [b for lines in comps.values() for ln in lines
+            for b in re.findall(r"body=%?([\w.\-]+)", ln)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c in seen or c not in comps:
+            continue
+        seen.add(c)
+        todo += [d for ln in comps[c] for d in called.findall(ln)]
+    return {c: comps[c] for c in seen}
+
+
+def test_resume_decode_loop_moves_no_whole_cache(topo):
+    """``resume_from_cache`` at qwen3-0.6b (B=8, a 1280-slot cache written
+    from slot 768, the pallas decode kernel): inside the decode loop no
+    copy of the whole stacked cache bf16[28,8,8,1280,128] and no
+    dynamic-slice of a whole layer bf16[8,8,1280,128]; temporaries below
+    2 GB (3.76 GB while each step copied the stack)."""
+    from repro.engine.generate import GenerateConfig, resume_from_cache
+    from repro.models import model as M
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = CFG.replace(decode_impl="pallas", tie_embeddings=True)
+    as_spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(as_spec, jax.eval_shape(
+        lambda k: M.init_lm(k, cfg), jax.random.PRNGKey(0)))
+    caches = jax.tree.map(as_spec, jax.eval_shape(
+        lambda: M.init_cache(cfg, 8, 1280)))
+    vec = lambda dt: jax.ShapeDtypeStruct((8,), dt, sharding=one_chip)
+    compiled = resume_from_cache.lower(
+        params, cfg, GenerateConfig(max_new_tokens=512), caches,
+        jax.ShapeDtypeStruct((8, cfg.vocab_size), F32, sharding=one_chip),
+        vec(I32), 768, jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                            sharding=one_chip),
+        vec(jnp.bool_), vec(I32)).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    loop = [m.groups() for lines in _loop_computations(hlo).values()
+            for ln in lines for m in [INSTR.match(ln)] if m]
+    assert loop
+    stack = f"bf16[{cfg.num_layers},8,{HKV},1280,{D}]"
+    layer = (f"bf16[8,{HKV},1280,{D}]", f"bf16[1,8,{HKV},1280,{D}]")
+    copies = [i for i in loop if i[1] == stack and i[2] == "copy"]
+    assert not copies, copies
+    slices = [i for i in loop if i[1] in layer and "dynamic-slice" in
+              i[0] + i[2]]
+    assert not slices, slices
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 
 def test_paged_decode_attention(spec):

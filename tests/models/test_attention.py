@@ -92,8 +92,10 @@ def test_mla_cache_decompression_matches_full(tiny_cfg):
     pos = _pos(B, T)
     full, _ = apply_mla(p, cfg, x, pos)
     from repro.models.attention import init_kv_cache
-    cache = init_kv_cache(cfg, B, T, jnp.float32)
-    via_cache, _ = apply_mla(p, cfg, x, pos, cache=cache, cache_start=0)
+    cache = jax.tree.map(lambda a: a[None],       # a stack of one layer
+                         init_kv_cache(cfg, B, T, jnp.float32))
+    via_cache, _ = apply_mla(p, cfg, x, pos, cache=cache, cache_start=0,
+                             layer=0)
     np.testing.assert_allclose(np.asarray(full), np.asarray(via_cache),
                                atol=1e-5)
 
