@@ -21,9 +21,12 @@ ARCH_IDS = {
     "deepseek-7b": "deepseek_7b",
     # the paper's own backbone (not part of the assigned 10)
     "qwen3-1.7b": "qwen3_1p7b",
+    # a DeepSeek-V3 block at RL post-training size
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
-ASSIGNED = [k for k in ARCH_IDS if k != "qwen3-1.7b"]
+ASSIGNED = [k for k in ARCH_IDS if k not in ("qwen3-1.7b",
+                                             "moonlight-16b-a3b")]
 
 
 def get_config(arch_id: str) -> ModelConfig:

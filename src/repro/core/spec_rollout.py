@@ -184,6 +184,12 @@ def _emit_rollout_obs(spec, metrics, stages, n=None):
             reg.observe("rollout.reuse_len", float(v))
 
 
+def _held_metric(held) -> Dict[str, float]:
+    """``moe_held_tokens``: the decode loop's routed assignments that land
+    on held experts (MoE trunks; nothing for others)."""
+    return {} if held is None else {"moe_held_tokens": float(held)}
+
+
 def _draft_metrics(stats=None) -> Dict[str, float]:
     """Rollout-metric view of a DraftStats (zeros when drafting is off).
 
@@ -254,7 +260,8 @@ def rollout(params, cfg: ModelConfig, gen: GenerateConfig, spec: SpecConfig,
     (``cache_get``, ``verify``, ``compact``, ``decode`` or ``generate``,
     ``assembly``, ``cache_put``, ``to_host``), each device stage ending at
     a sync on its outputs.  ``metrics["decode_steps"]`` is the decode
-    loop's trip count.
+    loop's trip count, and on MoE trunks ``metrics["moe_held_tokens"]`` its
+    routed assignments on held experts, from the same transfer.
     """
     assert spec.variant in VARIANTS, spec.variant
     from repro.obs import get_registry, get_tracer
@@ -346,9 +353,11 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
             resp_mask = jnp.arange(N)[None, :] < length[:, None]
             # the loop's scalar outputs, read in one transfer: the sync that
             # ends the stage (a program's outputs are ready together)
-            n_generated, decode_steps = jax.device_get(
-                (out["n_generated"], out["steps"]))
+            n_generated, decode_steps, held = jax.device_get(
+                (out["n_generated"], out["steps"],
+                 out.get("moe_held_tokens")))
             decode_time = time.perf_counter() - tg0
+        metrics.update(_held_metric(held))
         metrics.update(
             n_generated=int(n_generated),
             decode_steps=int(decode_steps), n_reused=0,
@@ -542,8 +551,9 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
                            int(len_fin[b]) - int(n_fin[b]))
             led.finalize(led_rows[b], int(led_p[b]) + int(len_fin[b]))
 
-    n_generated, decode_steps = jax.device_get(    # one transfer
-        (cont["n_generated"], cont["steps"]))
+    n_generated, decode_steps, held = jax.device_get(    # one transfer
+        (cont["n_generated"], cont["steps"], cont.get("moe_held_tokens")))
+    metrics.update(_held_metric(held))
     metrics.update(
         n_generated=int(n_generated),
         decode_steps=int(decode_steps),

@@ -84,6 +84,9 @@ def generate(params, cfg: ModelConfig, gen: GenerateConfig, prompt, prompt_mask,
       length     (B,)   #generated tokens per row (including eos)
       n_generated ()    total generated tokens (the paper's "Tokens" metric)
       steps      ()     iterations the decode while_loop ran
+      moe_held_tokens () (MoE trunks) the loop's routed assignments of
+                        valid tokens that land on held experts, summed
+                        over steps and MoE layers
     """
     B, P = prompt.shape
     N = gen.max_new_tokens
@@ -140,6 +143,8 @@ def _decode_loop(params, cfg: ModelConfig, gen: GenerateConfig, caches,
     """
     B = seed_logits.shape[0]
     N = gen.max_new_tokens
+    # MoE trunks also count the loop's routed assignments on held experts
+    count_held = cfg.num_experts > 0
     key, sub = split_key(key)
     tok0, lp0 = sample(sub, seed_logits, gen.temperature, gen.top_p)
 
@@ -152,7 +157,7 @@ def _decode_loop(params, cfg: ModelConfig, gen: GenerateConfig, caches,
 
     def body(state):
         (step, done, cur_tok, cur_lp, next_pos, caches, tokens_buf, lp_buf,
-         count, key) = state
+         count, key), held = state[:10], state[10:]
         tok_store = jnp.where(done, gen.pad_id, cur_tok)
         lp_store = jnp.where(done, 0.0, cur_lp)
         tokens_buf = jax.lax.dynamic_update_index_in_dim(
@@ -164,16 +169,19 @@ def _decode_loop(params, cfg: ModelConfig, gen: GenerateConfig, caches,
         # live cache extent: [kv_start, write_offset + step] — the dead
         # left padding in front of the context and the unwritten tail are
         # both skipped by the flash-decode kernel
-        logits, caches = M.decode_step(
+        out = M.decode_step(
             params, cfg, tok_store[:, None],
             jnp.where(done[:, None], -1, next_pos[:, None]),
             caches, write_offset + step,
             kv_length=write_offset + 1 + step, kv_start=kv_start,
-            mesh=mesh, **extras)
+            mesh=mesh, with_aux=count_held, **extras)
+        logits, caches = out[:2]
+        if count_held:
+            held = (held[0] + out[2]["moe_held_tokens"],)
         key, sub = split_key(key)
         nxt, nlp = sample(sub, logits[:, 0], gen.temperature, gen.top_p)
         return (step + 1, done_next, nxt, nlp, next_pos + 1, caches,
-                tokens_buf, lp_buf, count, key)
+                tokens_buf, lp_buf, count, key) + held
 
     done0 = jnp.zeros((B,), bool) if initial_done is None else initial_done
     budget = jnp.full((B,), N, jnp.int32) if row_budget is None else \
@@ -181,15 +189,20 @@ def _decode_loop(params, cfg: ModelConfig, gen: GenerateConfig, caches,
     done0 = done0 | (budget <= 0)
     state = (jnp.array(0), done0, tok0, lp0, next_pos, caches,
              tokens_buf, lp_buf, jnp.zeros((B,), jnp.int32), key)
+    if count_held:
+        state += (jnp.zeros((), jnp.int32),)
     final = jax.lax.while_loop(cond, body, state)
-    steps, _, _, _, _, _, tokens_buf, lp_buf, length, _ = final
-    return {
+    steps, _, _, _, _, _, tokens_buf, lp_buf, length, _ = final[:10]
+    out = {
         "tokens": tokens_buf,
         "logprobs": lp_buf,
         "length": length,
         "n_generated": length.sum(),
         "steps": steps,
     }
+    if count_held:
+        out["moe_held_tokens"] = final[10]
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "gen", "write_offset",

@@ -47,6 +47,11 @@ empty.  This kernel is specialised for the decode shape instead:
   ``q_len == 0``; a draft block proposes a valid prefix of its T columns.
   The ops wrapper derives both from the (B, T) position array; arbitrary
   non-contiguous query positions belong on the ref/blocked paths.
+
+* **Latent attention.**  ``decode_attention_mla_pallas`` runs MLA decode in
+  the absorbed form over the cached latent and shared rope key (one KV
+  "head" of width r + rd that all H query heads share), with the same
+  splits, masks and combine (DESIGN.md §15).
 """
 from __future__ import annotations
 
@@ -60,6 +65,40 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _neutral(m_ref, l_ref, acc_ref):
+    """A dead split's softmax-neutral partial, ignored by the combine."""
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _slot_mask(kpos, b, start, len_ref, start_ref, qpos0_ref, qlen_ref,
+               shape, T: int, window: int):
+    """(rows, block_k) mask of one split: sublane row r is query r % T (at
+    position qpos0 + r % T, valid while below qlen); a slot counts where
+    its position is live and causal, inside the window, and within the
+    row's [starts, lengths)."""
+    t_idx = jax.lax.broadcasted_iota(jnp.int32, shape, 0) % T
+    qpos = qpos0_ref[b] + t_idx
+    mask = (kpos >= 0) & (kpos <= qpos) & (t_idx < qlen_ref[b])
+    if window > 0:
+        mask &= (qpos - kpos) < window
+    j = start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    mask &= (j < len_ref[b]) & (j >= start_ref[b])
+    return mask
+
+
+def _write_partial(s, mask, v, m_ref, l_ref, acc_ref):
+    """One split's online-softmax partial of scores ``s`` over values v."""
+    s = jnp.where(mask, s, NEG_INF)
+    m = jnp.max(s, axis=1, keepdims=True)                # (rows, 1)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    m_ref[0, 0, 0] = m
+    l_ref[0, 0, 0] = jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[0, 0, 0] = jax.lax.dot(
+        p, v, preferred_element_type=jnp.float32)        # (rows, Dv)
+
+
 def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
                    kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
                    scale: float, window: int, block_k: int, T: int):
@@ -71,10 +110,7 @@ def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
 
     @pl.when(jnp.logical_not(live))
     def _dead():
-        # softmax-neutral partial: ignored by the combine stage
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _neutral(m_ref, l_ref, acc_ref)
 
     @pl.when(live)
     def _live():
@@ -85,21 +121,9 @@ def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         kpos = kpos_ref[0, 0].astype(jnp.int32)          # (1, bk)
-        # sublane row r = g*T + t: query t of group g, at position qpos0 + t
-        t_idx = jax.lax.broadcasted_iota(jnp.int32, (GT, block_k), 0) % T
-        qpos = qpos0_ref[b] + t_idx
-        mask = (kpos >= 0) & (kpos <= qpos) & (t_idx < qlen_ref[b])
-        if window > 0:
-            mask &= (qpos - kpos) < window
-        j = start + jax.lax.broadcasted_iota(jnp.int32, (GT, block_k), 1)
-        mask &= (j < len_ref[b]) & (j >= start_ref[b])
-        s = jnp.where(mask, s, NEG_INF)
-        m = jnp.max(s, axis=1, keepdims=True)            # (G*T, 1)
-        p = jnp.where(mask, jnp.exp(s - m), 0.0)
-        m_ref[0, 0, 0] = m
-        l_ref[0, 0, 0] = jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[0, 0, 0] = jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)    # (G*T, Dv)
+        mask = _slot_mask(kpos, b, start, len_ref, start_ref, qpos0_ref,
+                          qlen_ref, (GT, block_k), T, window)
+        _write_partial(s, mask, v, m_ref, l_ref, acc_ref)
 
 
 def reads_in_place(S: int, block_k: int = 128) -> bool:
@@ -310,3 +334,120 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
       _split_tiles(k_pos, nsplit, block_k), qg, k, v)
     out = _combine(m, l, acc)                            # (B, Hkv, G*T, Dv)
     return out.reshape(B, Hkv, G, T, Dv).reshape(B, Hq, T, Dv)
+
+
+# latent tile of the MLA kernel: whole tiles of it read the stack in place
+MLA_BLOCK_K = 256
+
+
+def _mla_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
+                kpos_ref, ql_ref, qr_ref, c_ref, r_ref, m_ref, l_ref,
+                acc_ref, *, scale: float, block_k: int, T: int):
+    del layer_ref                    # read by the latent index maps only
+    b = pl.program_id(0)
+    s_i = pl.program_id(1)
+    start = s_i * block_k
+    live = ((start < len_ref[b]) & (start + block_k > start_ref[b])
+            & (qlen_ref[b] > 0))
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        _neutral(m_ref, l_ref, acc_ref)
+
+    @pl.when(live)
+    def _live():
+        ql = ql_ref[0].astype(jnp.float32)               # (H*T, r)
+        qr = qr_ref[0].astype(jnp.float32)               # (H*T, rd)
+        c = c_ref[0].astype(jnp.float32)                 # (bk, r)
+        kr = r_ref[0].astype(jnp.float32)                # (bk, rd)
+        dims = (((1,), (1,)), ((), ()))
+        s = (jax.lax.dot_general(ql, c, dims,
+                                 preferred_element_type=jnp.float32)
+             + jax.lax.dot_general(qr, kr, dims,
+                                   preferred_element_type=jnp.float32)
+             ) * scale
+        kpos = kpos_ref[0, 0].astype(jnp.int32)          # (1, bk)
+        mask = _slot_mask(kpos, b, start, len_ref, start_ref, qpos0_ref,
+                          qlen_ref, s.shape, T, 0)
+        _write_partial(s, mask, c, m_ref, l_ref, acc_ref)
+
+
+def decode_attention_mla_pallas(q_lat, q_rope, ckv, krope, q_pos0, q_len,
+                                k_pos, lengths, starts, layer=None, *,
+                                scale: float, block_k: int = MLA_BLOCK_K,
+                                interpret: bool = False):
+    """Absorbed latent-attention decode (DeepSeek-V3 MLA, DESIGN.md §15).
+
+    q_lat: (B, H, T, r) — each head's no-position query already multiplied
+    into the K half of ``wkv_b``; q_rope: (B, H, T, rd) rotated; ckv:
+    (B, S, r) the cached (normed) latent and krope: (B, S, rd) the shared
+    rotated key, or with ``layer`` a run's stacks (L, B, S, r) / (L, B, S,
+    rd) read in place at that layer.  Score of slot j for head h is
+    (q_lat[h] . ckv[j] + q_rope[h] . krope[j]) * scale; the output is the
+    softmax-weighted latent (B, H, T, r) float32, to go through the V half
+    of ``wkv_b``.
+
+    The grid is (B, S / block_k): one program per (row, split) reads its
+    latent and rope tiles once for all H heads (packed with the T queries
+    into the sublane rows, row h*T + t).  Splits, per-row live bounds
+    [starts, lengths), the query-block contract and the combine are the
+    GQA kernel's; a row with no live query (finished) is dead in every
+    split and reads no tile.  S must be a whole number of tiles."""
+    B, H, T, r = q_lat.shape
+    rd = q_rope.shape[-1]
+    ckv, krope, layer = _as_stack(ckv, krope, layer)
+    S = ckv.shape[2]
+    block_k = min(block_k, S)
+    assert S % block_k == 0, (S, block_k)
+    nsplit = S // block_k
+    HT = H * T
+    ql = q_lat.reshape(B, HT, r)
+    qr = q_rope.reshape(B, HT, rd)
+
+    def _live_split(s, b, len_ref, start_ref, qlen_ref):
+        # a finished row (no live query) reads nothing
+        return ((s * block_k < len_ref[b]) & ((s + 1) * block_k > start_ref[b])
+                & (qlen_ref[b] > 0))
+
+    def _lat_block(b, s, len_ref, start_ref, qp_ref, ql_ref, layer_ref):
+        # dead splits re-fetch tile 0 (same-block DMA is elided)
+        live = _live_split(s, b, len_ref, start_ref, ql_ref)
+        return (layer_ref[0], b, jnp.where(live, s, 0), 0)
+
+    def _kpos_block(b, s, len_ref, start_ref, qp_ref, ql_ref, *_):
+        live = _live_split(s, b, len_ref, start_ref, ql_ref)
+        return (b, jnp.where(live, s, 0), 0, 0)
+
+    def _out(b, s, *_):
+        return (b, 0, s, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,
+        grid=(B, nsplit),
+        in_specs=[
+            pl.BlockSpec((1, 1, 1, block_k), _kpos_block),
+            pl.BlockSpec((1, HT, r), lambda b, s, *_: (b, 0, 0)),
+            pl.BlockSpec((1, HT, rd), lambda b, s, *_: (b, 0, 0)),
+            pl.BlockSpec((None, 1, block_k, r), _lat_block),  # layer squeezed
+            pl.BlockSpec((None, 1, block_k, rd), _lat_block),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, 1, 1, HT, 1), _out),
+            pl.BlockSpec((1, 1, 1, HT, 1), _out),
+            pl.BlockSpec((1, 1, 1, HT, r), _out),
+        ],
+    )
+    m, l, acc = pl.pallas_call(
+        functools.partial(_mla_kernel, scale=scale, block_k=block_k, T=T),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, 1, nsplit, HT, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, nsplit, HT, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, nsplit, HT, r), jnp.float32),
+        ],
+        interpret=interpret,
+        name="decode_attention_mla",
+    )(lengths.astype(jnp.int32), starts.astype(jnp.int32),
+      q_pos0.astype(jnp.int32), q_len.astype(jnp.int32), layer,
+      _split_tiles(k_pos, nsplit, block_k), ql, qr, ckv, krope)
+    return _combine(m, l, acc).reshape(B, H, T, r)

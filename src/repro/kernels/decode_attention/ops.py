@@ -24,13 +24,36 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .kernel import (decode_attention_pallas, paged_decode_attention_pallas,
+from .kernel import (MLA_BLOCK_K, decode_attention_mla_pallas,
+                     decode_attention_pallas, paged_decode_attention_pallas,
                      reads_in_place)
-from .ref import decode_attention_blocked, decode_attention_ref
+from .ref import (decode_attention_blocked, decode_attention_ref,
+                  mla_decode_attention_ref)
 
 # Below this cache width a single naive score pass beats the blocked
 # while_loop's bookkeeping (one block_k=128 chunk covers it anyway).
 NAIVE_MAX_S = 128
+
+
+def _kernel_bounds(q_pos, lengths, starts, B: int, T: int, S: int):
+    """The kernels' scalar-prefetch operands: (q_pos0, q_len, lengths,
+    starts), each (B,) int32, with lengths/starts defaulted and clamped to
+    [0, S]."""
+    if lengths is None:
+        lengths = jnp.full((B,), S, jnp.int32)
+    lengths = jnp.minimum(lengths.reshape(B).astype(jnp.int32), S)
+    if starts is None:
+        starts = jnp.zeros((B,), jnp.int32)
+    starts = jnp.clip(starts.reshape(B).astype(jnp.int32), 0, S)
+    q_pos = q_pos.reshape(B, -1).astype(jnp.int32)
+    if q_pos.shape != (B, T):
+        # same rejection as ref._norm_inputs: a (B,)/(B, 1) position for a
+        # T > 1 block would silently mean different things per impl
+        raise ValueError(f"q_pos {q_pos.shape} must be (B, T)={B, T} for "
+                         f"T > 1 query blocks")
+    # valid-prefix query-block contract (see module docstring)
+    q_len = jnp.sum((q_pos >= 0).astype(jnp.int32), axis=1)
+    return q_pos[:, 0], q_len, lengths, starts
 
 
 @functools.partial(jax.jit, static_argnames=("window", "impl", "block_k"))
@@ -68,21 +91,8 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None,
                                         starts, window=window,
                                         block_k=block_k)
     B, _, T = q.shape[:3]
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    lengths = jnp.minimum(lengths.reshape(B).astype(jnp.int32), S)
-    if starts is None:
-        starts = jnp.zeros((B,), jnp.int32)
-    starts = jnp.clip(starts.reshape(B).astype(jnp.int32), 0, S)
-    q_pos = q_pos.reshape(B, -1).astype(jnp.int32)
-    if q_pos.shape != (B, T):
-        # same rejection as ref._norm_inputs: a (B,)/(B, 1) position for a
-        # T > 1 block would silently mean different things per impl
-        raise ValueError(f"q_pos {q_pos.shape} must be (B, T)={B, T} for "
-                         f"T > 1 query blocks")
-    # valid-prefix query-block contract (see module docstring)
-    q_pos0 = q_pos[:, 0]
-    q_len = jnp.sum((q_pos >= 0).astype(jnp.int32), axis=1)
+    q_pos0, q_len, lengths, starts = _kernel_bounds(q_pos, lengths, starts,
+                                                    B, T, S)
     return decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos,
                                    lengths, starts, layer, window=window,
                                    block_k=block_k,
@@ -147,18 +157,40 @@ def paged_decode_attention(q, k_pool, v_pool, table, q_pos, k_pos,
         v = gather_paged_kv(v_pool, table)
         return decode_attention(q, k, v, q_pos, k_pos, lengths, starts,
                                 window=window, impl=impl, block_k=bs)
-    if lengths is None:
-        lengths = jnp.full((B,), S, jnp.int32)
-    lengths = jnp.minimum(lengths.reshape(B).astype(jnp.int32), S)
-    if starts is None:
-        starts = jnp.zeros((B,), jnp.int32)
-    starts = jnp.clip(starts.reshape(B).astype(jnp.int32), 0, S)
-    q_pos = q_pos.reshape(B, -1).astype(jnp.int32)
-    if q_pos.shape != (B, T):
-        raise ValueError(f"q_pos {q_pos.shape} must be (B, T)={B, T} for "
-                         f"T > 1 query blocks")
-    q_pos0 = q_pos[:, 0]
-    q_len = jnp.sum((q_pos >= 0).astype(jnp.int32), axis=1)
+    q_pos0, q_len, lengths, starts = _kernel_bounds(q_pos, lengths, starts,
+                                                    B, T, S)
     return paged_decode_attention_pallas(
         q, k_pool, v_pool, table, q_pos0, q_len, k_pos, lengths, starts,
         layer, window=window, interpret=(impl == "interpret"))
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "impl", "block_k"))
+def decode_attention_mla(q_lat, q_rope, ckv, krope, q_pos, k_pos,
+                         lengths=None, starts=None, layer=None, *,
+                         scale: float, impl: str = "auto",
+                         block_k: int = MLA_BLOCK_K):
+    """Absorbed latent-attention decode over a dense latent cache.
+
+    q_lat: (B, H, T, r) queries through the K half of ``wkv_b``; q_rope:
+    (B, H, T, rd); ckv: (B, S, r); krope: (B, S, rd); q_pos/k_pos/lengths/
+    starts as in ``decode_attention``.  layer: optional scalar int32, for
+    the kernel impls only — ckv/krope are then a run's stacks (L, B, S, D),
+    read in place at that layer.  Returns the weighted latent (B, H, T, r)
+    float32.
+
+    impl: 'pallas' | 'interpret' run the kernel (S a whole number of
+    ``block_k`` tiles, T a valid-prefix query block); 'naive' the jnp
+    absorbed form; 'auto' the kernel on TPU, else jnp."""
+    S = k_pos.shape[1]
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "naive"
+    if impl == "naive":
+        return mla_decode_attention_ref(q_lat, q_rope, ckv, krope, q_pos,
+                                        k_pos, lengths, starts, scale=scale)
+    B, _, T = q_lat.shape[:3]
+    q_pos0, q_len, lengths, starts = _kernel_bounds(q_pos, lengths, starts,
+                                                    B, T, S)
+    return decode_attention_mla_pallas(
+        q_lat, q_rope, ckv, krope, q_pos0, q_len, k_pos, lengths,
+        starts, layer, scale=scale, block_k=block_k,
+        interpret=(impl == "interpret"))

@@ -151,3 +151,28 @@ def decode_attention_blocked(q, k, v, q_pos, k_pos, lengths=None, starts=None,
     _, m, l, acc = jax.lax.while_loop(cond, body, init)
     out = acc / jnp.where(l > 0, l, 1.0)
     return out.reshape(B, Hq, T, Dv)
+
+
+def mla_decode_attention_ref(q_lat, q_rope, ckv, krope, q_pos, k_pos,
+                             lengths=None, starts=None, *, scale: float):
+    """Absorbed latent-attention decode in jnp (the kernel's oracle, and the
+    path for T > 1 blocks, the CPU, widths that are not whole tiles, paged
+    and mesh caches).
+
+    q_lat: (B, H, T, r); q_rope: (B, H, T, rd); ckv: (B, S, r); krope:
+    (B, S, rd); q_pos/k_pos/lengths/starts as in ``decode_attention_ref``.
+    Returns the softmax-weighted latent (B, H, T, r) float32."""
+    S = ckv.shape[1]
+    q_pos, lengths, starts = _norm_inputs(q_lat, q_pos, lengths, starts, S)
+    scores = (jnp.einsum("bhtr,bsr->bhts", q_lat.astype(jnp.float32),
+                         ckv.astype(jnp.float32))
+              + jnp.einsum("bhtd,bsd->bhts", q_rope.astype(jnp.float32),
+                           krope.astype(jnp.float32))) * scale
+    kp = k_pos[:, None, None, :]
+    qp = q_pos[:, None, :, None]
+    j = jnp.arange(S, dtype=jnp.int32)[None, None, None, :]
+    mask = ((kp >= 0) & (kp <= qp) & (j < lengths[:, None, None, None])
+            & (j >= starts[:, None, None, None]))
+    w = jax.nn.softmax(jnp.where(mask, scores, NEG_INF), axis=-1)
+    w = jnp.where(jnp.any(mask, axis=-1, keepdims=True), w, 0.0)
+    return jnp.einsum("bhts,bsr->bhtr", w, ckv.astype(jnp.float32))

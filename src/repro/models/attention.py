@@ -167,6 +167,19 @@ def _layer_view(stack, layer, table, width: int):
     return buf if table is None else _paged_gather(buf, table, width)
 
 
+def _live_bounds(B: int, T: int, cache_start, kv_length, kv_start):
+    """Per-row (lengths, starts) of a decode-shaped call: ``kv_length``,
+    else ``cache_start + T`` (the just-written block ends the live range);
+    ``kv_start`` or None (start at 0)."""
+    if kv_length is None:
+        kv_length = jnp.asarray(cache_start, jnp.int32) + T
+    lengths = jnp.broadcast_to(
+        jnp.asarray(kv_length, jnp.int32).reshape(-1), (B,))
+    starts = None if kv_start is None else jnp.broadcast_to(
+        jnp.asarray(kv_start, jnp.int32).reshape(-1), (B,))
+    return lengths, starts
+
+
 def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
                       window: int, cache_start, kv_length, kv_start,
                       use_pallas: bool, mesh=None, layer=None,
@@ -197,12 +210,7 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
                                                     reads_in_place)
     from .model import OP_COUNTS
     B, _, T = q.shape[:3]
-    if kv_length is None:
-        kv_length = jnp.asarray(cache_start, jnp.int32) + T
-    lengths = jnp.broadcast_to(
-        jnp.asarray(kv_length, jnp.int32).reshape(-1), (B,))
-    starts = None if kv_start is None else jnp.broadcast_to(
-        jnp.asarray(kv_start, jnp.int32).reshape(-1), (B,))
+    lengths, starts = _live_bounds(B, T, cache_start, kv_length, kv_start)
     if window > 0 and starts is not None:
         # contiguous layout (the kv_start contract): slot j holds position
         # j - start, so keys at or below start + q_pos - window are outside
@@ -559,10 +567,57 @@ def make_mla(key, cfg: ModelConfig, dtype):
     return p
 
 
+def _mla_decode(cfg: ModelConfig, wkv_b, q_nope, q_rope, ckv, krope,
+                q_pos, kv_pos, *, layer, table, cache_start, kv_length,
+                kv_start, mesh) -> jnp.ndarray:
+    """MLA decode in the absorbed form (DESIGN.md §15): each head's
+    no-position query goes through the K half of ``wkv_b``, scores are
+    taken against the cached latent (r) and the shared rotated key (rd),
+    the output over the latent, then through the V half of ``wkv_b``.
+    Nothing of the cache is decompressed.
+
+    ckv/krope are the run's stacked latent cache (or paged pools with
+    ``table``).  The ``decode_attention_mla`` kernel reads the layer in
+    place where it applies (a kernel impl, dense cache, no mesh, a width of
+    whole latent tiles); every other path takes the same arithmetic in jnp
+    on the layer's view.  ``OP_COUNTS`` counts each.
+    Returns (B, T, H * v_head_dim) float32."""
+    from repro.kernels.decode_attention.kernel import MLA_BLOCK_K
+    from repro.kernels.decode_attention.ops import (decode_attention_mla,
+                                                    reads_in_place)
+    from .model import OP_COUNTS
+    B, H, T, nd = q_nope.shape
+    r, vd = cfg.kv_lora_rank, cfg.v_head_dim
+    S = kv_pos.shape[-1]
+    w = wkv_b["kernel"].astype(q_nope.dtype).reshape(r, H, nd + vd)
+    q_lat = jnp.einsum("bhtn,rhn->bhtr", q_nope, w[..., :nd],
+                       preferred_element_type=jnp.float32)
+    lengths, starts = _live_bounds(B, T, cache_start, kv_length, kv_start)
+    impl = cfg.decode_impl
+    kernel = (impl in ("pallas", "interpret")
+              or (impl == "auto" and _default_backend() == "tpu"))
+    in_place = (kernel and table is None and mesh is None
+                and reads_in_place(S, MLA_BLOCK_K))
+    OP_COUNTS["decode_attn_inplace" if in_place else "decode_attn_sliced"] += 1
+    if not in_place:
+        ckv = _layer_view(ckv, layer, table, S)
+        krope = _layer_view(krope, layer, table, S)
+        layer, impl = None, "naive"
+    o = decode_attention_mla(q_lat, q_rope, ckv, krope, q_pos, kv_pos,
+                             lengths, starts, layer,
+                             scale=float(nd + cfg.qk_rope_head_dim) ** -0.5,
+                             impl=impl)
+    out = jnp.einsum("bhtr,rhv->bthv", o.astype(q_nope.dtype), w[..., nd:],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, T, H * vd)
+
+
 def apply_mla(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None,
               layer=None, causal=True, kv_length=None, kv_start=None,
               mesh=None):
-    """DeepSeek-V3 MLA; ``cache``/``layer`` as in ``apply_gqa``."""
+    """DeepSeek-V3 MLA; ``cache``/``layer`` as in ``apply_gqa``.  Decode
+    (a short cached query block) runs absorbed over the latent cache
+    (``_mla_decode``); prefill and full forwards decompress the latent."""
     B, T, _ = x.shape
     H = cfg.num_heads
     nd, rd, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -599,10 +654,17 @@ def apply_mla(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None
         pos_st = _cache_write(cache["pos"], positions.astype(jnp.int32),
                               cache_start, layer, axis=-1)
         new_cache = dict(cache, ckv=ckv_st, krope=krope_st, pos=pos_st)
-        # decompression needs the layer's whole logical view (DESIGN.md §7)
+        kv_pos = _layer(pos_st, layer)
+        if _decode_shaped(cache, None, causal, T, kv_length):
+            out = _mla_decode(cfg, p["wkv_b"], q_nope, q_rope, ckv_st,
+                              krope_st, positions, kv_pos, layer=layer,
+                              table=table, cache_start=cache_start,
+                              kv_length=kv_length, kv_start=kv_start,
+                              mesh=mesh)
+            return apply_dense(p["wo"], out.astype(x.dtype)), new_cache
+        # prefill decompresses the layer's whole logical view
         ckv = _layer_view(ckv_st, layer, table, S_log)
         k_rope = _layer_view(krope_st, layer, table, S_log)[:, None]
-        kv_pos = _layer(pos_st, layer)
 
     # decompress latent -> per-head K_nope and V
     kv = apply_dense(p["wkv_b"], ckv.astype(x.dtype))
@@ -612,20 +674,9 @@ def apply_mla(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope.astype(x.dtype),
                                                   (B, H, S, rd))], axis=-1)
     qfull = jnp.concatenate([q_nope, q_rope], axis=-1)
-
-    if _decode_shaped(cache, None, causal, T, kv_length):
-        # MLA decode: after latent decompression this is MHA (G = 1) with
-        # distinct Dk/Dv head dims — shapes the flash-decode kernel and its
-        # length-bounded blocked fallback both support (T > 1 packs the
-        # draft block into the sublane dim, §9).
-        out = _decode_attention(cfg, qfull, k, v, positions, kv_pos,
-                                window=0, cache_start=cache_start,
-                                kv_length=kv_length, kv_start=kv_start,
-                                use_pallas=False, mesh=mesh)
-    else:
-        out = dot_product_attention(qfull, k, v, positions, kv_pos,
-                                    window=0, causal=causal,
-                                    impl=cfg.attn_impl)
+    out = dot_product_attention(qfull, k, v, positions, kv_pos,
+                                window=0, causal=causal,
+                                impl=cfg.attn_impl)
     out = out.transpose(0, 2, 1, 3).reshape(B, T, H * vd)
     return apply_dense(p["wo"], out.astype(x.dtype)), new_cache
 
@@ -643,8 +694,8 @@ def apply_attention(p, cfg: ModelConfig, x, positions, **kw):
     if cfg.attention_kind == "mla":
         kw.pop("kv_x", None), kw.pop("kv_positions", None)
         # MLA prefill stays on the jnp path (mixed head dims defeat the
-        # prefill flash tiling); decode routes to the flash-decode op, which
-        # handles Dk != Dv, inside apply_mla.
+        # prefill flash tiling); decode routes to the latent decode op
+        # (cfg.decode_impl) inside apply_mla.
         kw.pop("use_pallas", None)
         return apply_mla(p, cfg, x, positions, **kw)
     return apply_gqa(p, cfg, x, positions, **kw)

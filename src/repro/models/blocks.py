@@ -152,7 +152,7 @@ def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
         if c is not None:
             new_cache["rwkv"] = _write_state(new_cache["rwkv"], c, layer)
     elif is_moe:
-        out, moe_aux = apply_moe(p["moe"], cfg, h)
+        out, moe_aux = apply_moe(p["moe"], cfg, h, valid=positions >= 0)
         aux.update(moe_aux)
     else:
         out = apply_ffn(p["mlp"], h, cfg.act)
@@ -194,12 +194,17 @@ def _maybe_remat(fn, cfg: ModelConfig):
     return fn
 
 
+# aux outputs that are counts: summed over layers, not averaged
+SUMMED_AUX = ("moe_held_tokens",)
+
+
 def apply_trunk(trunk_params, cfg: ModelConfig, x, positions, *,
                 caches=None, cache_start=None, encoder_out=None,
                 encoder_positions=None, use_pallas: bool = False,
                 causal: bool = True, kv_length=None, kv_start=None,
                 mesh=None):
-    """Run all layers.  Returns (x, new_caches, aux_mean).
+    """Run all layers.  Returns (x, new_caches, aux): each aux output's
+    mean over the layers that give it, a count (``SUMMED_AUX``) its sum.
 
     With ``caches``, each run's cache stack rides in the scan's carry and
     the scanned inputs are (params, layer index): every layer writes and
@@ -238,8 +243,9 @@ def apply_trunk(trunk_params, cfg: ModelConfig, x, positions, *,
                 (params, jnp.arange(run_len, dtype=jnp.int32)))
             new_caches.append(stack)
         for k, v in auxs.items():           # v: (run_len, ...) from scan ys
-            aux_sums[k] = aux_sums.get(k, 0.0) + jnp.sum(v, axis=0)
+            aux_sums[k] = aux_sums.get(k, 0) + jnp.sum(v, axis=0)
             aux_counts[k] = aux_counts.get(k, 0) + run_len
 
-    aux_mean = {k: aux_sums[k] / aux_counts[k] for k in aux_sums}
-    return x, new_caches, aux_mean
+    aux = {k: aux_sums[k] if k in SUMMED_AUX else aux_sums[k] / aux_counts[k]
+           for k in aux_sums}
+    return x, new_caches, aux

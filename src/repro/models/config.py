@@ -75,6 +75,14 @@ class ModelConfig:
     router_z_coef: float = 1e-4
     moe_impl: str = "dense"           # dense (exact) | dispatch (GShard einsum) | sort (argsort gather/scatter)
     moe_groups: int = 0               # dispatch groups (0 = one per sequence)
+    router_score: str = "softmax"     # softmax | sigmoid (deepseek-v3 noaux_tc: top-k
+                                      # chosen on sigmoid score + e_score_correction_bias)
+    routed_scaling: float = 1.0       # deepseek-v3 routed_scaling_factor
+    # expert parallelism: the experts this device holds of each MoE layer.
+    # The router keeps all num_experts; the layer computes only the held
+    # experts' part of the result (DESIGN.md §15)
+    held_expert_start: int = 0
+    num_held_experts: int = 0         # 0 = every expert held
 
     # -- hybrid / SSM layout -------------------------------------------------
     block_kind: str = ATTN            # default block type for all layers
@@ -136,6 +144,11 @@ class ModelConfig:
     def resolved_moe_d_ff(self) -> int:
         return self.moe_d_ff or self.d_ff
 
+    @property
+    def held_experts(self) -> int:
+        """Routed experts whose weights this device holds."""
+        return self.num_held_experts or self.num_experts
+
     def block_kind_for_layer(self, i: int) -> str:
         """Which block type layer ``i`` uses (jamba interleave etc.)."""
         if self.attn_period > 0:
@@ -184,6 +197,12 @@ class ModelConfig:
             assert self.kv_lora_rank > 0 and self.qk_rope_head_dim > 0
         if self.num_experts:
             assert 0 < self.num_experts_per_tok <= self.num_experts
+            assert self.router_score in ("softmax", "sigmoid"), self.router_score
+            assert 0 <= self.held_expert_start and (
+                self.held_expert_start + self.held_experts <= self.num_experts)
+            if self.held_experts < self.num_experts:
+                # capacity-bound paths would drop tokens of held experts
+                assert self.moe_impl == "dense", self.moe_impl
         if self.cross_attention:
             assert self.encoder_layers > 0
 
@@ -212,6 +231,8 @@ class ModelConfig:
                 num_experts_per_tok=min(self.num_experts_per_tok, 2, e),
                 moe_d_ff=min(self.resolved_moe_d_ff, 256),
                 first_dense_layers=min(self.first_dense_layers, 1),
+                held_expert_start=0,
+                num_held_experts=min(self.num_held_experts, e),
             )
         if self.attention_kind == "mla":
             changes.update(

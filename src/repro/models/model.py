@@ -37,8 +37,9 @@ def _dt(cfg: ModelConfig):
 # actual forwards executed — that is how the one-pass SPEC-RL benchmark/tests
 # assert "prompt ⊕ accepted prefix is forwarded exactly once per step".
 # ``decode_attn_inplace`` / ``decode_attn_sliced`` count decode-attention
-# calls that read the stacked K/V cache in place / read one layer's slice
-# of it (models/attention._decode_attention): which path a config takes.
+# calls that read the stacked K/V (or MLA latent) cache in place / read one
+# layer's view of it (models/attention._decode_attention, _mla_decode):
+# which path a config takes.
 OP_COUNTS = {"forward": 0, "prefill": 0, "decode_step": 0,
              "decode_attn_inplace": 0, "decode_attn_sliced": 0}
 
@@ -542,7 +543,7 @@ def prefill(params, cfg: ModelConfig, tokens, positions, caches, *,
 def decode_step(params, cfg: ModelConfig, token, position, caches, cache_start, *,
                 encoder_out=None, encoder_positions=None,
                 use_pallas: bool = False, kv_length=None, kv_start=None,
-                mesh=None):
+                mesh=None, with_aux: bool = False):
     """One decode step over a short token block.
 
     token: (B, T) with small T — 1 for classic decode, k + 1 for a §9
@@ -562,14 +563,18 @@ def decode_step(params, cfg: ModelConfig, token, position, caches, cache_start, 
     no vision prefix) so the kernel can also skip the dead left padding.
     mesh: optional live Mesh — decode attention then runs inside the §8
     shard_map boundary (batch over data, KV heads over model).
-    Returns (logits (B, 1, V), new_caches)."""
+    Returns (logits (B, 1, V), new_caches), and the trunk's aux outputs
+    third with ``with_aux`` (``moe_held_tokens``: the step's routed
+    assignments landing on held experts)."""
     OP_COUNTS["decode_step"] += 1
     x = _embed(params, cfg, token, position)
-    x, caches, _ = apply_trunk(params["trunk"], cfg, x, position,
-                               caches=caches, cache_start=cache_start,
-                               encoder_out=encoder_out,
-                               encoder_positions=encoder_positions,
-                               use_pallas=use_pallas, kv_length=kv_length,
-                               kv_start=kv_start, mesh=mesh)
+    x, caches, aux = apply_trunk(params["trunk"], cfg, x, position,
+                                 caches=caches, cache_start=cache_start,
+                                 encoder_out=encoder_out,
+                                 encoder_positions=encoder_positions,
+                                 use_pallas=use_pallas, kv_length=kv_length,
+                                 kv_start=kv_start, mesh=mesh)
     x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
+    if with_aux:
+        return _logits(params, cfg, x), caches, aux
     return _logits(params, cfg, x), caches

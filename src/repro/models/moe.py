@@ -16,8 +16,17 @@ Three MoE execution strategies:
               measured-and-refuted §Perf round-4 hypothesis; the TPU fix is
               megablox/ragged kernels.
 
+The layer may hold only some of the routed experts (``cfg.held_expert_start``
+/ ``num_held_experts``, expert parallelism, DESIGN.md §15): the router still
+scores all ``num_experts``, and ``dense`` computes the held experts' part of
+the result for the tokens routed to them, dropping none.
+The router is softmax top-k, or DeepSeek-V3's sigmoid scores chosen on
+score plus a correction bias (``cfg.router_score``).
+
 Aux losses (load-balance + router z-loss) are returned to the caller and
-added to the RL/pretrain objective.
+added to the RL/pretrain objective; ``moe_held_tokens`` counts the routed
+assignments of valid tokens that land on held experts (blocks.py sums it
+over layers).
 """
 from __future__ import annotations
 
@@ -61,7 +70,7 @@ def make_moe(key, cfg: ModelConfig, dtype):
     ks = split_keys(key, 5)
 
     def stack(k, ins, outs, scale=None):
-        keys = jax.random.split(k, E)
+        keys = jax.random.split(k, cfg.held_experts)
         return jnp.stack([make_dense(kk, ins, outs, False, dtype, scale)["kernel"]
                           for kk in keys])
 
@@ -71,17 +80,41 @@ def make_moe(key, cfg: ModelConfig, dtype):
         "w_up": stack(ks[2], d, ff),
         "w_down": stack(ks[3], ff, d, 1.0 / math.sqrt(ff)),
     }
+    if cfg.router_score == "sigmoid":
+        p["e_score_correction_bias"] = jnp.zeros((E,), dtype)
     if cfg.num_shared_experts:
         p["shared"] = make_ffn(ks[4], d, ff * cfg.num_shared_experts, dtype)
     return p
 
 
+def _sigmoid_router(p, cfg: ModelConfig, xf):
+    """DeepSeek-V3 routing (noaux_tc, one group): float32 sigmoid scores;
+    the top k of score + ``e_score_correction_bias`` are chosen and weighted
+    by their unbiased scores, normalised over the k (norm_topk_prob).
+    Returns (logits, probs for the aux losses, weights, idx)."""
+    logits = jnp.matmul(xf.astype(jnp.float32),
+                        p["router"]["kernel"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choice = scores + p["e_score_correction_bias"].astype(jnp.float32)
+    _, idx = jax.lax.top_k(choice, cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, idx, axis=-1)
+    weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return logits, scores / scores.sum(-1, keepdims=True), weights, idx
+
+
 def _router(p, cfg: ModelConfig, xf):
     """xf: (N, d) -> (weights (N,k), idx (N,k), aux dict)."""
-    logits = (xf @ p["router"]["kernel"].astype(xf.dtype)).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
-    weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if cfg.router_score == "sigmoid":
+        logits, probs, weights, idx = _sigmoid_router(p, cfg, xf)
+    else:
+        logits = (xf @ p["router"]["kernel"].astype(xf.dtype)
+                  ).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, idx = jax.lax.top_k(probs, cfg.num_experts_per_tok)
+        weights = weights / jnp.maximum(weights.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling != 1.0:
+        weights = weights * cfg.routed_scaling
 
     # Switch-style load-balance loss + z-loss.
     E = cfg.num_experts
@@ -103,19 +136,27 @@ def _experts_batched(p, xe, act_name):
     return jnp.einsum("ecf,efd->ecd", act(h) * u, p["w_down"].astype(xe.dtype))
 
 
+def _held_index(cfg: ModelConfig, idx):
+    """Routed expert ids -> ids among the held experts; an expert that is
+    not held falls outside [0, held_experts)."""
+    return idx - cfg.held_expert_start if cfg.held_expert_start else idx
+
+
 def _apply_moe_dense(p, cfg: ModelConfig, x):
     B, T, d = x.shape
     xf = x.reshape(-1, d)
     weights, idx, aux = _router(p, cfg, xf)
     act = activation(cfg.act)
-    # all experts on all tokens: (E, N, d)
+    # all held experts on all tokens: (E, N, d)
     h = jnp.einsum("nd,edf->enf", xf, p["w_gate"].astype(x.dtype))
     u = jnp.einsum("nd,edf->enf", xf, p["w_up"].astype(x.dtype))
     ye = jnp.einsum("enf,efd->end", act(h) * u, p["w_down"].astype(x.dtype))
-    onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=x.dtype)   # (N,k,E)
+    # (N,k,E); a routed expert that is not held has an all-zero row
+    onehot = jax.nn.one_hot(_held_index(cfg, idx), cfg.held_experts,
+                            dtype=x.dtype)
     combine = jnp.einsum("nke,nk->en", onehot, weights.astype(x.dtype))
     y = jnp.einsum("end,en->nd", ye, combine)
-    return y.reshape(B, T, d), aux
+    return y.reshape(B, T, d), (idx, aux)
 
 
 def _apply_moe_dispatch(p, cfg: ModelConfig, x):
@@ -151,7 +192,7 @@ def _apply_moe_dispatch(p, cfg: ModelConfig, x):
     y = jnp.einsum("gnkec,gecd->gnd", combine, ye)
     dropped = 1.0 - jnp.mean(jnp.sum(disp, axis=(2, 3)) > 0)
     aux["moe_drop_frac"] = dropped.astype(jnp.float32)
-    return y.reshape(B, T, d), aux
+    return y.reshape(B, T, d), (idx, aux)
 
 
 def _apply_moe_sort(p, cfg: ModelConfig, x):
@@ -188,16 +229,23 @@ def _apply_moe_sort(p, cfg: ModelConfig, x):
     w_s = weights.reshape(-1)[order].astype(x.dtype)
     y = jnp.zeros((N, d), x.dtype).at[tok_s].add(rows * w_s[:, None])
     aux["moe_drop_frac"] = (1.0 - keep.mean().astype(jnp.float32))
-    return y.reshape(B, T, d), aux
+    return y.reshape(B, T, d), (idx, aux)
 
 
-def apply_moe(p, cfg: ModelConfig, x):
+def apply_moe(p, cfg: ModelConfig, x, valid=None):
+    """x: (B, T, d); valid: optional (B, T) bool, the tokens that count in
+    ``moe_held_tokens`` (padding and finished rows do not)."""
     if cfg.moe_impl == "dispatch":
-        y, aux = _apply_moe_dispatch(p, cfg, x)
+        y, (idx, aux) = _apply_moe_dispatch(p, cfg, x)
     elif cfg.moe_impl == "sort":
-        y, aux = _apply_moe_sort(p, cfg, x)
+        y, (idx, aux) = _apply_moe_sort(p, cfg, x)
     else:
-        y, aux = _apply_moe_dense(p, cfg, x)
+        y, (idx, aux) = _apply_moe_dense(p, cfg, x)
+    e = _held_index(cfg, idx)
+    held = (e >= 0) & (e < cfg.held_experts)
+    if valid is not None:
+        held &= valid.reshape(-1, 1)
+    aux["moe_held_tokens"] = jnp.sum(held, dtype=jnp.int32)
     if "shared" in p:
         y = y + apply_ffn(p["shared"], x, cfg.act)
     return y, aux

@@ -321,3 +321,66 @@ def test_stacked_layer_read_is_bit_identical(S, T, window, layer):
     pads = _pad_operand_shapes(stacked, k_st, v_st, jnp.int32(layer))
     assert all(s[0] == 1 for s in pads if len(s) == 5), pads
     assert bool(pads) == (S % 128 != 0)
+
+
+# ----------------------------------------------------------------- latent
+
+
+def _mla_case(S, T, layer, seed, B=3, H=4, r=32, rd=8, nd=16, vd=16, L=5):
+    """Absorbed and decompressed operands of one MLA decode call over a
+    stacked latent cache (L layers), with _block_case's per-row masking
+    (a done row, a full block, left padding, live bounds)."""
+    _, _, _, q_pos, kpos, lengths, starts = _block_case(B, 1, 1, S, 8, T,
+                                                        seed=seed)
+    ks = jax.random.split(jax.random.PRNGKey(seed + layer), 5)
+    q_nope = jax.random.normal(ks[0], (B, H, T, nd))
+    q_rope = jax.random.normal(ks[1], (B, H, T, rd))
+    ckv = jax.random.normal(ks[2], (L, B, S, r))
+    krope = jax.random.normal(ks[3], (L, B, S, rd))
+    w = jax.random.normal(ks[4], (r, H, nd + vd)) * r ** -0.5
+    q_lat = jnp.einsum("bhtn,rhn->bhtr", q_nope, w[..., :nd])
+    return (q_nope, q_rope, q_lat, ckv, krope, w, q_pos, kpos, lengths,
+            starts)
+
+
+def _decompressed(q_nope, q_rope, ckv, krope, w, q_pos, kpos, lengths,
+                  starts, nd=16):
+    """Textbook MLA over one layer's latent: per-head K = [ckv W_UK, k_pe],
+    V = ckv W_UV, through the GQA oracle (scale (nd + rd)^-0.5)."""
+    H = q_nope.shape[1]
+    k = jnp.concatenate([jnp.einsum("bsr,rhn->bhsn", ckv, w[..., :nd]),
+                         jnp.broadcast_to(krope[:, None], (ckv.shape[0], H)
+                                          + krope.shape[1:])], -1)
+    v = jnp.einsum("bsr,rhv->bhsv", ckv, w[..., nd:])
+    return decode_attention_ref(jnp.concatenate([q_nope, q_rope], -1), k, v,
+                                q_pos, kpos, lengths, starts)
+
+
+@pytest.mark.parametrize("S,block_k", [(1280, 256), (1000, 40)])
+@pytest.mark.parametrize("T", [1, 3])
+@pytest.mark.parametrize("layer", [0, 2, 4])
+def test_mla_kernel_matches_absorbed_and_decompressed(S, block_k, T, layer):
+    """``decode_attention_mla`` in interpret mode reading a stacked latent
+    cache at its first, middle and last layer, against the jnp absorbed form
+    on that layer's slice and the decompressed textbook form: a width of
+    whole 256-slot tiles, and one (1000) that the model routes to the jnp
+    form (its kernel run here on 40-slot tiles)."""
+    from repro.kernels.decode_attention.ops import decode_attention_mla
+    (q_nope, q_rope, q_lat, ckv, krope, w, q_pos, kpos, lengths,
+     starts) = _mla_case(S, T, layer, seed=S + T)
+    scale = 24 ** -0.5
+    got = decode_attention_mla(q_lat, q_rope, ckv, krope, q_pos, kpos,
+                               lengths, starts, jnp.int32(layer),
+                               scale=scale, impl="interpret",
+                               block_k=block_k)
+    jnp_form = decode_attention_mla(q_lat, q_rope, ckv[layer], krope[layer],
+                                    q_pos, kpos, lengths, starts,
+                                    scale=scale, impl="naive")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(jnp_form),
+                               atol=2e-5, rtol=2e-5)
+    out = jnp.einsum("bhtr,rhv->bhtv", got, w[..., 16:])
+    want = _decompressed(q_nope, q_rope, ckv[layer], krope[layer], w, q_pos,
+                         kpos, lengths, starts)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-4,
+                               rtol=2e-4)
+    assert (np.asarray(got)[0] == 0.0).all()       # the done row

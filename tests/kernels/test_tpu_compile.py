@@ -17,7 +17,8 @@ from repro.kernels.cache_gather.kernel import (cache_roll_pallas,
                                                paged_gather_pallas)
 from repro.kernels.cache_slot_write.kernel import cache_slot_write_pallas
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_pallas, paged_decode_attention_pallas)
+    decode_attention_mla_pallas, decode_attention_pallas,
+    paged_decode_attention_pallas)
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.rwkv6_wkv.kernel import wkv_pallas
 from repro.kernels.spec_verify.kernel import spec_verify_pallas
@@ -160,6 +161,60 @@ def test_resume_decode_loop_moves_no_whole_cache(topo):
     slices = [i for i in loop if i[1] in layer and "dynamic-slice" in
               i[0] + i[2]]
     assert not slices, slices
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+@pytest.mark.parametrize("T", [1, 5])
+def test_decode_attention_mla_stacked(spec, T):
+    """The latent kernel at Moonlight-16B-A3B's widths (16 heads, latent
+    512, rope 64) over the benchmark cell's stacked latent cache (26 MoE
+    layers, 16 rows, 1280 slots), read at a layer given as a scalar."""
+    L, Bm, Sm = 26, 16, 1280
+    _compile(lambda *a: decode_attention_mla_pallas(*a, scale=192 ** -0.5),
+             spec((Bm, 16, T, 512), F32), spec((Bm, 16, T, 64), BF),
+             spec((L, Bm, Sm, 512), BF), spec((L, Bm, Sm, 64), BF),
+             spec((Bm,), I32), spec((Bm,), I32), spec((Bm, Sm), I32),
+             spec((Bm,), I32), spec((Bm,), I32), spec((), I32))
+
+
+def test_moonlight_resume_reads_the_latent_cache_in_place(topo):
+    """``resume_from_cache`` at the Moonlight reuse traffic's shapes
+    (Moonlight-16B-A3B, 8 of 64 experts and a 20480-word vocabulary
+    held, B=16, a 1280-slot latent cache written from slot 768): the decode
+    loop holds one latent kernel call per layer run and no whole-layer
+    latent slice or copy of a latent stack."""
+    from repro.engine.generate import GenerateConfig, resume_from_cache
+    from repro.models import model as M
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = get_config("moonlight-16b-a3b").replace(
+        vocab_size=20480, num_held_experts=8, decode_impl="pallas")
+    Bm, Sm = 16, 1280
+    as_spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             sharding=one_chip)
+    params = jax.tree.map(as_spec, jax.eval_shape(
+        lambda k: M.init_lm(k, cfg), jax.random.PRNGKey(0)))
+    caches = jax.tree.map(as_spec, jax.eval_shape(
+        lambda: M.init_cache(cfg, Bm, Sm)))
+    vec = lambda dt: jax.ShapeDtypeStruct((Bm,), dt, sharding=one_chip)
+    compiled = resume_from_cache.lower(
+        params, cfg, GenerateConfig(max_new_tokens=512), caches,
+        jax.ShapeDtypeStruct((Bm, cfg.vocab_size), F32, sharding=one_chip),
+        vec(I32), 768, jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                            sharding=one_chip),
+        vec(jnp.bool_), vec(I32)).compile()
+    lines = [ln for c in _loop_computations(compiled.as_text()).values()
+             for ln in c]
+    # the dense layer's call (its run of one is inlined) and the MoE run's
+    kernels = [ln for ln in lines
+               if re.match(r"\s*(?:ROOT )?%decode_attention", ln)]
+    assert len(kernels) == 2 and all("custom-call(" in ln for ln in kernels)
+    loop = [m.groups() for ln in lines for m in [INSTR.match(ln)] if m]
+    for n, r in ((1, 512), (26, 512), (1, 64), (26, 64)):
+        stack = f"bf16[{n},{Bm},{Sm},{r}]"
+        assert not [i for i in loop if i[1] == stack and i[2] == "copy"]
+        layer = (f"bf16[{Bm},{Sm},{r}]", f"bf16[1,{Bm},{Sm},{r}]")
+        assert not [i for i in loop if i[1] in layer and "dynamic-slice"
+                    in i[0] + i[2]]
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
 
 

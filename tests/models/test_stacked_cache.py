@@ -65,12 +65,37 @@ def test_other_paths_read_one_layer_slice(impl, S, mesh):
     assert counts["decode_attn_inplace"] == 0
 
 
+def _mla_cfg():
+    return _cfg(decode_impl="interpret", attention_kind="mla",
+                q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
+                qk_rope_head_dim=8, v_head_dim=16, qk_norm=False)
+
+
 def test_mla_decompresses_a_layer_slice():
-    cfg = _cfg(decode_impl="interpret", attention_kind="mla",
-               q_lora_rank=32, kv_lora_rank=32, qk_nope_head_dim=16,
-               qk_rope_head_dim=8, v_head_dim=16, qk_norm=False)
-    counts, _, _ = _decode_once(cfg, 64)
+    """MLA decode no longer decompresses anything: at a width that is not a
+    whole number of 256-slot latent tiles it reads the layer's latent slice
+    (the jnp absorbed form)."""
+    counts, _, _ = _decode_once(_mla_cfg(), 300)
     assert counts["decode_attn_sliced"] == L
+    assert counts["decode_attn_inplace"] == 0
+
+
+@pytest.mark.parametrize("S", [64, 256])
+def test_mla_latent_kernel_reads_the_stack_in_place(S):
+    """At a width of whole latent tiles (one short tile, or 256 slots) the
+    latent kernel reads each layer's latent out of the stack; the result
+    is the jnp absorbed form's on the layer slices, to float32 rounding
+    (the two sum the split partials in another order)."""
+    counts, logits, caches = _decode_once(_mla_cfg(), S)
+    assert counts["decode_attn_inplace"] == L
+    assert counts["decode_attn_sliced"] == 0
+    _, want, want_caches = _decode_once(_mla_cfg().replace(
+        decode_impl="naive"), S)
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(caches), jax.tree.leaves(want_caches)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5,
+                                   rtol=1e-5)
 
 
 def test_paged_kernel_reads_the_stacked_pools_in_place():
