@@ -9,35 +9,40 @@ empty.  This kernel is specialised for the decode shape instead:
 * **Head×query packing.**  The ``G = Hq / Hkv`` query heads that share one
   KV head, times the T block queries (T == 1 for classic decode, k + 1 for
   a draft-verify block), are packed into the MXU *sublane* dimension, so
-  each KV tile is consumed by one ``(G·T, Dk) × (Dk, block_k)`` matmul
-  rather than G·T single-row tiles, and each KV block is fetched exactly
-  once per group.
+  each KV head's tile is consumed by one ``(G·T, Dk) × (Dk, block_k)``
+  matmul rather than G·T single-row tiles, and each KV tile is fetched
+  exactly once per group.
 
-* **Split-K.**  The grid is ``(B, Hkv, S / block_k)`` — cache slots are
-  *split* across programs.  Each program emits an online-softmax partial
-  (row max ``m``, row sum ``l``, unnormalised accumulator ``acc``) for its
-  slot range; a cheap second-stage jnp combine (`_combine`) merges the
-  partials with the standard logsumexp rescaling.  Splits are independent,
-  so there is no sequential scratch carry and the (tiny-T) grid parallelism
-  lost to small ``block_q`` is recovered across the split axis.
+* **Split-K over all KV heads.**  The grid is ``(B, S / block_k)``: one
+  program reads all ``Hkv`` heads of one row over one slot tile (cache
+  slots are *split* across programs).  For each head it emits an
+  online-softmax partial (row max ``m``, row sum ``l``, unnormalised
+  accumulator ``acc``) for its slot range; a cheap second-stage jnp combine
+  (`_combine`) merges the partials with the standard logsumexp rescaling.
+  A program's fixed cost (grid step, DMA issue) is what a decode call pays
+  for, not its bytes, so the tile is as wide as the shapes allow
+  (`decode_block_k`): the widest of 512, 256 and 128 slots that divides S
+  and keeps one K + V block within ``KV_BLOCK_BYTES``.
 
 * **Per-row early exit.**  Per-row live bounds arrive via scalar prefetch:
   ``lengths`` (write offset + block width — essential for the serving slot
   engine, whose rows sit at different decode depths) and ``starts`` (the
   first live slot — the §3 compacted layout right-aligns context at the
   verify width, so a short accepted prefix has a dead left-pad region in
-  front of it).  A split whose slot range falls outside
-  [starts[b], lengths[b]) redirects its K/V/k_pos block DMAs to block 0
-  (already resident — no HBM traffic) and skips the matmul entirely,
-  writing the softmax-neutral partial (m=-inf, l=0, acc=0).
+  front of it).  A split is live when its slot range meets
+  [starts[b], lengths[b]) and the row has a live query; a dead split skips
+  the matmuls and writes the softmax-neutral partial (m=-inf, l=0, acc=0).
+  Its K/V/k_pos DMAs read the row's nearest live tile (`_kv_tile`: the
+  first for the dead left pad, the last for the empty tail), the tile the
+  neighbouring program reads too, so the pipeline elides the copy.
 
 * **Stacked cache, read in place.**  ``k``/``v`` may be a whole run's
   stacked cache ``(L, B, Hkv, S, D)`` with the layer as one more scalar
-  prefetch: the K/V index maps address ``(layer, b, h, split)`` with the
+  prefetch: the K/V index maps address ``(layer, b, :, split)`` with the
   layer axis squeezed, so a decode step reads its layer's live tiles
   straight out of the stack instead of a copied layer slice (DESIGN.md §3).
-  A width that is not a whole number of blocks pads one layer's slice,
-  never the stack (``reads_in_place``).
+  A width that is not a whole number of 128-slot tiles pads one layer's
+  slice, never the stack (``reads_in_place``).
 
 * **Query-block contract.**  Query positions arrive as two scalars per
   row — ``q_pos0[b]`` (position of query 0) and ``q_len[b]`` (number of
@@ -63,6 +68,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+# one K + V block of the dense kernel at most: double-buffered, and for a
+# bf16 cache upcast to float32, it takes four times this (8 MiB), well
+# inside v5e's default scoped VMEM (16 MiB)
+KV_BLOCK_BYTES = 2 * 1024 * 1024
+TILE_WIDTHS = (512, 256, 128)
 
 
 def _neutral(m_ref, l_ref, acc_ref):
@@ -99,14 +110,35 @@ def _write_partial(s, mask, v, m_ref, l_ref, acc_ref):
         p, v, preferred_element_type=jnp.float32)        # (rows, Dv)
 
 
-def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
-                   kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *,
-                   scale: float, window: int, block_k: int, T: int):
-    del layer_ref                    # read by the K/V index maps only
-    b = pl.program_id(0)
-    s_i = pl.program_id(2)
+def _split_live(s, block_k: int, length, start, qlen):
+    """Whether split s of a row is live: its slots meet the row's live
+    range [start, length) and the row has a live query."""
+    return (s * block_k < length) & ((s + 1) * block_k > start) & (qlen > 0)
+
+
+def _kv_tile(s, block_k: int, nsplit: int, length, start, qlen):
+    """The tile split s of a row reads: s itself while live; a dead split
+    reads the row's nearest live tile instead (the first for the dead left
+    pad, the last for the empty tail), which the neighbouring program of
+    the row reads too, so the pipeline elides the copy.  A row with no
+    live tile reads tile ``start // block_k`` in every split."""
+    first = jnp.minimum(start // block_k, nsplit - 1)
+    row_live = (start < length) & (qlen > 0)
+    last = jnp.where(row_live, (length - 1) // block_k, first)
+    return jnp.clip(s, first, last)
+
+
+def _decode_body(b, s_i, len_ref, start_ref, qpos0_ref, qlen_ref, kpos_ref,
+                 q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref, *, scale: float,
+                 window: int, block_k: int, T: int):
+    """One program: split ``s_i`` of row ``b`` for every KV head of the
+    block (q: (1, H, G*T, Dk); k/v: (1, H, block_k, D); partials (1, H, 1,
+    G*T, .)), each head's partial as `_write_partial` makes it, the H heads
+    as the batch of one dot_general (faster on v5e than a loop over them).
+    The slot mask depends on the row and split alone, so the heads share
+    it."""
     start = s_i * block_k
-    live = (start < len_ref[b]) & (start + block_k > start_ref[b])
+    live = _split_live(s_i, block_k, len_ref[b], start_ref[b], qlen_ref[b])
 
     @pl.when(jnp.logical_not(live))
     def _dead():
@@ -114,16 +146,46 @@ def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
 
     @pl.when(live)
     def _live():
-        q = q_ref[0, 0].astype(jnp.float32)              # (G*T, Dk)
-        k = k_ref[0, 0].astype(jnp.float32)              # (bk, Dk)
-        v = v_ref[0, 0].astype(jnp.float32)              # (bk, Dv)
-        GT = q.shape[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        GT = q_ref.shape[2]
         kpos = kpos_ref[0, 0].astype(jnp.int32)          # (1, bk)
         mask = _slot_mask(kpos, b, start, len_ref, start_ref, qpos0_ref,
-                          qlen_ref, (GT, block_k), T, window)
-        _write_partial(s, mask, v, m_ref, l_ref, acc_ref)
+                          qlen_ref, (GT, block_k), T, window)[None]
+        q = q_ref[0].astype(jnp.float32)                 # (H, G*T, Dk)
+        k = k_ref[0].astype(jnp.float32)                 # (H, bk, Dk)
+        v = v_ref[0].astype(jnp.float32)                 # (H, bk, Dv)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(mask, s, NEG_INF)                  # (H, G*T, bk)
+        m = jnp.max(s, axis=2, keepdims=True)
+        p = jnp.where(mask, jnp.exp(s - m), 0.0)
+        m_ref[0, :, 0] = m
+        l_ref[0, :, 0] = jnp.sum(p, axis=2, keepdims=True)
+        acc_ref[0, :, 0] = jax.lax.dot_general(
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)          # (H, G*T, Dv)
+
+
+def _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
+                   kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
+                   **kw):
+    del layer_ref                    # read by the K/V index maps only
+    _decode_body(pl.program_id(0), pl.program_id(1), len_ref, start_ref,
+                 qpos0_ref, qlen_ref, kpos_ref, q_ref, k_ref, v_ref, m_ref,
+                 l_ref, acc_ref, **kw)
+
+
+def decode_block_k(S: int, Hkv: int, Dk: int, Dv: int, itemsize: int) -> int:
+    """Slots one dense decode program reads for all Hkv heads: the widest
+    of ``TILE_WIDTHS`` that divides S and keeps one K + V block within
+    ``KV_BLOCK_BYTES`` (128 where none does).  A width that is not a whole
+    number of 128-slot tiles reads ``min(S, 128)``-slot tiles of a padded
+    layer slice."""
+    if S % 128:
+        return min(S, 128)
+    for bk in TILE_WIDTHS:
+        if S % bk == 0 and bk * Hkv * (Dk + Dv) * itemsize <= KV_BLOCK_BYTES:
+            return bk
+    return 128
 
 
 def reads_in_place(S: int, block_k: int = 128) -> bool:
@@ -166,13 +228,14 @@ def _combine(m, l, acc):
 
 def _paged_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
                   table_ref, kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref,
-                  acc_ref, *, scale: float, window: int, block_k: int, T: int):
-    # identical math to the dense kernel — the block table only redirects
-    # the K/V DMAs (see the index maps in paged_decode_attention_pallas)
-    del table_ref
-    _decode_kernel(len_ref, start_ref, qpos0_ref, qlen_ref, layer_ref,
-                   kpos_ref, q_ref, k_ref, v_ref, m_ref, l_ref, acc_ref,
-                   scale=scale, window=window, block_k=block_k, T=T)
+                  acc_ref, **kw):
+    # the dense body on one KV head a block (grid (B, Hkv, nb)) — the block
+    # table only redirects the K/V DMAs (see the index maps in
+    # paged_decode_attention_pallas)
+    del layer_ref, table_ref
+    _decode_body(pl.program_id(0), pl.program_id(2), len_ref, start_ref,
+                 qpos0_ref, qlen_ref, kpos_ref, q_ref, k_ref, v_ref, m_ref,
+                 l_ref, acc_ref, **kw)
 
 
 def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
@@ -256,7 +319,7 @@ def paged_decode_attention_pallas(q, k_pool, v_pool, table, q_pos0, q_len,
 
 
 def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
-                            layer=None, *, window: int = 0, block_k: int = 128,
+                            layer=None, *, window: int = 0,
                             interpret: bool = False):
     """q: (B, Hq, T, Dk); k: (B, Hkv, S, Dk); v: (B, Hkv, S, Dv);
     q_pos0/q_len: (B,) int32 query-block descriptors (query t lives at
@@ -266,13 +329,15 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
     are a run's stacked cache (L, B, Hkv, S, D), read in place at that
     layer; k_pos is that layer's positions.
 
-    Returns (B, Hq, T, Dv) float32.  Dk and Dv may differ (MLA)."""
+    The grid is (B, S / block_k), block_k from ``decode_block_k``: one
+    program per (row, split) over all Hkv heads.  Returns (B, Hq, T, Dv)
+    float32.  Dk and Dv may differ (MLA)."""
     B, Hq, T, Dk = q.shape
     k, v, layer = _as_stack(k, v, layer)
     Hkv, S = k.shape[2], k.shape[3]
     Dv = v.shape[-1]
     G = Hq // Hkv
-    block_k = min(block_k, S)
+    block_k = decode_block_k(S, Hkv, Dk, Dv, k.dtype.itemsize)
     pad_s = (-S) % block_k
     if pad_s:
         # a partial last tile: pad the layer's slice, never the stack
@@ -288,35 +353,33 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
     qg = q.reshape(B, Hkv, G, T, Dk).reshape(B, Hkv, G * T, Dk)
     scale = 1.0 / (Dk ** 0.5)
 
-    def _live_split(s, len_ref, start_ref, b):
-        return (s * block_k < len_ref[b]) & ((s + 1) * block_k > start_ref[b])
+    def _tile(b, s, len_ref, start_ref, qlen_ref):
+        return _kv_tile(s, block_k, nsplit, len_ref[b], start_ref[b],
+                        qlen_ref[b])
 
-    def _kv_block(b, h, s, len_ref, start_ref, qp_ref, ql_ref, layer_ref):
-        # early exit: dead splits re-fetch block 0 instead of streaming the
-        # dead left-pad / empty tail (same-block DMA is elided)
-        return (layer_ref[0], b, h,
-                jnp.where(_live_split(s, len_ref, start_ref, b), s, 0), 0)
+    def _kv_block(b, s, len_ref, start_ref, qp_ref, ql_ref, layer_ref):
+        return (layer_ref[0], b, 0, _tile(b, s, len_ref, start_ref, ql_ref),
+                0)
 
-    def _kpos_block(b, h, s, len_ref, start_ref, *_):
-        return (b, jnp.where(_live_split(s, len_ref, start_ref, b), s, 0),
-                0, 0)
+    def _kpos_block(b, s, len_ref, start_ref, qp_ref, ql_ref, *_):
+        return (b, _tile(b, s, len_ref, start_ref, ql_ref), 0, 0)
+
+    def _out(b, s, *_):
+        return (b, 0, s, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,
-        grid=(B, Hkv, nsplit),
+        grid=(B, nsplit),
         in_specs=[
             pl.BlockSpec((1, 1, 1, block_k), _kpos_block),
-            pl.BlockSpec((1, 1, G * T, Dk), lambda b, h, s, *_: (b, h, 0, 0)),
-            pl.BlockSpec((None, 1, 1, block_k, Dk), _kv_block),  # layer squeezed
-            pl.BlockSpec((None, 1, 1, block_k, Dv), _kv_block),
+            pl.BlockSpec((1, Hkv, G * T, Dk), lambda b, s, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((None, 1, Hkv, block_k, Dk), _kv_block),  # layer squeezed
+            pl.BlockSpec((None, 1, Hkv, block_k, Dv), _kv_block),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, 1, G * T, 1),
-                         lambda b, h, s, *_: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G * T, 1),
-                         lambda b, h, s, *_: (b, h, s, 0, 0)),
-            pl.BlockSpec((1, 1, 1, G * T, Dv),
-                         lambda b, h, s, *_: (b, h, s, 0, 0)),
+            pl.BlockSpec((1, Hkv, 1, G * T, 1), _out),
+            pl.BlockSpec((1, Hkv, 1, G * T, 1), _out),
+            pl.BlockSpec((1, Hkv, 1, G * T, Dv), _out),
         ],
     )
     m, l, acc = pl.pallas_call(
@@ -329,6 +392,7 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
             jax.ShapeDtypeStruct((B, Hkv, nsplit, G * T, Dv), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), starts.astype(jnp.int32),
       q_pos0.astype(jnp.int32), q_len.astype(jnp.int32), layer,
       _split_tiles(k_pos, nsplit, block_k), qg, k, v)

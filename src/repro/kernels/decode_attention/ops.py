@@ -73,7 +73,8 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None,
 
     impl: 'auto' (pallas on TPU; elsewhere naive for S <= NAIVE_MAX_S,
     length-bounded blocked beyond) | 'pallas' | 'interpret' | 'blocked' |
-    'naive'.
+    'naive'.  ``block_k`` is the blocked path's chunk; the kernel chooses
+    its tile from the shapes (``kernel.decode_block_k``).
     """
     S = k_pos.shape[1]
     if impl == "auto":
@@ -95,7 +96,6 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None,
                                                     B, T, S)
     return decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos,
                                    lengths, starts, layer, window=window,
-                                   block_k=block_k,
                                    interpret=(impl == "interpret"))
 
 

@@ -199,12 +199,14 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
     with ``table`` (this layer's block table) its stacked paged pools: the
     flash-decode kernel reads the layer in place; every other path (the
     jnp impls, the mesh wrapper, a width that is not a whole number of
-    kernel tiles) reads the layer's slice.  ``OP_COUNTS`` counts each.
+    kernel tiles) reads the layer's slice.  ``OP_COUNTS`` counts each, and
+    the dense kernel's slot tile (``decode_attn_tile_<width>``).
 
     ``mesh`` routes the call through the shard_map boundary (DESIGN.md §8):
     each device runs the kernel on its local (batch, head) block with a
     static per-shard shape instead of leaving a Pallas black box to GSPMD.
     """
+    from repro.kernels.decode_attention.kernel import decode_block_k
     from repro.kernels.decode_attention.ops import (decode_attention,
                                                     paged_decode_attention,
                                                     reads_in_place)
@@ -227,8 +229,14 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
     # kernel is taken only when named, "auto" reads the gathered view
     kernel = impl in ("pallas", "interpret")
     if table is None:
-        kernel = ((kernel or (impl == "auto" and _default_backend() == "tpu"))
-                  and reads_in_place(kv_pos.shape[-1]))
+        kernel = kernel or (impl == "auto" and _default_backend() == "tpu")
+        if kernel and mesh is None:
+            # the slot tile the dense kernel takes from these shapes
+            tile = "decode_attn_tile_%d" % decode_block_k(
+                kv_pos.shape[-1], k.shape[-3], k.shape[-1], v.shape[-1],
+                jnp.dtype(q.dtype).itemsize)
+            OP_COUNTS[tile] = OP_COUNTS.get(tile, 0) + 1
+        kernel = kernel and reads_in_place(kv_pos.shape[-1])
     in_place = layer is not None and mesh is None and kernel
     OP_COUNTS["decode_attn_inplace" if in_place else "decode_attn_sliced"] += 1
     if layer is not None and not in_place:

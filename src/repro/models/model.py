@@ -39,9 +39,12 @@ def _dt(cfg: ModelConfig):
 # ``decode_attn_inplace`` / ``decode_attn_sliced`` count decode-attention
 # calls that read the stacked K/V (or MLA latent) cache in place / read one
 # layer's view of it (models/attention._decode_attention, _mla_decode):
-# which path a config takes.
+# which path a config takes.  ``decode_attn_tile_<width>`` counts the dense
+# GQA kernel's calls by the slot tile it takes from the shapes.
 OP_COUNTS = {"forward": 0, "prefill": 0, "decode_step": 0,
-             "decode_attn_inplace": 0, "decode_attn_sliced": 0}
+             "decode_attn_inplace": 0, "decode_attn_sliced": 0,
+             "decode_attn_tile_128": 0, "decode_attn_tile_256": 0,
+             "decode_attn_tile_512": 0}
 
 
 def reset_op_counts() -> None:

@@ -1,6 +1,8 @@
 """decode_attention kernel: interpret-mode split-K sweep vs the jnp oracle
 across GQA/MQA ratios, sliding windows and per-row live lengths (empty rows,
-rows at S-1, mixed depths), plus agreement with the legacy naive decode."""
+rows at S-1, mixed depths), plus agreement with the legacy naive decode.
+Widths of 384, 768, 1280 and 1024 slots give the kernel 128-, 256- and
+512-slot tiles over all KV heads of a row (``decode_block_k``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -48,6 +50,10 @@ def _case(B, Hq, Hkv, S, D, Dv=None, seed=0):
     (3, 8, 1, 48, 8),           # MQA
     (3, 4, 4, 33, 16),          # MHA, non-divisible S
     (4, 6, 3, 96, 32),          # GQA 2x, wider
+    (3, 2, 1, 384, 16),         # MQA, three 128-slot tiles
+    (3, 4, 2, 768, 16),         # GQA 2x, three 256-slot tiles
+    (3, 16, 8, 1280, 16),       # qwen3's 16/8 heads, five 256-slot tiles
+    (3, 16, 8, 1024, 16),       # two 512-slot tiles
 ])
 @pytest.mark.parametrize("window", [0, 16])
 def test_split_k_matches_ref(B, Hq, Hkv, S, D, window):
@@ -219,6 +225,9 @@ def _block_case(B, Hq, Hkv, S, D, T, Dv=None, seed=0):
     (4, 4, 2, 64, 16, 5),       # GQA 2x, draft_k = 4
     (3, 8, 1, 48, 8, 3),        # MQA
     (3, 4, 4, 40, 16, 2),       # MHA
+    (3, 2, 1, 384, 16, 5),      # MQA, 128-slot tiles
+    (3, 16, 8, 1280, 16, 5),    # qwen3's 16/8 heads, 256-slot tiles
+    (3, 4, 2, 1024, 16, 5),     # 512-slot tiles
 ])
 @pytest.mark.parametrize("window", [0, 16])
 def test_block_query_matches_ref(B, Hq, Hkv, S, D, T, window):
@@ -295,15 +304,16 @@ def _pad_operand_shapes(fn, *args):
     return shapes
 
 
-@pytest.mark.parametrize("S", [1280, 202])
+@pytest.mark.parametrize("S", [1280, 202, 1024])
 @pytest.mark.parametrize("T", [1, 5])
 @pytest.mark.parametrize("window", [0, 16])
 @pytest.mark.parametrize("layer", [0, 2, 4])
 def test_stacked_layer_read_is_bit_identical(S, T, window, layer):
     """The kernel on a stacked cache (L=5) at layer l gives the same bits as
     the kernel on that layer's slice: first, middle and last layer, decode
-    and draft blocks, a width of whole tiles (1280) and a trainer width
-    that is not (202, whose padding must touch one layer, not the stack)."""
+    and draft blocks, widths of whole 256- and 512-slot tiles (1280, 1024)
+    and a trainer width that is not (202, whose padding must touch one
+    layer, not the stack)."""
     L = 5
     q, k, v, q_pos, kpos, lengths, starts = _block_case(3, 4, 2, S, 16, T,
                                                         seed=S + T)
@@ -321,6 +331,85 @@ def test_stacked_layer_read_is_bit_identical(S, T, window, layer):
     pads = _pad_operand_shapes(stacked, k_st, v_st, jnp.int32(layer))
     assert all(s[0] == 1 for s in pads if len(s) == 5), pads
     assert bool(pads) == (S % 128 != 0)
+
+
+# ------------------------------------------------------- tiles (DESIGN.md §7)
+
+
+@pytest.mark.parametrize("S,Hkv,D,itemsize,want", [
+    (1280, 8, 128, 2, 256),     # q06b-spec-reuse: 512 does not divide 1280
+    (768, 8, 128, 2, 256),      # the first-epoch cells
+    (1024, 8, 128, 2, 512),     # 512 slots x 8 heads x 2 x 128 x bf16: 2 MiB
+    (384, 8, 128, 2, 128),
+    (4096, 16, 128, 2, 256),    # 512 would hold 4 MiB
+    (4096, 64, 128, 2, 128),    # even 128 is over the budget: the floor
+    (1024, 8, 128, 4, 256),     # float32 caches take half the slots
+    (1024, 1, 16, 4, 512),
+    (202, 8, 128, 2, 128),      # not whole 128-slot tiles: padded
+    (64, 2, 16, 4, 64),         # one short tile
+])
+def test_tile_is_the_widest_that_divides_and_fits(S, Hkv, D, itemsize, want):
+    from repro.kernels.decode_attention.kernel import (KV_BLOCK_BYTES,
+                                                       TILE_WIDTHS,
+                                                       decode_block_k)
+    bk = decode_block_k(S, Hkv, D, D, itemsize)
+    assert bk == want
+    if bk > 128:
+        assert S % bk == 0
+        assert bk * Hkv * 2 * D * itemsize <= KV_BLOCK_BYTES
+        wider = [w for w in TILE_WIDTHS if w > bk and S % w == 0]
+        assert all(w * Hkv * 2 * D * itemsize > KV_BLOCK_BYTES
+                   for w in wider)
+
+
+@pytest.mark.parametrize("block_k,nsplit", [(256, 5), (128, 6), (512, 2)])
+def test_dead_splits_read_the_nearest_live_tile(block_k, nsplit):
+    """Every (start, length, q_len) of a row: a live split reads its own
+    tile; a dead split in front of the live range reads the first live
+    tile and one behind it the last, so no dead split reads a tile that no
+    live split reads (tile 0 only where it is live).  A row with no live
+    tile reads one tile in every split."""
+    from repro.kernels.decode_attention.kernel import _kv_tile, _split_live
+    S = block_k * nsplit
+    edges = sorted({0, 1, block_k - 1, block_k, block_k + 1, S // 2,
+                    S - block_k, S - 1, S})
+    s = jnp.arange(nsplit, dtype=jnp.int32)
+    for start in edges:
+        for length in edges:
+            for qlen in (0, 1):
+                live = np.asarray(_split_live(s, block_k, length, start,
+                                              qlen))
+                tile = np.asarray(_kv_tile(s, block_k, nsplit, length,
+                                           start, qlen))
+                assert ((0 <= tile) & (tile < nsplit)).all()
+                if not live.any():
+                    assert len(set(tile.tolist())) == 1
+                    continue
+                lo, hi = np.flatnonzero(live)[[0, -1]]
+                np.testing.assert_array_equal(tile, np.clip(np.arange(
+                    nsplit), lo, hi))
+                assert live[tile].all()
+
+
+def test_qwen3_06b_decode_step_takes_256_slot_tiles():
+    """A decode step at qwen3-0.6b's attention widths (16/8 heads of 128,
+    bf16) over the reuse cell's 1280-slot cache: every layer's dense kernel
+    call takes 256-slot tiles."""
+    from repro.configs import get_config
+    from repro.models import model as M
+    cfg = get_config("qwen3-0.6b").replace(num_layers=2, vocab_size=256,
+                                           decode_impl="interpret")
+    params = jax.eval_shape(lambda k: M.init_lm(k, cfg),
+                            jax.random.PRNGKey(0))
+    caches = jax.eval_shape(lambda: M.init_cache(cfg, 8, 1280))
+    tok = jax.ShapeDtypeStruct((8, 1), jnp.int32)
+    M.reset_op_counts()
+    jax.eval_shape(lambda p, t, c: M.decode_step(p, cfg, t, t, c, 768),
+                   params, tok, caches)
+    counts = dict(M.OP_COUNTS)
+    assert counts["decode_attn_tile_256"] == counts["decode_attn_inplace"]
+    assert counts["decode_attn_tile_256"] > 0
+    assert counts["decode_attn_tile_128"] == counts["decode_attn_tile_512"] == 0
 
 
 # ----------------------------------------------------------------- latent
