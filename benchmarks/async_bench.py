@@ -55,8 +55,7 @@ def _make_trainer(max_new_tokens: int, variant: str = "spec") -> Trainer:
     rl = RLConfig(algo="grpo", group_size=2, prompts_per_batch=4,
                   max_new_tokens=max_new_tokens, optim=AdamWConfig(lr=1e-3),
                   max_resample_rounds=1)
-    spec = SpecConfig(variant=variant, lenience=math.e ** 0.5,
-                      verify_impl="ref")
+    spec = SpecConfig(variant=variant, lenience=math.e ** 0.5)
     return Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(0))
 
 

@@ -53,7 +53,7 @@ def make_trainer(algo: str = "grpo", variant: str = "spec",
     rl = RLConfig(algo=algo, group_size=2, prompts_per_batch=4,
                   max_new_tokens=max_new_tokens, optim=AdamWConfig(lr=5e-4),
                   max_resample_rounds=1)
-    spec = SpecConfig(variant=variant, lenience=lenience, verify_impl="ref")
+    spec = SpecConfig(variant=variant, lenience=lenience)
     return Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(seed))
 
 

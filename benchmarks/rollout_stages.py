@@ -47,8 +47,7 @@ def _spec(one_pass: str) -> SpecConfig:
     # lenience < 1 with an unchanged policy gives per-token accept prob == l,
     # i.e. a realistic partial-acceptance mix of reused and regenerated
     # tokens (full acceptance would degenerate decode_time to ~0).
-    return SpecConfig(variant="spec", lenience=0.8, verify_impl="ref",
-                      one_pass=one_pass, compact_impl="ref")
+    return SpecConfig(variant="spec", lenience=0.8, one_pass=one_pass)
 
 
 def _time_path(cfg, params, prompt, mask, gen, cache, one_pass: str,

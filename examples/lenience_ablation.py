@@ -38,8 +38,7 @@ def run_one(lenience, steps, seed=0):
     elif math.isinf(lenience):
         spec = SpecConfig(variant="full")
     else:
-        spec = SpecConfig(variant="spec", lenience=lenience,
-                          verify_impl="ref")
+        spec = SpecConfig(variant="spec", lenience=lenience)
     tr = Trainer(model, rl, spec, ds, jax.random.PRNGKey(seed))
     rewards, prefixes = [], []
     for _ in range(steps):

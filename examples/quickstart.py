@@ -28,8 +28,7 @@ def main():
     dataset = PromptDataset(problems, max_prompt_len=10)
     rl = RLConfig(algo="grpo", group_size=4, prompts_per_batch=4,
                   max_new_tokens=10, optim=AdamWConfig(lr=1e-3))
-    spec = SpecConfig(variant="spec", lenience=math.e ** 0.5,
-                      verify_impl="ref")
+    spec = SpecConfig(variant="spec", lenience=math.e ** 0.5)
 
     trainer = Trainer(model, rl, spec, dataset, jax.random.PRNGKey(0))
     print(f"{'step':>4} {'reward':>7} {'gen_tok':>8} {'reused':>7} "

@@ -53,7 +53,7 @@ def main():
     t0 = time.time()
     ver = verify_drafts(updated, cfg, prompts, mask, out["tokens"],
                         out["logprobs"], out["length"], jax.random.PRNGKey(3),
-                        math.log(math.e ** 0.5), impl="ref")
+                        math.log(math.e ** 0.5))
     n = ver["n"]
     jax.block_until_ready(n)
     reused = int(n.sum())

@@ -81,8 +81,7 @@ def main():
     rl = RLConfig(algo=args.algo, group_size=4, prompts_per_batch=8,
                   max_new_tokens=args.max_new_tokens,
                   optim=AdamWConfig(lr=args.lr))
-    spec = SpecConfig(variant=args.variant, lenience=args.lenience,
-                      verify_impl="ref")
+    spec = SpecConfig(variant=args.variant, lenience=args.lenience)
     trainer = Trainer(model, rl, spec, dataset, jax.random.PRNGKey(0))
 
     os.makedirs(args.out, exist_ok=True)

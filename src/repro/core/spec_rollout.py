@@ -57,10 +57,8 @@ class SpecConfig:
     variant: str = "spec"
     lenience: float = math.e ** 0.5     # paper default for GRPO
     cache_history: int = 4
-    verify_impl: str = "auto"           # kernels.spec_verify impl selector
     one_pass: str = "auto"              # 'auto' | 'on' | 'off' — fused
                                         # verify→compact→resume engine path
-    compact_impl: str = "auto"          # kernels.cache_gather impl selector
     backfill: str = "none"              # 'none' | 'slots' — continuous-
                                         # batching rollout (DESIGN.md §6):
                                         # finished rows immediately pick up
@@ -339,9 +337,7 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
                 try:
                     out = drafted_generate(params, cfg, gen, prompts,
                                            prompt_mask, sub, spec.draft,
-                                           corpus=corpus,
-                                           verify_impl=spec.verify_impl,
-                                           mesh=mesh)
+                                           corpus=corpus, mesh=mesh)
                 finally:
                     if rows is not None:
                         led.unbind()
@@ -399,8 +395,8 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
                                      draft_tokens, draft_lp, draft_len, sub,
                                      spec.log_lenience,
                                      temperature=gen.temperature,
-                                     top_p=gen.top_p, impl=spec.verify_impl,
-                                     mesh=mesh, **model_kwargs)
+                                     top_p=gen.top_p, mesh=mesh,
+                                     **model_kwargs)
             n = ver["n"]
             prefix_lp = ver["lp_curr"]
             accept_rate = float(ver["accept_rate"])
@@ -414,8 +410,7 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
             p_len = jnp.sum(prompt_mask, axis=1).astype(jnp.int32)
             caches = M.realign_decode_cache(cfg, ver["caches"],
                                             (N - n).astype(jnp.int32),
-                                            p_len + n, W,
-                                            impl=spec.compact_impl, mesh=mesh)
+                                            p_len + n, W, mesh=mesh)
             # the decode stage's inputs, dispatched before the sync so their
             # launch hides behind the rolls
             full_reuse = (n == draft_len) & draft_eos
@@ -452,9 +447,7 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
                                           sub, spec.draft, contexts,
                                           corpus=corpus,
                                           initial_done=full_reuse,
-                                          row_budget=N - n,
-                                          verify_impl=spec.verify_impl,
-                                          mesh=mesh)
+                                          row_budget=N - n, mesh=mesh)
                 finally:
                     if led_rows is not None:
                         led.unbind()
@@ -477,8 +470,8 @@ def _rollout_fixed(params, cfg, gen, spec, prompts, prompt_mask, prompt_ids,
                                     draft_tokens, draft_lp, draft_len, sub,
                                     spec.log_lenience,
                                     temperature=gen.temperature,
-                                    top_p=gen.top_p, impl=spec.verify_impl,
-                                    mesh=mesh, **model_kwargs)
+                                    top_p=gen.top_p, mesh=mesh,
+                                    **model_kwargs)
                 n = ver["n"]
                 prefix_lp = ver["lp_curr"]      # current-policy probs (exact)
                 accept_rate = float(ver["accept_rate"])

@@ -44,11 +44,11 @@ def _accept_uniforms(key, B: int, N: int) -> jnp.ndarray:
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "temperature", "top_p",
-                                             "impl", "mesh"))
+                                             "mesh"))
 def verify_drafts(params, cfg: ModelConfig, prompt, prompt_mask,
                   draft_tokens, draft_logprobs, draft_len, key,
                   log_lenience, *, temperature: float = 1.0,
-                  top_p: float = 1.0, impl: str = "auto", mesh=None,
+                  top_p: float = 1.0, mesh=None,
                   **model_kwargs) -> Dict[str, jnp.ndarray]:
     """prompt: (B, P) left-padded; draft_*: (B, N) right-padded.
 
@@ -70,29 +70,27 @@ def verify_drafts(params, cfg: ModelConfig, prompt, prompt_mask,
 
     u = _accept_uniforms(key, B, N)
     n = _spec_verify(mesh, lp_curr, draft_logprobs, u, draft_len,
-                     log_lenience, impl)
+                     log_lenience)
 
     total = jnp.maximum(draft_len.sum(), 1)
     accept_rate = n.sum() / total
     return {"n": n, "lp_curr": lp_curr, "accept_rate": accept_rate}
 
 
-def _spec_verify(mesh, lp_curr, draft_logprobs, u, draft_len, log_lenience,
-                 impl):
+def _spec_verify(mesh, lp_curr, draft_logprobs, u, draft_len, log_lenience):
     """Dispatch the accept/first-reject kernel, via §8 shard_map on a mesh."""
     if mesh is not None:
         return sharded_spec_verify(mesh, lp_curr, draft_logprobs, u,
-                                   draft_len, log_lenience, impl=impl)
-    return spec_verify(lp_curr, draft_logprobs, u, draft_len, log_lenience,
-                       impl=impl)
+                                   draft_len, log_lenience)
+    return spec_verify(lp_curr, draft_logprobs, u, draft_len, log_lenience)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "temperature", "top_p",
-                                             "impl", "mesh"))
+                                             "mesh"))
 def verify_and_prefill(params, cfg: ModelConfig, prompt, prompt_mask,
                        draft_tokens, draft_logprobs, draft_len, key,
                        log_lenience, *, temperature: float = 1.0,
-                       top_p: float = 1.0, impl: str = "auto", mesh=None,
+                       top_p: float = 1.0, mesh=None,
                        **model_kwargs) -> Dict[str, jnp.ndarray]:
     """Fused verification + engine prefill over [prompt | draft] (one pass).
 
@@ -133,7 +131,7 @@ def verify_and_prefill(params, cfg: ModelConfig, prompt, prompt_mask,
 
     u = _accept_uniforms(key, B, N)
     n = _spec_verify(mesh, lp_curr, draft_logprobs, u, draft_len,
-                     log_lenience, impl)
+                     log_lenience)
 
     seed_idx = P + n.astype(jnp.int32) - 1                # n==0 -> last prompt tok
     seed_logits = jnp.take_along_axis(

@@ -111,18 +111,17 @@ def sharded_decode_attention(mesh: Optional[Mesh], q, k, v, q_pos, k_pos,
 
 
 def sharded_spec_verify(mesh: Optional[Mesh], lp_curr, lp_prev, u, valid_len,
-                        log_lenience, *, impl: str = "auto"):
+                        log_lenience):
     """Mesh-partitioned accept/first-reject reduction (batch over data)."""
     from repro.kernels.spec_verify.ops import spec_verify
     B = lp_curr.shape[0]
     if not batch_shardable(mesh, B):
-        return spec_verify(lp_curr, lp_prev, u, valid_len, log_lenience,
-                           impl=impl)
+        return spec_verify(lp_curr, lp_prev, u, valid_len, log_lenience)
     d_ax = batch_axis_name(mesh)
     r2, r1 = P(d_ax, None), P(d_ax)
 
     def inner(lc, lp, uu, vl, ll):
-        return spec_verify(lc, lp, uu, vl, ll, impl=impl)
+        return spec_verify(lc, lp, uu, vl, ll)
 
     return shard_map_call(
         mesh, inner, (r2, r2, r2, r1, P()), r1,

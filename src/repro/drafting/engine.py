@@ -82,14 +82,13 @@ class _DraftLoop:
     def __init__(self, params, cfg: ModelConfig, gen: GenerateConfig,
                  draft: DraftConfig, caches, tok0, lp0, next_pos, key,
                  write_idx, initial_done, row_budget, contexts,
-                 corpus, verify_impl: str, mesh):
+                 corpus, mesh):
         from .step import draft_step
         self._step = draft_step
         B = int(np.asarray(next_pos).shape[0])
         N = gen.max_new_tokens
         self.params, self.cfg, self.gen, self.mesh = params, cfg, gen, mesh
         self.K = draft.draft_k
-        self.verify_impl = verify_impl
         self.caches = caches
         self.cur_tok = tok0
         self.cur_lp = lp0
@@ -189,8 +188,7 @@ class _DraftLoop:
                 self.cur_lp, self.done, self.count, self.budget,
                 self.next_pos, self.write_idx, self.key,
                 jnp.asarray(dt[:, :K_step]), jnp.asarray(dl), K=K_step,
-                u_width=self.K, verify_impl=self.verify_impl,
-                mesh=self.mesh)
+                u_width=self.K, mesh=self.mesh)
             self.caches = out["caches"]
             for name in ("cur_tok", "cur_lp", "done", "count", "next_pos",
                          "write_idx"):
@@ -268,8 +266,7 @@ class _DraftLoop:
 def drafted_generate(params, cfg: ModelConfig, gen: GenerateConfig, prompt,
                      prompt_mask, key, draft: DraftConfig, *,
                      corpus: Optional[Sequence[Sequence[np.ndarray]]] = None,
-                     initial_done=None, row_budget=None,
-                     verify_impl: str = "auto", mesh=None
+                     initial_done=None, row_budget=None, mesh=None
                      ) -> Dict[str, jnp.ndarray]:
     """``generate`` with the drafted decode loop (same output contract,
     plus ``stats``).  ``corpus[b]`` optionally holds row b's sibling /
@@ -285,7 +282,7 @@ def drafted_generate(params, cfg: ModelConfig, gen: GenerateConfig, prompt,
     loop = _DraftLoop(params, cfg, gen, draft, pre["caches"], pre["tok0"],
                       pre["lp0"], pre["next_pos"], pre["key"],
                       np.full((B,), P, np.int32), initial_done, row_budget,
-                      contexts, corpus, verify_impl, mesh)
+                      contexts, corpus, mesh)
     return loop.run()
 
 
@@ -293,8 +290,7 @@ def drafted_resume(params, cfg: ModelConfig, gen: GenerateConfig, caches,
                    seed_logits, next_pos, write_offset: int, key,
                    draft: DraftConfig, contexts: Sequence[Sequence[int]], *,
                    corpus: Optional[Sequence[Sequence[np.ndarray]]] = None,
-                   initial_done=None, row_budget=None,
-                   verify_impl: str = "auto", mesh=None
+                   initial_done=None, row_budget=None, mesh=None
                    ) -> Dict[str, jnp.ndarray]:
     """``resume_from_cache`` with the drafted decode loop — the one-pass
     SPEC-RL continuation drafts past the verified prefix (DESIGN.md §9).
@@ -308,5 +304,5 @@ def drafted_resume(params, cfg: ModelConfig, gen: GenerateConfig, caches,
     loop = _DraftLoop(params, cfg, gen, draft, pre["caches"], pre["tok0"],
                       pre["lp0"], next_pos, pre["key"],
                       np.full((B,), write_offset, np.int32), initial_done,
-                      row_budget, contexts, corpus, verify_impl, mesh)
+                      row_budget, contexts, corpus, mesh)
     return loop.run()

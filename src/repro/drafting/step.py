@@ -52,13 +52,13 @@ def _uniforms(key, B: int, K: int):
     return jax.random.uniform(key, (B, K))
 
 
-def _spec_verify(mesh, lp_curr, lp_prev, u, valid_len, impl):
+def _spec_verify(mesh, lp_curr, lp_prev, u, valid_len):
     if mesh is not None:
         from repro.distributed.shard_wrap import sharded_spec_verify
         return sharded_spec_verify(mesh, lp_curr, lp_prev, u, valid_len,
-                                   0.0, impl=impl)
+                                   0.0)
     from repro.kernels.spec_verify.ops import spec_verify
-    return spec_verify(lp_curr, lp_prev, u, valid_len, 0.0, impl=impl)
+    return spec_verify(lp_curr, lp_prev, u, valid_len, 0.0)
 
 
 def _invalidate_slots(caches, lo, hi):
@@ -89,11 +89,11 @@ def block_width(max_proposed: int, k_max: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "gen", "K", "u_width",
-                                             "verify_impl", "mesh"))
+                                             "mesh"))
 def draft_step(params, cfg: ModelConfig, gen: GenerateConfig, caches,
                cur_tok, cur_lp, done, count, budget, next_pos, write_idx,
                keys, draft_tokens, draft_len, *, K: int, u_width: int = 0,
-               verify_impl: str = "auto", mesh=None):
+               mesh=None):
     """One draft-verify macro-step for all B rows.
 
     cur_tok/cur_lp: (B,) carry token (sampled, not yet stored) and its
@@ -152,8 +152,8 @@ def draft_step(params, cfg: ModelConfig, gen: GenerateConfig, caches,
         lp_acc = lp_draft
         keys, sub = split_key(keys)
         u = _uniforms(sub, B, max(u_width, K))[:, :K]
-    n = _spec_verify(mesh, lp_acc, jnp.zeros_like(lp_acc), u, eff_len,
-                     verify_impl)                               # (B,)
+    n = _spec_verify(mesh, lp_acc, jnp.zeros_like(lp_acc), u,
+                     eff_len)                                   # (B,)
 
     # ---- accept/truncate: replay vanilla done-semantics over the kept
     # candidates [cur_tok | draft[:n]] ----------------------------------

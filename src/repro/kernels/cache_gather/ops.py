@@ -11,6 +11,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
+
 from .kernel import cache_roll_pallas, paged_gather_pallas
 from .ref import cache_roll_ref
 
@@ -23,8 +25,7 @@ def cache_roll(buf, shift, *, impl: str = "auto"):
     impl: 'auto' (pallas on TPU, ref elsewhere) | 'pallas' | 'interpret' | 'ref'.
     """
     assert buf.ndim == 3, buf.shape
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = kernels.resolve("cache_gather", impl, "ref")
     if impl == "ref":
         return cache_roll_ref(buf, shift)
     return cache_roll_pallas(buf, shift, interpret=(impl == "interpret"))
@@ -41,8 +42,7 @@ def paged_gather(pool, table, *, impl: str = "auto"):
     impl: 'auto' (pallas on TPU, ref elsewhere) | 'pallas' | 'interpret' | 'ref'.
     """
     assert pool.ndim == 3 and table.ndim == 2, (pool.shape, table.shape)
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = kernels.resolve("cache_gather", impl, "ref")
     if impl == "ref":
         R, nb = table.shape
         return jnp.take(pool, table.reshape(-1), axis=0).reshape(

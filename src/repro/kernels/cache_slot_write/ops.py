@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
+
 from .kernel import cache_slot_write_pallas
 from .ref import cache_slot_write_ref
 
@@ -41,8 +43,7 @@ def cache_slot_write(dst, src, dst_rows, *, impl: str = "auto"):
     assert dst.shape[1:] == src.shape[1:], (dst.shape, src.shape)
     src_for_dst = _invert_rows(dst_rows.astype(jnp.int32), dst.shape[0],
                                src.shape[0])
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = kernels.resolve("cache_slot_write", impl, "ref")
     if impl == "ref":
         return cache_slot_write_ref(dst, src, src_for_dst)
     return cache_slot_write_pallas(dst, src, src_for_dst,

@@ -60,6 +60,7 @@ empty.  This kernel is specialised for the decode shape instead:
 """
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -74,6 +75,14 @@ NEG_INF = -1e30
 # inside v5e's default scoped VMEM (16 MiB)
 KV_BLOCK_BYTES = 2 * 1024 * 1024
 TILE_WIDTHS = (512, 256, 128)
+
+# Dispatch counters, bumped at trace time: ``decode_attn_inplace`` /
+# ``decode_attn_sliced`` count a model's decode-attention calls by whether
+# they read the stacked cache in place (``ops.decode_plan``);
+# ``decode_attn_tile_<width>`` counts the dense kernel's traces by the slot
+# tile it takes.  A traced op that jit finds in its cache is not counted
+# again: reset and trace afresh (or run under ``jax.disable_jit()``).
+COUNTS = collections.Counter()
 
 
 def _neutral(m_ref, l_ref, acc_ref):
@@ -338,6 +347,7 @@ def decode_attention_pallas(q, k, v, q_pos0, q_len, k_pos, lengths, starts,
     Dv = v.shape[-1]
     G = Hq // Hkv
     block_k = decode_block_k(S, Hkv, Dk, Dv, k.dtype.itemsize)
+    COUNTS["decode_attn_tile_%d" % block_k] += 1
     pad_s = (-S) % block_k
     if pad_s:
         # a partial last tile: pad the layer's slice, never the stack

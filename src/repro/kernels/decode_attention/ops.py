@@ -20,11 +20,14 @@ prefetches.  The ref/blocked oracles accept arbitrary per-query positions.
 from __future__ import annotations
 
 import functools
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 
-from .kernel import (MLA_BLOCK_K, decode_attention_mla_pallas,
+from repro import kernels
+
+from .kernel import (COUNTS, MLA_BLOCK_K, decode_attention_mla_pallas,
                      decode_attention_pallas, paged_decode_attention_pallas,
                      reads_in_place)
 from .ref import (decode_attention_blocked, decode_attention_ref,
@@ -33,6 +36,50 @@ from .ref import (decode_attention_blocked, decode_attention_ref,
 # Below this cache width a single naive score pass beats the blocked
 # while_loop's bookkeeping (one block_k=128 chunk covers it anyway).
 NAIVE_MAX_S = 128
+
+
+def _reference(S: int) -> str:
+    """The dense op's jnp path at cache width S."""
+    return "naive" if S <= NAIVE_MAX_S else "blocked"
+
+
+def decode_plan(impl: str, *, width: int, paged: bool, mesh: bool,
+                latent: bool = False) -> Tuple[str, bool]:
+    """How a model's decode-shaped attention call runs: the concrete
+    implementation, and whether it reads the run's stacked cache in place.
+    Otherwise the caller hands the op one layer's view: its slice, or for
+    a paged cache the gather of its blocks.  Counts the choice in
+    ``COUNTS``.
+
+    impl: the requested ``ModelConfig.decode_impl``; width: the cache's
+    logical width S; paged: a block table addresses the cache; mesh: the
+    call runs inside the mesh's shard_map wrapper; latent: MLA's latent
+    cache (``decode_attention_mla``).
+
+    * ``"auto"`` is the kernel on a TPU; elsewhere "naive" up to
+      ``NAIVE_MAX_S`` slots and "blocked" beyond ("naive" for latents).
+    * The dense kernel reads the stack in place unless a mesh wraps it or
+      S is not a whole number of 128-slot tiles; it then reads the layer's
+      slice (padding a partial last tile).
+    * The paged kernel runs only when named ("pallas" / "interpret"), and
+      reads the stacked pools in place.  Under "auto" on a TPU a paged
+      layer is gathered into its dense view, which the dense kernel reads.
+    * The latent kernel runs only in place: a dense stack, no mesh and S a
+      whole number of ``MLA_BLOCK_K`` tiles.  Every other latent call runs
+      the jnp absorbed form ("naive") on the layer's view.
+    """
+    resolved = kernels.resolve("decode_attention", impl,
+                               "naive" if latent else _reference(width))
+    kernel = resolved in ("pallas", "interpret") and not mesh
+    if latent:
+        in_place = kernel and not paged and reads_in_place(width, MLA_BLOCK_K)
+        resolved = resolved if in_place else "naive"
+    elif paged:
+        in_place = kernel and impl != "auto"
+    else:
+        in_place = kernel and reads_in_place(width)
+    COUNTS["decode_attn_inplace" if in_place else "decode_attn_sliced"] += 1
+    return resolved, in_place
 
 
 def _kernel_bounds(q_pos, lengths, starts, B: int, T: int, S: int):
@@ -77,13 +124,7 @@ def decode_attention(q, k, v, q_pos, k_pos, lengths=None, starts=None,
     its tile from the shapes (``kernel.decode_block_k``).
     """
     S = k_pos.shape[1]
-    if impl == "auto":
-        if jax.default_backend() == "tpu":
-            impl = "pallas"
-        elif S <= NAIVE_MAX_S:
-            impl = "naive"
-        else:
-            impl = "blocked"
+    impl = kernels.resolve("decode_attention", impl, _reference(S))
     if impl == "naive":
         return decode_attention_ref(q, k, v, q_pos, k_pos, lengths, starts,
                                     window=window)
@@ -147,11 +188,7 @@ def paged_decode_attention(q, k_pool, v_pool, table, q_pos, k_pos,
         # rounding slack is empty by construction, so pad with -1 (masked)
         k_pos = jnp.pad(k_pos, ((0, 0), (0, S - k_pos.shape[1])),
                         constant_values=-1)
-    if impl == "auto":
-        if jax.default_backend() == "tpu":
-            impl = "pallas"
-        else:
-            impl = "naive" if S <= NAIVE_MAX_S else "blocked"
+    impl = kernels.resolve("decode_attention", impl, _reference(S))
     if impl in ("naive", "blocked"):
         k = gather_paged_kv(k_pool, table)
         v = gather_paged_kv(v_pool, table)
@@ -182,8 +219,7 @@ def decode_attention_mla(q_lat, q_rope, ckv, krope, q_pos, k_pos,
     ``block_k`` tiles, T a valid-prefix query block); 'naive' the jnp
     absorbed form; 'auto' the kernel on TPU, else jnp."""
     S = k_pos.shape[1]
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "naive"
+    impl = kernels.resolve("decode_attention", impl, "naive")
     if impl == "naive":
         return mla_decode_attention_ref(q_lat, q_rope, ckv, krope, q_pos,
                                         k_pos, lengths, starts, scale=scale)

@@ -5,6 +5,8 @@ import functools
 
 import jax
 
+from repro import kernels
+
 from .kernel import flash_attention_pallas
 from .ref import flash_attention_ref
 
@@ -20,8 +22,7 @@ def flash_attention(q, k, v, q_pos, k_pos, *, causal: bool = True,
     assert q.shape[2] > 1, (
         "flash_attention is the prefill/verify kernel; single-token decode "
         f"(T={q.shape[2]}) must route to kernels/decode_attention")
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = kernels.resolve("flash_attention", impl, "ref")
     if impl == "ref":
         return flash_attention_ref(q, k, v, q_pos, k_pos, causal=causal,
                                    window=window)

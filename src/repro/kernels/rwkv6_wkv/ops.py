@@ -10,6 +10,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import kernels
+
 from .kernel import wkv_pallas
 from .ref import wkv_ref
 
@@ -26,8 +28,7 @@ def wkv(r, k, v, w, u, s0, *, impl: str = "auto", block_t: int = 256):
     rb, kb, vb, wb = (to_bh(t.astype(jnp.float32)) for t in (r, k, v, w))
     s0b = s0.reshape(B * H, hd, hd).astype(jnp.float32)
 
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = kernels.resolve("rwkv6_wkv", impl, "ref")
     if impl == "ref":
         yb, sb = wkv_ref(rb, kb, vb, wb, u, s0b)
     else:

@@ -1,8 +1,8 @@
 """Jit'd public wrapper for the spec_verify kernel.
 
 ``spec_verify`` returns, per batch row, the first rejected draft position
-``n`` ∈ [0, valid_len] (== valid_len ⇒ full acceptance).  On CPU it runs the
-kernel in interpret mode unless ``use_ref`` short-circuits to the oracle.
+``n`` ∈ [0, valid_len] (== valid_len ⇒ full acceptance): the Pallas kernel
+on TPU, its oracle elsewhere (``repro.kernels.resolve``).
 """
 from __future__ import annotations
 
@@ -10,6 +10,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from repro import kernels
 
 from .kernel import INT_MAX, spec_verify_pallas
 from .ref import spec_verify_ref
@@ -19,8 +21,7 @@ from .ref import spec_verify_ref
 def spec_verify(lp_curr, lp_prev, u, valid_len, log_lenience, *,
                 impl: str = "auto", block_b: int = 8, block_t: int = 512):
     """impl: 'auto' (pallas on TPU, ref elsewhere) | 'pallas' | 'interpret' | 'ref'."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    impl = kernels.resolve("spec_verify", impl, "ref")
     if impl == "ref":
         return spec_verify_ref(lp_curr, lp_prev, u, valid_len, log_lenience)
     raw = spec_verify_pallas(lp_curr, lp_prev, u, valid_len, log_lenience,

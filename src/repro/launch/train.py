@@ -138,7 +138,7 @@ def build_trainer(args: argparse.Namespace) -> Trainer:
                         adaptive=not args.draft_fixed) if args.draft > 0 \
         else DraftConfig()
     spec = SpecConfig(variant=args.variant, lenience=args.lenience,
-                      verify_impl="auto", draft=draft)
+                      draft=draft)
     mesh_cfg = MeshConfig(data=args.mesh_data, model=args.mesh_model)
     watchdog = None
     if args.watchdog_dir:

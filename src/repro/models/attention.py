@@ -33,7 +33,6 @@ flash-decode kernel reads its layer straight out of the stack (DESIGN.md
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
@@ -44,13 +43,6 @@ from .layers import (apply_dense, apply_rmsnorm, apply_rope, make_dense,
                      make_rmsnorm, split_keys)
 
 NEG_INF = -1e30
-
-
-@functools.lru_cache(maxsize=None)
-def _default_backend() -> str:
-    """Backend probe, hoisted out of the per-layer hot path (the answer
-    cannot change within a process)."""
-    return jax.default_backend()
 
 
 # ------------------------------------------------------------------ core math
@@ -64,8 +56,7 @@ def dot_product_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
     q: (B, Hq, T, D); k/v: (B, Hkv, S, D); q_pos: (B, T); k_pos: (B, S).
     impl='blocked' streams KV chunks through an online softmax (flash
     attention expressed in XLA) so the (T, S) score matrix is never
-    materialised — the pure-JAX analogue of kernels/flash_attention, used
-    when the Pallas kernel is unavailable (dry-run / CPU).
+    materialised — the pure-JAX analogue of kernels/flash_attention.
     """
     if impl == "blocked" and k.shape[2] > block_k:
         return _blocked_attention(q, k, v, q_pos, k_pos, window=window,
@@ -181,9 +172,8 @@ def _live_bounds(B: int, T: int, cache_start, kv_length, kv_start):
 
 
 def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
-                      window: int, cache_start, kv_length, kv_start,
-                      use_pallas: bool, mesh=None, layer=None,
-                      table=None) -> jnp.ndarray:
+                      window: int, cache_start, kv_length, kv_start, layer,
+                      mesh=None, table=None) -> jnp.ndarray:
     """Route a decode-shaped (short-T, cached) call to the flash-decode op.
 
     ``kv_length`` is the per-row live cache extent.  When the caller does
@@ -195,23 +185,21 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
     callers that know their layout is contiguous from that slot may thread
     it — None means start at 0, which is always safe.
 
-    With ``layer``, k/v are the run's stacked cache (L, B, Hkv, S, D), or
-    with ``table`` (this layer's block table) its stacked paged pools: the
-    flash-decode kernel reads the layer in place; every other path (the
-    jnp impls, the mesh wrapper, a width that is not a whole number of
-    kernel tiles) reads the layer's slice.  ``OP_COUNTS`` counts each, and
-    the dense kernel's slot tile (``decode_attn_tile_<width>``).
+    k/v are the run's stacked cache (L, B, Hkv, S, D), or with ``table``
+    (this layer's block table) its stacked paged pools, and ``layer`` this
+    layer's index.  ``decode_plan`` (kernels/decode_attention/ops.py)
+    picks the implementation and whether the kernel reads the layer in
+    place; every other path reads the layer's slice (or gathered view).
 
     ``mesh`` routes the call through the shard_map boundary (DESIGN.md §8):
     each device runs the kernel on its local (batch, head) block with a
     static per-shard shape instead of leaving a Pallas black box to GSPMD.
     """
-    from repro.kernels.decode_attention.kernel import decode_block_k
     from repro.kernels.decode_attention.ops import (decode_attention,
-                                                    paged_decode_attention,
-                                                    reads_in_place)
-    from .model import OP_COUNTS
+                                                    decode_plan,
+                                                    paged_decode_attention)
     B, _, T = q.shape[:3]
+    S = kv_pos.shape[-1]
     lengths, starts = _live_bounds(B, T, cache_start, kv_length, kv_start)
     if window > 0 and starts is not None:
         # contiguous layout (the kv_start contract): slot j holds position
@@ -221,27 +209,12 @@ def _decode_attention(cfg: ModelConfig, q, k, v, q_pos, kv_pos, *,
         # window-masked; this changes no output)
         qp = q_pos[:, 0].astype(jnp.int32)
         starts = jnp.maximum(starts, starts + qp - window + 1)
-    impl = cfg.decode_impl
-    if impl == "auto" and use_pallas:
-        impl = "pallas" if _default_backend() == "tpu" else "interpret"
-    # remaining "auto" resolves in the op: pallas on TPU, else naive for
-    # tiny caches / length-bounded blocked beyond (DESIGN.md §7); the paged
-    # kernel is taken only when named, "auto" reads the gathered view
-    kernel = impl in ("pallas", "interpret")
-    if table is None:
-        kernel = kernel or (impl == "auto" and _default_backend() == "tpu")
-        if kernel and mesh is None:
-            # the slot tile the dense kernel takes from these shapes
-            tile = "decode_attn_tile_%d" % decode_block_k(
-                kv_pos.shape[-1], k.shape[-3], k.shape[-1], v.shape[-1],
-                jnp.dtype(q.dtype).itemsize)
-            OP_COUNTS[tile] = OP_COUNTS.get(tile, 0) + 1
-        kernel = kernel and reads_in_place(kv_pos.shape[-1])
-    in_place = layer is not None and mesh is None and kernel
-    OP_COUNTS["decode_attn_inplace" if in_place else "decode_attn_sliced"] += 1
-    if layer is not None and not in_place:
-        k = _layer_view(k, layer, table, kv_pos.shape[-1])
-        v = _layer_view(v, layer, table, kv_pos.shape[-1])
+    impl, in_place = decode_plan(cfg.decode_impl, width=S,
+                                 paged=table is not None,
+                                 mesh=mesh is not None)
+    if not in_place:
+        k = _layer_view(k, layer, table, S)
+        v = _layer_view(v, layer, table, S)
         layer = table = None
     if mesh is not None:
         # paged + mesh reuses the dense shard_map path on the gathered
@@ -451,8 +424,7 @@ def make_gqa(key, cfg: ModelConfig, dtype):
 
 def apply_gqa(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None,
               layer=None, causal=True, kv_x=None, kv_positions=None,
-              use_pallas: bool = False, kv_length=None, kv_start=None,
-              mesh=None):
+              kv_length=None, kv_start=None, mesh=None):
     """GQA attention.
 
     x: (B, T, d).  With ``cache`` given, writes K/V at ``cache_start`` and
@@ -522,20 +494,8 @@ def apply_gqa(p, cfg: ModelConfig, x, positions, *, cache=None, cache_start=None
         out = _decode_attention(cfg, q, k, v, positions, kv_pos,
                                 window=cfg.sliding_window,
                                 cache_start=cache_start, kv_length=kv_length,
-                                kv_start=kv_start, use_pallas=use_pallas,
-                                mesh=mesh, layer=layer, table=table)
-    elif use_pallas and kv_x is None and T > 1:
-        # Pallas flash kernel (TPU; interpret mode in tests).  Same schedule
-        # as _blocked_attention but with MXU-aligned VMEM tiles.  The decode
-        # dispatch above guarantees the prefill kernel never sees the
-        # degenerate block_q=1 shape.
-        from repro.kernels.flash_attention.ops import flash_attention
-        impl = "pallas" if _default_backend() == "tpu" else "interpret"
-        out = flash_attention(q, k.astype(q.dtype), v.astype(q.dtype),
-                              positions, kv_pos, causal=causal,
-                              window=cfg.sliding_window, impl=impl,
-                              block_q=min(128, q.shape[2]),
-                              block_k=min(128, k.shape[2]))
+                                kv_start=kv_start, mesh=mesh, layer=layer,
+                                table=table)
     else:
         out = dot_product_attention(q, k.astype(q.dtype), v.astype(q.dtype),
                                     positions, kv_pos,
@@ -585,15 +545,12 @@ def _mla_decode(cfg: ModelConfig, wkv_b, q_nope, q_rope, ckv, krope,
     Nothing of the cache is decompressed.
 
     ckv/krope are the run's stacked latent cache (or paged pools with
-    ``table``).  The ``decode_attention_mla`` kernel reads the layer in
-    place where it applies (a kernel impl, dense cache, no mesh, a width of
-    whole latent tiles); every other path takes the same arithmetic in jnp
-    on the layer's view.  ``OP_COUNTS`` counts each.
+    ``table``).  ``decode_plan`` (kernels/decode_attention/ops.py) decides
+    whether the ``decode_attention_mla`` kernel reads the layer in place;
+    every other path takes the same arithmetic in jnp on the layer's view.
     Returns (B, T, H * v_head_dim) float32."""
-    from repro.kernels.decode_attention.kernel import MLA_BLOCK_K
     from repro.kernels.decode_attention.ops import (decode_attention_mla,
-                                                    reads_in_place)
-    from .model import OP_COUNTS
+                                                    decode_plan)
     B, H, T, nd = q_nope.shape
     r, vd = cfg.kv_lora_rank, cfg.v_head_dim
     S = kv_pos.shape[-1]
@@ -601,16 +558,13 @@ def _mla_decode(cfg: ModelConfig, wkv_b, q_nope, q_rope, ckv, krope,
     q_lat = jnp.einsum("bhtn,rhn->bhtr", q_nope, w[..., :nd],
                        preferred_element_type=jnp.float32)
     lengths, starts = _live_bounds(B, T, cache_start, kv_length, kv_start)
-    impl = cfg.decode_impl
-    kernel = (impl in ("pallas", "interpret")
-              or (impl == "auto" and _default_backend() == "tpu"))
-    in_place = (kernel and table is None and mesh is None
-                and reads_in_place(S, MLA_BLOCK_K))
-    OP_COUNTS["decode_attn_inplace" if in_place else "decode_attn_sliced"] += 1
+    impl, in_place = decode_plan(cfg.decode_impl, width=S,
+                                 paged=table is not None,
+                                 mesh=mesh is not None, latent=True)
     if not in_place:
         ckv = _layer_view(ckv, layer, table, S)
         krope = _layer_view(krope, layer, table, S)
-        layer, impl = None, "naive"
+        layer = None
     o = decode_attention_mla(q_lat, q_rope, ckv, krope, q_pos, kv_pos,
                              lengths, starts, layer,
                              scale=float(nd + cfg.qk_rope_head_dim) ** -0.5,
@@ -701,9 +655,5 @@ def make_attention(key, cfg: ModelConfig, dtype):
 def apply_attention(p, cfg: ModelConfig, x, positions, **kw):
     if cfg.attention_kind == "mla":
         kw.pop("kv_x", None), kw.pop("kv_positions", None)
-        # MLA prefill stays on the jnp path (mixed head dims defeat the
-        # prefill flash tiling); decode routes to the latent decode op
-        # (cfg.decode_impl) inside apply_mla.
-        kw.pop("use_pallas", None)
         return apply_mla(p, cfg, x, positions, **kw)
     return apply_gqa(p, cfg, x, positions, **kw)

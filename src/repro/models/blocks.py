@@ -102,9 +102,8 @@ def _write_state(stack, new, layer):
 
 def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
                 cache=None, layer=None, cache_start=None, encoder_out=None,
-                encoder_positions=None, use_pallas: bool = False,
-                causal: bool = True, kv_length=None, kv_start=None,
-                mesh=None):
+                encoder_positions=None, causal: bool = True,
+                kv_length=None, kv_start=None, mesh=None):
     """One block.  ``cache`` is the run's stacked cache, read and written at
     ``layer``: attention K/V in place, a recurrent layer's small state as
     one slice written back.  Returns (x, new stacked cache, aux)."""
@@ -119,8 +118,7 @@ def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
         out, c = apply_attention(p["attn"], cfg, h, positions,
                                  cache=None if cache is None else cache["self"],
                                  layer=layer, cache_start=cache_start,
-                                 causal=causal,
-                                 use_pallas=use_pallas, kv_length=kv_length,
+                                 causal=causal, kv_length=kv_length,
                                  kv_start=kv_start, mesh=mesh)
         if c is not None:
             new_cache["self"] = c
@@ -133,7 +131,7 @@ def apply_block(p, cfg: ModelConfig, sig: BlockSig, x, positions, *,
     else:  # RWKV time mix
         rwkv = None if cache is None else _layer_state(cache["rwkv"], layer)
         out, c = apply_rwkv_time_mix(p["time_mix"], cfg, h, positions,
-                                     cache=rwkv, use_pallas=use_pallas)
+                                     cache=rwkv)
         if c is not None:
             new_cache["rwkv"] = _write_state(cache["rwkv"], c, layer)
     x = x + out
@@ -200,9 +198,8 @@ SUMMED_AUX = ("moe_held_tokens",)
 
 def apply_trunk(trunk_params, cfg: ModelConfig, x, positions, *,
                 caches=None, cache_start=None, encoder_out=None,
-                encoder_positions=None, use_pallas: bool = False,
-                causal: bool = True, kv_length=None, kv_start=None,
-                mesh=None):
+                encoder_positions=None, causal: bool = True,
+                kv_length=None, kv_start=None, mesh=None):
     """Run all layers.  Returns (x, new_caches, aux): each aux output's
     mean over the layers that give it, a count (``SUMMED_AUX``) its sum.
 
@@ -215,8 +212,7 @@ def apply_trunk(trunk_params, cfg: ModelConfig, x, positions, *,
     aux_sums: Dict[str, jnp.ndarray] = {}
     aux_counts: Dict[str, int] = {}
     block_kw = dict(cache_start=cache_start, encoder_out=encoder_out,
-                    encoder_positions=encoder_positions,
-                    use_pallas=use_pallas, causal=causal,
+                    encoder_positions=encoder_positions, causal=causal,
                     kv_length=kv_length, kv_start=kv_start, mesh=mesh)
 
     for run_idx, (sig, run_len) in enumerate(runs):

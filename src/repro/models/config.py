@@ -47,9 +47,9 @@ class ModelConfig:
     pos_embed: str = "rope"           # rope|learned (whisper decoder)
     sliding_window: int = 0           # 0 = full attention; >0 = SWA (mixtral)
     attn_impl: str = "naive"          # naive (materialised scores) | blocked (online-softmax XLA flash)
-    decode_impl: str = "auto"         # T==1 decode attention: auto (pallas on TPU;
-                                      # naive for tiny caches, length-bounded blocked
-                                      # beyond) | naive | blocked | pallas | interpret
+    decode_impl: str = "auto"         # short-query decode attention: auto (kernels.resolve:
+                                      # pallas on TPU; naive for tiny caches, length-bounded
+                                      # blocked beyond) | naive | blocked | pallas | interpret
 
     # -- KV cache layout (DESIGN.md §13) -------------------------------------
     cache_layout: str = "dense"       # dense (contiguous (B, S) slabs) | paged
@@ -170,10 +170,6 @@ class ModelConfig:
         )
 
     @property
-    def has_decode_path(self) -> bool:
-        return True  # all assigned archs have a decoder
-
-    @property
     def subquadratic(self) -> bool:
         """True when a 500k-token decode is feasible (SSM / hybrid / SWA)."""
         plan = self.layer_plan()
@@ -185,8 +181,10 @@ class ModelConfig:
 
     def validate(self) -> None:
         assert self.block_kind in VALID_BLOCKS, self.block_kind
+        assert self.attn_impl in ("naive", "blocked"), self.attn_impl
         assert self.decode_impl in ("auto", "naive", "blocked", "pallas",
                                     "interpret"), self.decode_impl
+        assert self.moe_impl in ("dense", "dispatch", "sort"), self.moe_impl
         assert self.cache_layout in ("dense", "paged"), self.cache_layout
         assert self.kv_block_size > 0, self.kv_block_size
         if self.num_heads:

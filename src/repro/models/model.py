@@ -14,7 +14,6 @@ encoder (audio).
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -36,15 +35,7 @@ def _dt(cfg: ModelConfig):
 # jit they count *traces*; wrap a region in ``jax.disable_jit()`` to count the
 # actual forwards executed — that is how the one-pass SPEC-RL benchmark/tests
 # assert "prompt ⊕ accepted prefix is forwarded exactly once per step".
-# ``decode_attn_inplace`` / ``decode_attn_sliced`` count decode-attention
-# calls that read the stacked K/V (or MLA latent) cache in place / read one
-# layer's view of it (models/attention._decode_attention, _mla_decode):
-# which path a config takes.  ``decode_attn_tile_<width>`` counts the dense
-# GQA kernel's calls by the slot tile it takes from the shapes.
-OP_COUNTS = {"forward": 0, "prefill": 0, "decode_step": 0,
-             "decode_attn_inplace": 0, "decode_attn_sliced": 0,
-             "decode_attn_tile_128": 0, "decode_attn_tile_256": 0,
-             "decode_attn_tile_512": 0}
+OP_COUNTS = {"forward": 0, "prefill": 0, "decode_step": 0}
 
 
 def reset_op_counts() -> None:
@@ -120,8 +111,8 @@ def encode(params, cfg: ModelConfig, frames) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 def forward(params, cfg: ModelConfig, tokens, positions, *,
             encoder_out=None, encoder_positions=None, prefix_embeds=None,
-            use_pallas: bool = False, return_hidden: bool = False,
-            return_mtp: bool = False, compute_logits: bool = True):
+            return_hidden: bool = False, return_mtp: bool = False,
+            compute_logits: bool = True):
     """Full-sequence teacher-forced forward.
 
     tokens: (B, T) int32; positions: (B, T) with -1 on padding.
@@ -136,8 +127,7 @@ def forward(params, cfg: ModelConfig, tokens, positions, *,
         x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
     x, _, aux = apply_trunk(params["trunk"], cfg, x, positions,
                             encoder_out=encoder_out,
-                            encoder_positions=encoder_positions,
-                            use_pallas=use_pallas)
+                            encoder_positions=encoder_positions)
     x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if prefix_embeds is not None:
         x = x[:, prefix_embeds.shape[1]:]
@@ -169,7 +159,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
     return init_trunk_cache(cfg, batch, max_len, jnp.dtype(cfg.dtype))
 
 
-def _paged_run_gather(sc, impl: str = "auto"):
+def _paged_run_gather(sc):
     """Dense logical K/V view of one paged cache run.
 
     sc: run dict with pools (run, NB, Hkv, bs, D) / (run, NB, bs, r) and
@@ -193,14 +183,12 @@ def _paged_run_gather(sc, impl: str = "auto"):
         tab = (r0 * NB + table.astype(jnp.int32)).reshape(run_len * B, nb)
         if pool.ndim == 5:                       # GQA: (run, NB, Hkv, bs, D)
             Hkv = pool.shape[2]
-            g = paged_gather(pool.reshape(run_len * NB, Hkv * bs, D), tab,
-                             impl=impl)
+            g = paged_gather(pool.reshape(run_len * NB, Hkv * bs, D), tab)
             g = (g.reshape(run_len, B, nb, Hkv, bs, D)
                  .transpose(0, 1, 3, 2, 4, 5)
                  .reshape(run_len, B, Hkv, nb * bs, D)[..., :S_log, :])
         else:                                    # MLA: (run, NB, bs, r)
-            g = paged_gather(pool.reshape(run_len * NB, bs, D), tab,
-                             impl=impl)
+            g = paged_gather(pool.reshape(run_len * NB, bs, D), tab)
             g = g.reshape(run_len, B, nb * bs, D)[..., :S_log, :]
         out[name] = g
     return out
@@ -226,7 +214,7 @@ def supports_cache_realign(cfg: ModelConfig) -> bool:
     return all(kind == ATTN for kind, _ in cfg.layer_plan())
 
 
-def _roll_rows(buf, shift, impl):
+def _roll_rows(buf, shift):
     """Right-rotate ``buf`` (..., S, D) along axis -2, per-batch shift.
 
     buf: (run, B, S, D) or (run, B, H, S, D); shift: (B,) int32."""
@@ -238,11 +226,11 @@ def _roll_rows(buf, shift, impl):
     per_b = reps // (lead[0] * lead[1])          # heads folded after batch
     shift_r = jnp.tile(jnp.repeat(shift.astype(jnp.int32), per_b), lead[0])
     flat = buf.reshape((reps,) + buf.shape[-2:])
-    return cache_roll(flat, shift_r, impl=impl).reshape(buf.shape)
+    return cache_roll(flat, shift_r).reshape(buf.shape)
 
 
 def realign_decode_cache(cfg: ModelConfig, caches, shift, valid_len,
-                         width: int, *, impl: str = "auto", mesh=None):
+                         width: int, *, mesh=None):
     """Compact verify-prefill caches to the left-aligned decode layout.
 
     After ``prefill`` over [left-padded prompt | right-padded draft] of width
@@ -272,14 +260,13 @@ def realign_decode_cache(cfg: ModelConfig, caches, shift, valid_len,
                                                   shard_map_call)
         from jax.sharding import PartitionSpec as P
 
-        def roll(buf, shift_, impl_):
+        def roll(buf, shift_):
             if not batch_shardable(mesh, buf.shape[1]):
-                return _roll_rows(buf, shift_, impl_)
+                return _roll_rows(buf, shift_)
             d = batch_axis_name(mesh)
             bspec = P(None, d, *([None] * (buf.ndim - 2)))
-            return shard_map_call(
-                mesh, functools.partial(_roll_rows, impl=impl_),
-                (bspec, P(d)), bspec, buf, shift_)
+            return shard_map_call(mesh, _roll_rows, (bspec, P(d)), bspec,
+                                  buf, shift_)
 
     new_caches = []
     for run in caches:
@@ -300,16 +287,16 @@ def realign_decode_cache(cfg: ModelConfig, caches, shift, valid_len,
             from repro.kernels.cache_slot_write.ops import paged_slot_write
             nb = sc["table"].shape[-1]
             bs = (sc["k"] if "k" in sc else sc["ckv"]).shape[-2]
-            dense = _paged_run_gather(sc, impl)
+            dense = _paged_run_gather(sc)
             for name, buf in dense.items():
-                rolled = _pad_to_blocks(roll(buf, shift, impl), nb, bs)
+                rolled = _pad_to_blocks(roll(buf, shift), nb, bs)
                 new_sc[name] = paged_slot_write(sc[name], rolled,
-                                                sc["table"], impl=impl)
+                                                sc["table"])
             new_sc["table"] = sc["table"]
         else:
             for name in ("k", "v", "ckv", "krope"):
                 if name in sc:
-                    new_sc[name] = roll(sc[name], shift, impl)
+                    new_sc[name] = roll(sc[name], shift)
         new_caches.append({"self": new_sc})
     if mesh is not None:
         from repro.distributed.mesh import constrain_caches
@@ -414,7 +401,7 @@ def supports_slot_serving(cfg: ModelConfig, model_kwargs=None) -> bool:
 
 
 def write_cache_slots(cfg: ModelConfig, dst_caches, src_caches, slots, *,
-                      impl: str = "auto", mesh=None):
+                      mesh=None):
     """Admit prefilled rows into the persistent serving batch, in place.
 
     dst_caches: trunk caches over B slots; src_caches: same structure over R
@@ -437,8 +424,7 @@ def write_cache_slots(cfg: ModelConfig, dst_caches, src_caches, slots, *,
     assert supports_cache_realign(cfg), "slot serving needs attention trunks"
     slots = slots.astype(jnp.int32)
     if any("table" in run["self"] for run in dst_caches):
-        return _write_cache_slots_paged(dst_caches, src_caches, slots,
-                                        impl=impl)
+        return _write_cache_slots_paged(dst_caches, src_caches, slots)
 
     def scatter(d, s, slots_):
         run_len, B = d.shape[0], d.shape[1]
@@ -452,7 +438,7 @@ def write_cache_slots(cfg: ModelConfig, dst_caches, src_caches, slots, *,
         flat = cache_slot_write(
             d.reshape((run_len * B * per,) + d.shape[-2:]),
             s.reshape((run_len * R * per,) + s.shape[-2:]),
-            rows, impl=impl)
+            rows)
         return flat.reshape(d.shape)
 
     new_caches = []
@@ -479,8 +465,7 @@ def write_cache_slots(cfg: ModelConfig, dst_caches, src_caches, slots, *,
     return new_caches
 
 
-def _write_cache_slots_paged(dst_caches, src_caches, slots, *,
-                             impl: str = "auto"):
+def _write_cache_slots_paged(dst_caches, src_caches, slots):
     """Admit dense prefilled rows into a *paged* persistent cache (§13).
 
     The admission forward runs on small throwaway dense caches (identical
@@ -515,15 +500,13 @@ def _write_cache_slots_paged(dst_caches, src_caches, slots, *,
             if name not in dsc:
                 continue
             new_sc[name] = paged_slot_write(
-                dsc[name], _pad_to_blocks(ssc[name], nb, bs), table,
-                impl=impl)
+                dsc[name], _pad_to_blocks(ssc[name], nb, bs), table)
         new_caches.append({"self": new_sc})
     return new_caches
 
 
 def prefill(params, cfg: ModelConfig, tokens, positions, caches, *,
-            encoder_out=None, encoder_positions=None, prefix_embeds=None,
-            use_pallas: bool = False):
+            encoder_out=None, encoder_positions=None, prefix_embeds=None):
     """Run the prompt through the model, filling caches at slots [0, T).
 
     Returns (logits (B, T, V), new_caches)."""
@@ -535,8 +518,7 @@ def prefill(params, cfg: ModelConfig, tokens, positions, caches, *,
     x, caches, _ = apply_trunk(params["trunk"], cfg, x, positions,
                                caches=caches, cache_start=0,
                                encoder_out=encoder_out,
-                               encoder_positions=encoder_positions,
-                               use_pallas=use_pallas)
+                               encoder_positions=encoder_positions)
     x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if prefix_embeds is not None:
         x = x[:, prefix_embeds.shape[1]:]
@@ -544,9 +526,8 @@ def prefill(params, cfg: ModelConfig, tokens, positions, caches, *,
 
 
 def decode_step(params, cfg: ModelConfig, token, position, caches, cache_start, *,
-                encoder_out=None, encoder_positions=None,
-                use_pallas: bool = False, kv_length=None, kv_start=None,
-                mesh=None, with_aux: bool = False):
+                encoder_out=None, encoder_positions=None, kv_length=None,
+                kv_start=None, mesh=None, with_aux: bool = False):
     """One decode step over a short token block.
 
     token: (B, T) with small T — 1 for classic decode, k + 1 for a §9
@@ -575,8 +556,8 @@ def decode_step(params, cfg: ModelConfig, token, position, caches, cache_start, 
                                  caches=caches, cache_start=cache_start,
                                  encoder_out=encoder_out,
                                  encoder_positions=encoder_positions,
-                                 use_pallas=use_pallas, kv_length=kv_length,
-                                 kv_start=kv_start, mesh=mesh)
+                                 kv_length=kv_length, kv_start=kv_start,
+                                 mesh=mesh)
     x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if with_aux:
         return _logits(params, cfg, x), caches, aux

@@ -12,8 +12,9 @@ between x_t and x_{t-1} per projection stream.
 Padding: embeddings at invalid positions are zeroed by the trunk, and k is
 masked / w forced to 1 there, so the state is untouched by pads.
 
-The time-mix recurrence has a Pallas kernel (`repro.kernels.rwkv6_wkv`) used
-when ``use_pallas`` is enabled; the jnp scan here is the reference path.
+The time-mix recurrence runs as the jnp scan here (``wkv_scan``); the
+Pallas kernel in `repro.kernels.rwkv6_wkv` is tested against it but not
+called by the model.
 """
 from __future__ import annotations
 
@@ -136,8 +137,7 @@ def wkv_scan(r, k, v, w, u, s0, chunk: int = 64):
     return jnp.moveaxis(ys, 0, 1), s_final
 
 
-def apply_rwkv_time_mix(p, cfg: ModelConfig, x, positions, *, cache=None,
-                        use_pallas: bool = False):
+def apply_rwkv_time_mix(p, cfg: ModelConfig, x, positions, *, cache=None):
     B, T, d = x.shape
     H, hd = cfg.rwkv_num_heads, cfg.rwkv_head_dim
     valid = (positions >= 0)[..., None].astype(jnp.float32)
@@ -163,11 +163,7 @@ def apply_rwkv_time_mix(p, cfg: ModelConfig, x, positions, *, cache=None,
     u = p["u"].astype(jnp.float32).reshape(H, hd)
     s0 = cache["wkv"] if cache is not None else jnp.zeros((B, H, hd, hd), jnp.float32)
 
-    if use_pallas:
-        from repro.kernels.rwkv6_wkv import ops as wkv_ops
-        y, s_final = wkv_ops.wkv(r_, k_, v_, w_, u, s0)
-    else:
-        y, s_final = wkv_scan(r_, k_, v_, w_, u, s0, cfg.scan_chunk)
+    y, s_final = wkv_scan(r_, k_, v_, w_, u, s0, cfg.scan_chunk)
 
     y = _group_norm(p, y.astype(x.dtype), cfg.norm_eps).reshape(B, T, d)
     out = apply_dense(p["wo"], y * g)

@@ -104,12 +104,10 @@ def _admit_vanilla(params, cfg: ModelConfig, gen: GenerateConfig, prompts,
             "seed_logits": logits[:, -1]}
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "gen", "verify_impl",
-                                             "compact_impl", "mesh"))
+@functools.partial(jax.jit, static_argnames=("cfg", "gen", "mesh"))
 def _admit_spec(params, cfg: ModelConfig, gen: GenerateConfig, prompts, mask,
                 draft_tokens, draft_lp, draft_len, draft_eos, verify_keys,
-                decode_keys, log_lenience, *, verify_impl: str,
-                compact_impl: str, mesh=None):
+                decode_keys, log_lenience, *, mesh=None):
     """Speculative-prefix admission: one forward over [prompt | draft].
 
     Identical device program to the fixed-batch one-pass rollout path
@@ -123,12 +121,12 @@ def _admit_spec(params, cfg: ModelConfig, gen: GenerateConfig, prompts, mask,
     ver = verify_and_prefill(params, cfg, prompts, mask, draft_tokens,
                              draft_lp, draft_len, verify_keys, log_lenience,
                              temperature=gen.temperature, top_p=gen.top_p,
-                             impl=verify_impl, mesh=mesh)
+                             mesh=mesh)
     n = ver["n"]
     p_len = mask.sum(axis=1).astype(jnp.int32)
     caches = M.realign_decode_cache(cfg, ver["caches"],
                                     (N - n).astype(jnp.int32), p_len + n, W,
-                                    impl=compact_impl, mesh=mesh)
+                                    mesh=mesh)
     full_reuse = (n == draft_len) & draft_eos
     keys, sub = split_key(decode_keys)
     tok0, lp0 = sample(sub, ver["seed_logits"], gen.temperature, gen.top_p)
@@ -137,16 +135,14 @@ def _admit_spec(params, cfg: ModelConfig, gen: GenerateConfig, prompts, mask,
             "next_pos": p_len + n, "keys": keys}
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "impl", "pad_src",
-                                             "mesh"))
+@functools.partial(jax.jit, static_argnames=("cfg", "pad_src", "mesh"))
 def _write_slots(cfg: ModelConfig, dst_caches, src_caches, slots, *,
-                 impl: str = "auto", pad_src: int = 0, mesh=None):
+                 pad_src: int = 0, mesh=None):
     # drafted engines keep draft_k spare slots per row (§9 block headroom);
     # admission caches are padded to the persistent width before the scatter
     if pad_src:
         src_caches = M.pad_cache(cfg, src_caches, pad_src)
-    return M.write_cache_slots(cfg, dst_caches, src_caches, slots, impl=impl,
-                               mesh=mesh)
+    return M.write_cache_slots(cfg, dst_caches, src_caches, slots, mesh=mesh)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "gen", "steps", "mesh"))
@@ -226,8 +222,7 @@ class SlotEngine:
     def __init__(self, params, cfg: ModelConfig, gen: GenerateConfig, *,
                  num_slots: int, prompt_width: int, spec_prefix: bool = False,
                  log_lenience: float = 0.0, chunk_steps: int = 8,
-                 verify_impl: str = "auto", compact_impl: str = "auto",
-                 slot_write_impl: str = "auto", draft=None, mesh=None,
+                 draft=None, mesh=None,
                  faults: Optional[FaultPlan] = None,
                  deadline_steps: Optional[int] = None,
                  max_queue: Optional[int] = None, overflow: str = "reject",
@@ -242,8 +237,6 @@ class SlotEngine:
         self.spec_prefix = bool(spec_prefix)
         self.log_lenience = float(log_lenience)
         self.chunk_steps = max(1, int(chunk_steps))
-        self.verify_impl, self.compact_impl = verify_impl, compact_impl
-        self.slot_write_impl = slot_write_impl
         # §9 continuation draft engine: a DraftConfig switches _run_chunk
         # from `chunk_steps` single-token scans to one draft-verify block
         # per chunk, with per-slot n-gram sources / length controllers
@@ -569,8 +562,7 @@ class SlotEngine:
                 jnp.asarray(self._pad_group(list(dn))),
                 jnp.asarray(self._pad_group(list(de))),
                 jnp.asarray(vkeys), jnp.asarray(keys),
-                self.log_lenience, verify_impl=self.verify_impl,
-                compact_impl=self.compact_impl, mesh=self.mesh)
+                self.log_lenience, mesh=self.mesh)
         else:
             out = _admit_vanilla(self.params, self._admit_cfg(), self.gen,
                                  jnp.asarray(prompts), jnp.asarray(masks),
@@ -616,7 +608,6 @@ class SlotEngine:
         """Scatter the admission caches into the persistent batch."""
         return _write_slots(self.cfg, self.caches, src_caches,
                             jnp.asarray(slot_ids),
-                            impl=self.slot_write_impl,
                             pad_src=self.draft.draft_k if self.draft else 0,
                             mesh=self.mesh)
 
@@ -838,7 +829,7 @@ class SlotEngine:
             jnp.asarray(self.budget), jnp.asarray(self.next_pos),
             jnp.asarray(self.write_idx), jnp.asarray(self.keys),
             jnp.asarray(dt[:, :K_step]), jnp.asarray(dl), K=K_step,
-            u_width=K, verify_impl=self.verify_impl, mesh=self.mesh)
+            u_width=K, mesh=self.mesh)
         self.caches = out["caches"]
         toks = np.asarray(out["tokens"])            # (B, K+1)
         lps = np.asarray(out["logprobs"])

@@ -41,9 +41,7 @@ from .request import Request, Response
 def make_slot_engine(params, cfg: ModelConfig, gen: GenerateConfig, *,
                      mesh=None, num_slots: int, prompt_width: int,
                      spec_prefix: bool = False, log_lenience: float = 0.0,
-                     chunk_steps: int = 8, verify_impl: str = "auto",
-                     compact_impl: str = "auto",
-                     slot_write_impl: str = "auto", draft=None, faults=None,
+                     chunk_steps: int = 8, draft=None, faults=None,
                      deadline_steps=None, max_queue=None,
                      overflow: str = "reject", tracer=None, ledger=None,
                      kv_pool_blocks: Optional[int] = None):
@@ -69,11 +67,9 @@ def make_slot_engine(params, cfg: ModelConfig, gen: GenerateConfig, *,
     from repro.distributed.mesh import data_size
     kw = dict(num_slots=num_slots, prompt_width=prompt_width,
               spec_prefix=spec_prefix, log_lenience=log_lenience,
-              chunk_steps=chunk_steps, verify_impl=verify_impl,
-              compact_impl=compact_impl, slot_write_impl=slot_write_impl,
-              draft=draft, faults=faults, deadline_steps=deadline_steps,
-              max_queue=max_queue, overflow=overflow, tracer=tracer,
-              ledger=ledger)
+              chunk_steps=chunk_steps, draft=draft, faults=faults,
+              deadline_steps=deadline_steps, max_queue=max_queue,
+              overflow=overflow, tracer=tracer, ledger=ledger)
     if cfg.cache_layout == "paged":
         kw["kv_pool_blocks"] = kv_pool_blocks
     if mesh is not None and data_size(mesh) > 1:
@@ -98,10 +94,9 @@ class MeshSlotServer:
     def __init__(self, params, cfg: ModelConfig, gen: GenerateConfig, *,
                  mesh, num_slots: int, prompt_width: int,
                  spec_prefix: bool = False, log_lenience: float = 0.0,
-                 chunk_steps: int = 8, verify_impl: str = "auto",
-                 compact_impl: str = "auto", slot_write_impl: str = "auto",
-                 draft=None, faults=None, deadline_steps=None,
-                 max_queue=None, overflow: str = "reject", tracer=None,
+                 chunk_steps: int = 8, draft=None, faults=None,
+                 deadline_steps=None, max_queue=None,
+                 overflow: str = "reject", tracer=None,
                  ledger=None, kv_pool_blocks: Optional[int] = None):
         self.submeshes = data_submeshes(mesh)
         D = len(self.submeshes)
@@ -122,9 +117,7 @@ class MeshSlotServer:
             mk(shard_params(sm, cfg, params), cfg, gen,
                num_slots=num_slots // D, prompt_width=prompt_width,
                spec_prefix=spec_prefix, log_lenience=log_lenience,
-               chunk_steps=chunk_steps, verify_impl=verify_impl,
-               compact_impl=compact_impl,
-               slot_write_impl=slot_write_impl, draft=draft, mesh=sm,
+               chunk_steps=chunk_steps, draft=draft, mesh=sm,
                faults=plan, deadline_steps=deadline_steps,
                max_queue=max_queue, overflow=overflow,
                tracer=tracer, ledger=ledger, obs_label=f"shard{i}/")

@@ -101,8 +101,6 @@ def rollout_via_slots(params, cfg: ModelConfig, gen: GenerateConfig,
                               num_slots=num_slots, prompt_width=P,
                               spec_prefix=have_drafts,
                               log_lenience=spec.log_lenience,
-                              verify_impl=spec.verify_impl,
-                              compact_impl=spec.compact_impl,
                               draft=spec.draft if drafting else None)
     num_slots = int(engine.stats()["num_slots"])    # post-rounding, for metrics
     corpora = cache.batch_siblings(prompt_ids, spec.cache_lag) \

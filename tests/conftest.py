@@ -1,6 +1,7 @@
 """Shared fixtures.  NOTE: no XLA_FLAGS device-count override here — smoke
 tests and benches must see the single real CPU device (the 512-device
 override belongs ONLY to repro.launch.dryrun)."""
+import contextlib
 import os
 import sys
 
@@ -32,6 +33,34 @@ def _bound_compile_maps():
     whole suite."""
     yield
     jax.clear_caches()
+
+
+@pytest.fixture
+def interpret_kernels(monkeypatch):
+    """``with interpret_kernels("cache_gather", ...):`` runs the named kernel
+    packages in Pallas interpret mode wherever the program leaves them at
+    ``"auto"`` (off a TPU), by patching ``repro.kernels.resolve``.  The
+    choice is made at trace time, so compiled programs are dropped on entry
+    and on exit."""
+    import repro.kernels as K
+
+    @contextlib.contextmanager
+    def use(*names):
+        resolve = K.resolve
+
+        def interpret(kernel, impl, ref):
+            if (impl == "auto" and kernel in names
+                    and jax.default_backend() != "tpu"):
+                return "interpret"
+            return resolve(kernel, impl, ref)
+        jax.clear_caches()
+        try:
+            with monkeypatch.context() as m:
+                m.setattr(K, "resolve", interpret)
+                yield
+        finally:
+            jax.clear_caches()
+    return use
 
 
 @pytest.fixture(scope="session")

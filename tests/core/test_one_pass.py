@@ -31,7 +31,7 @@ def setup():
 
 def _seeded_cache(cfg, params, prompt, mask, variant="spec"):
     cache = RolloutCache()
-    spec = SpecConfig(variant=variant, verify_impl="ref", one_pass="off")
+    spec = SpecConfig(variant=variant, one_pass="off")
     gen = GenerateConfig(max_new_tokens=N)
     rollout(params, cfg, gen, spec, prompt, mask, list(range(B)), cache,
             jax.random.PRNGKey(0), 0)
@@ -46,19 +46,17 @@ def test_one_pass_matches_two_pass(setup, variant):
     ids = list(range(B))
     cache1 = _seeded_cache(cfg, params_a, prompt, mask)
     if variant == "delayed":   # lag=2 needs two cached visits
-        spec = SpecConfig(variant="spec", verify_impl="ref", one_pass="off")
+        spec = SpecConfig(variant="spec", one_pass="off")
         rollout(params_a, cfg, gen, spec, prompt, mask, ids, cache1,
                 jax.random.PRNGKey(5), 1)
     cache2 = copy.deepcopy(cache1)
 
     key = jax.random.PRNGKey(7)
     two = rollout(params_b, cfg, gen,
-                  SpecConfig(variant=variant, verify_impl="ref",
-                             one_pass="off"),
+                  SpecConfig(variant=variant, one_pass="off"),
                   prompt, mask, ids, cache1, key, 2)
     one = rollout(params_b, cfg, gen,
-                  SpecConfig(variant=variant, verify_impl="ref", one_pass="on",
-                             compact_impl="ref"),
+                  SpecConfig(variant=variant, one_pass="on"),
                   prompt, mask, ids, cache2, key, 2)
 
     assert one.metrics["one_pass"] == 1.0
@@ -72,7 +70,7 @@ def test_one_pass_matches_two_pass(setup, variant):
     assert one.metrics["n_reused"] > 0          # the comparison is non-trivial
 
 
-def test_one_pass_with_pallas_compactor(setup):
+def test_one_pass_with_pallas_compactor(setup, interpret_kernels):
     """Interpret-mode cache_gather kernel on the real rollout path."""
     cfg, params_a, params_b, prompt, mask = setup
     gen = GenerateConfig(max_new_tokens=N)
@@ -81,13 +79,12 @@ def test_one_pass_with_pallas_compactor(setup):
     cache2 = copy.deepcopy(cache1)
     key = jax.random.PRNGKey(3)
     ref = rollout(params_b, cfg, gen,
-                  SpecConfig(variant="spec", verify_impl="ref", one_pass="on",
-                             compact_impl="ref"),
+                  SpecConfig(variant="spec", one_pass="on"),
                   prompt, mask, ids, cache1, key, 1)
-    ker = rollout(params_b, cfg, gen,
-                  SpecConfig(variant="spec", verify_impl="ref", one_pass="on",
-                             compact_impl="interpret"),
-                  prompt, mask, ids, cache2, key, 1)
+    with interpret_kernels("cache_gather", "cache_slot_write"):
+        ker = rollout(params_b, cfg, gen,
+                      SpecConfig(variant="spec", one_pass="on"),
+                      prompt, mask, ids, cache2, key, 1)
     np.testing.assert_array_equal(ker.response, ref.response)
     np.testing.assert_array_equal(ker.length, ref.length)
 
@@ -105,13 +102,12 @@ def test_realigned_cache_matches_aligned_prefill(setup):
     draft_lp = jnp.where(draft_mask, -1.0, 0.0)
 
     ver = verify_and_prefill(params_a, cfg, prompt, mask, draft, draft_lp,
-                             draft_len, jax.random.PRNGKey(2), 0.0,
-                             impl="ref")
+                             draft_len, jax.random.PRNGKey(2), 0.0)
     n = ver["n"]
     W = P + N
     p_len = mask.sum(axis=1).astype(jnp.int32)
     got = M.realign_decode_cache(cfg, ver["caches"], (N - n).astype(jnp.int32),
-                                 p_len + n, W, impl="ref")
+                                 p_len + n, W)
 
     # reference: left-align prompt ⊕ accepted prefix and prefill from scratch
     prefix_mask = didx < n[:, None]
@@ -151,15 +147,14 @@ def test_one_pass_forwards_context_exactly_once(setup):
     with jax.disable_jit():
         M.reset_op_counts()
         rollout(params_b, cfg, small,
-                SpecConfig(variant="spec", verify_impl="ref", one_pass="on",
-                           compact_impl="ref"),
+                SpecConfig(variant="spec", one_pass="on"),
                 prompt, mask, ids, cache1, jax.random.PRNGKey(1), 1)
         assert M.OP_COUNTS["prefill"] == 1
         assert M.OP_COUNTS["forward"] == 0
 
         M.reset_op_counts()
         rollout(params_b, cfg, small,
-                SpecConfig(variant="spec", verify_impl="ref", one_pass="off"),
+                SpecConfig(variant="spec", one_pass="off"),
                 prompt, mask, ids, cache2, jax.random.PRNGKey(1), 1)
         assert M.OP_COUNTS["prefill"] == 1     # continuation re-prefill
         assert M.OP_COUNTS["forward"] == 1     # scoring pass
@@ -197,8 +192,7 @@ def test_one_pass_with_encoder_extras(setup):
     gen = GenerateConfig(max_new_tokens=8)
     kw = dict(encoder_out=enc, encoder_positions=epos)
     cache = RolloutCache()
-    spec = SpecConfig(variant="spec", verify_impl="ref", one_pass="on",
-                      compact_impl="ref")
+    spec = SpecConfig(variant="spec", one_pass="on")
     rollout(params, cfg, gen, spec, prompt, mask, [0, 1], cache,
             jax.random.PRNGKey(3), 0, **kw)
     rb = rollout(params, cfg, gen, spec, prompt, mask, [0, 1], cache,

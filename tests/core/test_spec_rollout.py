@@ -57,7 +57,7 @@ def test_identical_policy_full_acceptance(setup):
     ids = list(range(prompt.shape[0]))
     gen = GenerateConfig(max_new_tokens=12)
     cache = RolloutCache()
-    spec = SpecConfig(variant="spec", lenience=1.0, verify_impl="ref")
+    spec = SpecConfig(variant="spec", lenience=1.0)
     rollout(params, cfg, gen, spec, prompt, mask, ids, cache,
             jax.random.PRNGKey(0), 0)
     rb = rollout(params, cfg, gen, spec, prompt, mask, ids, cache,
@@ -72,7 +72,7 @@ def test_cache_refreshed_after_step(setup):
     ids = list(range(prompt.shape[0]))
     gen = GenerateConfig(max_new_tokens=8)
     cache = RolloutCache()
-    spec = SpecConfig(variant="spec", verify_impl="ref")
+    spec = SpecConfig(variant="spec")
     rb0 = rollout(params, cfg, gen, spec, prompt, mask, ids, cache,
                   jax.random.PRNGKey(0), 0)
     for i, pid in enumerate(ids):
@@ -92,12 +92,12 @@ def test_variants_run_and_report(setup):
     ids = list(range(prompt.shape[0]))
     gen = GenerateConfig(max_new_tokens=8)
     cache = RolloutCache()
-    rollout(params, cfg, gen, SpecConfig(variant="spec", verify_impl="ref"),
+    rollout(params, cfg, gen, SpecConfig(variant="spec"),
             prompt, mask, ids, cache, jax.random.PRNGKey(0), 0)
-    rollout(params, cfg, gen, SpecConfig(variant="spec", verify_impl="ref"),
+    rollout(params, cfg, gen, SpecConfig(variant="spec"),
             prompt, mask, ids, cache, jax.random.PRNGKey(1), 1)
     for variant in ("random", "delayed", "full", "off"):
-        spec = SpecConfig(variant=variant, verify_impl="ref")
+        spec = SpecConfig(variant=variant)
         rb = rollout(params, cfg, gen, spec, prompt, mask, ids,
                      None if variant == "off" else cache,
                      jax.random.PRNGKey(2), 2)
@@ -113,7 +113,7 @@ def test_lenience_zero_equals_vanilla_token_counts(setup):
     ids = list(range(prompt.shape[0]))
     gen = GenerateConfig(max_new_tokens=8)
     cache = RolloutCache()
-    spec0 = SpecConfig(variant="spec", lenience=1e-9, verify_impl="ref")
+    spec0 = SpecConfig(variant="spec", lenience=1e-9)
     rollout(params, cfg, gen, spec0, prompt, mask, ids, cache,
             jax.random.PRNGKey(0), 0)
     rb = rollout(params, cfg, gen, spec0, prompt, mask, ids, cache,
@@ -128,8 +128,7 @@ def test_response_tokens_match_behaviour_source(setup):
     ids = list(range(prompt.shape[0]))
     gen = GenerateConfig(max_new_tokens=10)
     cache = RolloutCache()
-    spec = SpecConfig(variant="spec", lenience=math.e ** 0.5,
-                      verify_impl="ref")
+    spec = SpecConfig(variant="spec", lenience=math.e ** 0.5)
     rb0 = rollout(params, cfg, gen, spec, prompt, mask, ids, cache,
                   jax.random.PRNGKey(0), 0)
     drafts = cache.batch_get(ids, 10)
@@ -161,7 +160,7 @@ def test_rollout_with_encoder_model_kwargs():
     mask = jnp.ones((B, P), bool)
     gen = GenerateConfig(max_new_tokens=8)
     cache = RolloutCache()
-    spec = SpecConfig(variant="spec", verify_impl="ref")
+    spec = SpecConfig(variant="spec")
     kw = dict(encoder_out=enc, encoder_positions=epos)
     rb0 = rollout(params, cfg, gen, spec, prompt, mask, [0, 1], cache,
                   jax.random.PRNGKey(3), 0, **kw)

@@ -41,7 +41,7 @@ def test_verify_drafts_identical_policy(tiny_cfg, tiny_params):
     out = generate(params, cfg, gen, prompt, pmask, jax.random.PRNGKey(1))
     res = verify_drafts(params, cfg, prompt, pmask, out["tokens"],
                         out["logprobs"], out["length"], jax.random.PRNGKey(2),
-                        0.0, impl="ref")
+                        0.0)
     # p_curr == p_prev exactly (same model) => full acceptance at l=1
     np.testing.assert_array_equal(np.asarray(res["n"]),
                                   np.asarray(out["length"]))
@@ -60,7 +60,7 @@ def test_verify_drafts_prefix_consistency(tiny_cfg, tiny_params):
     dlen = jnp.array([6, 4], jnp.int32)
     dlp = jnp.full((B, N), -1.0)
     res = verify_drafts(params, cfg, prompt, pmask, draft, dlp, dlen,
-                        jax.random.PRNGKey(5), 0.0, impl="ref")
+                        jax.random.PRNGKey(5), 0.0)
     from repro.engine.generate import score
     didx = jnp.arange(N)[None, :]
     dmask = didx < dlen[:, None]
@@ -87,10 +87,10 @@ def test_perturbed_policy_reduces_acceptance(tiny_cfg, tiny_params):
         .astype(x.dtype), params)
     same = verify_drafts(params, cfg, prompt, pmask, out["tokens"],
                          out["logprobs"], out["length"],
-                         jax.random.PRNGKey(9), 0.0, impl="ref")
+                         jax.random.PRNGKey(9), 0.0)
     diff = verify_drafts(perturbed, cfg, prompt, pmask, out["tokens"],
                          out["logprobs"], out["length"],
-                         jax.random.PRNGKey(9), 0.0, impl="ref")
+                         jax.random.PRNGKey(9), 0.0)
     assert float(diff["n"].sum()) < float(same["n"].sum())
 
 
@@ -113,6 +113,6 @@ def test_lenience_recovers_acceptance(tiny_cfg, tiny_params):
     for logl in (0.0, 0.5, 2.0):
         r = verify_drafts(perturbed, cfg, prompt, pmask, out["tokens"],
                           out["logprobs"], out["length"],
-                          jax.random.PRNGKey(13), logl, impl="ref")
+                          jax.random.PRNGKey(13), logl)
         ns.append(int(r["n"].sum()))
     assert ns[0] <= ns[1] <= ns[2]

@@ -179,13 +179,12 @@ def _verify_resume(cfg, params, prompt, mask, draft_len_rows, log_lenience,
     draft_lp = jnp.where(didx < draft_len[:, None], -6.0, 0.0)
     kv, kd = jax.random.split(key)
     ver = verify_and_prefill(params, cfg, prompt, mask, draft, draft_lp,
-                             draft_len, kv, log_lenience, impl="ref")
+                             draft_len, kv, log_lenience)
     n = ver["n"]
     W = P + N
     p_len = mask.sum(axis=1).astype(jnp.int32)
     caches = M.realign_decode_cache(cfg, ver["caches"],
-                                    (N - n).astype(jnp.int32), p_len + n, W,
-                                    impl="ref")
+                                    (N - n).astype(jnp.int32), p_len + n, W)
     eos_at_n = jnp.take_along_axis(
         draft, jnp.maximum(n - 1, 0)[:, None], axis=1)[:, 0] == 2
     full_reuse = (n == draft_len) & (n > 0) & eos_at_n if draft_eos_rows \

@@ -8,7 +8,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.decode_attention.ops import decode_attention
+from repro import kernels
+from repro.kernels.decode_attention.kernel import COUNTS
+from repro.kernels.decode_attention.ops import decode_attention, decode_plan
 from repro.kernels.decode_attention.ref import (decode_attention_blocked,
                                                 decode_attention_ref)
 
@@ -403,13 +405,74 @@ def test_qwen3_06b_decode_step_takes_256_slot_tiles():
                             jax.random.PRNGKey(0))
     caches = jax.eval_shape(lambda: M.init_cache(cfg, 8, 1280))
     tok = jax.ShapeDtypeStruct((8, 1), jnp.int32)
-    M.reset_op_counts()
+    COUNTS.clear()
     jax.eval_shape(lambda p, t, c: M.decode_step(p, cfg, t, t, c, 768),
                    params, tok, caches)
-    counts = dict(M.OP_COUNTS)
+    counts = COUNTS.copy()
     assert counts["decode_attn_tile_256"] == counts["decode_attn_inplace"]
     assert counts["decode_attn_tile_256"] > 0
     assert counts["decode_attn_tile_128"] == counts["decode_attn_tile_512"] == 0
+
+
+@pytest.mark.parametrize("impl,ref,want", [
+    ("auto", "naive", "naive"),
+    ("auto", "ref", "ref"),
+    ("pallas", "ref", "pallas"),
+    ("interpret", "naive", "interpret"),
+    ("blocked", "naive", "blocked"),
+])
+def test_resolve_off_the_tpu(impl, ref, want):
+    """Off a TPU, "auto" is the op's reference; a named impl is itself."""
+    assert kernels.resolve("decode_attention", impl, ref) == want
+
+
+# backend, requested impl, cache width, paged, mesh, latent -> the concrete
+# impl and whether the stacked cache is read in place
+@pytest.mark.parametrize("backend,impl,S,paged,mesh,latent,want", [
+    # TPU, GQA: the dense kernel in place on whole 128-slot tiles ...
+    ("tpu", "auto", 1280, False, False, False, ("pallas", True)),
+    # ... on a padded layer slice at a ragged width, or under a mesh
+    ("tpu", "auto", 202, False, False, False, ("pallas", False)),
+    ("tpu", "auto", 1280, False, True, False, ("pallas", False)),
+    # paged "auto": the gathered dense view, read by the dense kernel
+    ("tpu", "auto", 1280, True, False, False, ("pallas", False)),
+    # the paged kernel runs, in place, only when named
+    ("tpu", "pallas", 1280, True, False, False, ("pallas", True)),
+    ("tpu", "pallas", 1280, True, True, False, ("pallas", False)),
+    ("tpu", "naive", 1280, False, False, False, ("naive", False)),
+    ("tpu", "blocked", 1280, False, False, False, ("blocked", False)),
+    # TPU, MLA: the latent kernel only in place; jnp everywhere else
+    ("tpu", "auto", 1280, False, False, True, ("pallas", True)),
+    ("tpu", "auto", 300, False, False, True, ("naive", False)),
+    ("tpu", "auto", 1280, True, False, True, ("naive", False)),
+    ("tpu", "auto", 1280, False, True, True, ("naive", False)),
+    # CPU: "auto" is jnp, naive up to NAIVE_MAX_S slots, blocked beyond
+    ("cpu", "auto", 64, False, False, False, ("naive", False)),
+    ("cpu", "auto", 1280, False, False, False, ("blocked", False)),
+    ("cpu", "auto", 1280, True, False, False, ("blocked", False)),
+    ("cpu", "auto", 1280, False, False, True, ("naive", False)),
+    # CPU, a kernel named in interpret mode takes the TPU's paths
+    ("cpu", "interpret", 1280, False, False, False, ("interpret", True)),
+    ("cpu", "interpret", 202, False, False, False, ("interpret", False)),
+    ("cpu", "interpret", 64, True, False, False, ("interpret", True)),
+    ("cpu", "interpret", 64, False, True, False, ("interpret", False)),
+    ("cpu", "interpret", 256, False, False, True, ("interpret", True)),
+    ("cpu", "interpret", 300, False, False, True, ("naive", False)),
+    ("cpu", "blocked", 1280, False, False, True, ("naive", False)),
+])
+def test_decode_plan(monkeypatch, backend, impl, S, paged, mesh, latent,
+                     want):
+    """The decode dispatch's decision table, with the resolver patched to
+    stand for the backend; each call is counted on its path."""
+    real = kernels.resolve
+    monkeypatch.setattr(kernels, "resolve", lambda kernel, i, ref: (
+        "pallas" if i == "auto" and backend == "tpu"
+        else real(kernel, i, ref)))
+    COUNTS.clear()
+    assert decode_plan(impl, width=S, paged=paged, mesh=mesh,
+                       latent=latent) == want
+    path = "decode_attn_inplace" if want[1] else "decode_attn_sliced"
+    assert COUNTS == {path: 1}
 
 
 # ----------------------------------------------------------------- latent

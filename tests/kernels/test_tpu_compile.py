@@ -17,7 +17,7 @@ from repro.kernels.cache_gather.kernel import (cache_roll_pallas,
                                                paged_gather_pallas)
 from repro.kernels.cache_slot_write.kernel import cache_slot_write_pallas
 from repro.kernels.decode_attention.kernel import (
-    decode_attention_mla_pallas, decode_attention_pallas,
+    COUNTS, decode_attention_mla_pallas, decode_attention_pallas,
     paged_decode_attention_pallas)
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.rwkv6_wkv.kernel import wkv_pallas
@@ -147,15 +147,15 @@ def test_resume_decode_loop_moves_no_whole_cache(topo):
     caches = jax.tree.map(as_spec, jax.eval_shape(
         lambda: M.init_cache(cfg, 8, 1280)))
     vec = lambda dt: jax.ShapeDtypeStruct((8,), dt, sharding=one_chip)
-    M.reset_op_counts()
+    COUNTS.clear()
     compiled = resume_from_cache.lower(
         params, cfg, GenerateConfig(max_new_tokens=512), caches,
         jax.ShapeDtypeStruct((8, cfg.vocab_size), F32, sharding=one_chip),
         vec(I32), 768, jax.ShapeDtypeStruct((2,), jnp.uint32,
                                             sharding=one_chip),
         vec(jnp.bool_), vec(I32)).compile()
-    assert M.OP_COUNTS["decode_attn_tile_256"] == \
-        M.OP_COUNTS["decode_attn_inplace"] > 0
+    assert COUNTS["decode_attn_tile_256"] == \
+        COUNTS["decode_attn_inplace"] > 0
     hlo = compiled.as_text()
     assert "tpu_custom_call" in hlo
     lines = [ln for c in _loop_computations(hlo).values() for ln in c]

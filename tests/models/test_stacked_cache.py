@@ -2,14 +2,16 @@
 
 A layer writes its new K/V into its slot of the run's cache stack, and the
 flash-decode kernel reads its layer out of the stack; every other decode
-path reads one layer slice.  ``OP_COUNTS`` records which path each
-decode-attention call took, and both paths give the same bits."""
+path reads one layer slice.  The kernel package's ``COUNTS`` records
+which path each decode-attention call took, and both paths give the same
+bits."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from repro.kernels.decode_attention.kernel import COUNTS
 from repro.models import model as M
 from repro.models.attention import _cache_write
 from repro.models.config import ModelConfig
@@ -25,7 +27,7 @@ def _cfg(**kw):
 
 def _decode_once(cfg, S, mesh=None):
     """Prefill 6 tokens into an S-wide cache, then one decode step; returns
-    (OP_COUNTS of the step, logits, caches), run op by op so the counters
+    (COUNTS of the step, logits, caches), run op by op so the counters
     count every layer's call."""
     params = M.init_lm(jax.random.PRNGKey(0), cfg)
     prompt = jax.random.randint(jax.random.PRNGKey(1), (B, 6), 3, 32)
@@ -34,11 +36,11 @@ def _decode_once(cfg, S, mesh=None):
     _, caches = M.prefill(params, cfg, prompt, pos, caches)
     tok = jnp.full((B, 1), 5, jnp.int32)
     with jax.disable_jit():
-        M.reset_op_counts()
+        COUNTS.clear()
         logits, caches = M.decode_step(params, cfg, tok,
                                        jnp.full((B, 1), 6, jnp.int32),
                                        caches, 6, mesh=mesh)
-        counts = dict(M.OP_COUNTS)
+        counts = COUNTS.copy()
     return counts, logits, caches
 
 

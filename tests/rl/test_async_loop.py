@@ -44,8 +44,7 @@ def _make_trainer(algo="grpo"):
     rl = RLConfig(algo=algo, group_size=2, prompts_per_batch=4,
                   max_new_tokens=6, optim=AdamWConfig(lr=1e-3),
                   max_resample_rounds=1)
-    spec = SpecConfig(variant="spec", lenience=math.e ** 0.5,
-                      verify_impl="ref")
+    spec = SpecConfig(variant="spec", lenience=math.e ** 0.5)
     return Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(0))
 
 

@@ -38,7 +38,7 @@ def collected(tiny_cfg, tiny_params, tmp_path):
     tr = Tracer()
     configure(tracer=tr)
     try:
-        col = Collector(tiny_cfg, rl, SpecConfig(verify_impl="ref"),
+        col = Collector(tiny_cfg, rl, SpecConfig(),
                         PromptDataset(problems, max_prompt_len=10),
                         jax.random.PRNGKey(0))
         batch = col.sample(0)

@@ -25,8 +25,7 @@ def _make_trainer(algo, variant="spec", steps_cfg=None, seed=0,
     rl = RLConfig(algo=algo, group_size=2, prompts_per_batch=4,
                   max_new_tokens=6, optim=AdamWConfig(lr=1e-3),
                   max_resample_rounds=1, **(steps_cfg or {}))
-    spec = SpecConfig(variant=variant, lenience=math.e ** 0.5,
-                      verify_impl="ref")
+    spec = SpecConfig(variant=variant, lenience=math.e ** 0.5)
     return Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(seed))
 
 
@@ -84,7 +83,7 @@ def test_grpo_learns_single_digit_addition():
     rl = RLConfig(algo="grpo", group_size=8, prompts_per_batch=6,
                   max_new_tokens=4, optim=AdamWConfig(lr=4e-3),
                   temperature=1.0)
-    tr = Trainer(cfg, rl, SpecConfig(variant="spec", verify_impl="ref"), ds,
+    tr = Trainer(cfg, rl, SpecConfig(variant="spec"), ds,
                  jax.random.PRNGKey(0))
     rewards = [tr.train_step()["reward_mean"] for _ in range(30)]
     early = np.mean(rewards[:5])

@@ -3,7 +3,6 @@ through the continuous-batching engine are identical to fixed-batch
 ``generate`` / one-pass ``rollout`` — including speculative-prefix admission
 and the cache_slot_write admission path (ISSUE 2 acceptance criterion)."""
 import copy
-from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -70,7 +69,7 @@ def test_slot_engine_matches_fixed_batch_generate(setup):
 
 def _seeded_cache(cfg, params, prompt, mask):
     cache = RolloutCache()
-    spec = SpecConfig(variant="spec", verify_impl="ref", one_pass="off")
+    spec = SpecConfig(variant="spec", one_pass="off")
     gen = GenerateConfig(max_new_tokens=N)
     rollout(params, cfg, gen, spec, prompt, mask, list(range(B)), cache,
             jax.random.PRNGKey(0), 0)
@@ -88,18 +87,16 @@ def test_backfill_slots_matches_fixed_batch_rollout(setup, variant):
     cache1 = _seeded_cache(cfg, params_a, prompt, mask)
     if variant == "delayed":
         rollout(params_a, cfg, gen,
-                SpecConfig(variant="spec", verify_impl="ref", one_pass="off"),
+                SpecConfig(variant="spec", one_pass="off"),
                 prompt, mask, ids, cache1, jax.random.PRNGKey(5), 1)
     cache2 = copy.deepcopy(cache1)
 
     keys = _row_keys(9)
     fixed = rollout(params_b, cfg, gen,
-                    SpecConfig(variant=variant, verify_impl="ref",
-                               one_pass="on", compact_impl="ref"),
+                    SpecConfig(variant=variant, one_pass="on"),
                     prompt, mask, ids, cache1, keys, 2)
     slots = rollout(params_b, cfg, gen,
-                    SpecConfig(variant=variant, verify_impl="ref",
-                               one_pass="on", compact_impl="ref",
+                    SpecConfig(variant=variant, one_pass="on",
                                backfill="slots", backfill_slots=2),
                     prompt, mask, ids, cache2, keys, 2)
 
@@ -134,7 +131,8 @@ def test_backfill_slots_vanilla_cold_start(setup):
     assert slots.metrics["one_pass"] == 0.0
 
 
-def test_spec_prefix_admission_with_interpret_kernels(setup):
+def test_spec_prefix_admission_with_interpret_kernels(setup,
+                                                      interpret_kernels):
     """The Pallas admission kernels (interpret mode) on the real slot path:
     cache_slot_write + cache_gather produce the same responses as ref."""
     cfg, params_a, params_b, prompt, mask = setup
@@ -143,13 +141,13 @@ def test_spec_prefix_admission_with_interpret_kernels(setup):
     cache1 = _seeded_cache(cfg, params_a, prompt, mask)
     cache2 = copy.deepcopy(cache1)
     keys = _row_keys(13)
-    base = SpecConfig(variant="spec", verify_impl="ref", one_pass="on",
+    base = SpecConfig(variant="spec", one_pass="on",
                       backfill="slots", backfill_slots=2)
-    ref = rollout(params_b, cfg, gen, replace(base, compact_impl="ref"),
-                  prompt, mask, ids, cache1, keys, 1)
-    # interpret-mode compaction; slot writes go through the kernel wrapper
-    ker = rollout(params_b, cfg, gen, replace(base, compact_impl="interpret"),
-                  prompt, mask, ids, cache2, keys, 1)
+    ref = rollout(params_b, cfg, gen, base, prompt, mask, ids, cache1, keys,
+                  1)
+    with interpret_kernels("cache_gather", "cache_slot_write"):
+        ker = rollout(params_b, cfg, gen, base, prompt, mask, ids, cache2,
+                      keys, 1)
     np.testing.assert_array_equal(ker.response, ref.response)
     np.testing.assert_array_equal(ker.length, ref.length)
 
@@ -163,7 +161,7 @@ def test_write_cache_slots_exact(setup):
                                  positions_from_mask(mask[:2]),
                                  M.init_cache(cfg, 2, P + 4))
     slots = jnp.array([2, 0], jnp.int32)
-    out = M.write_cache_slots(cfg, caches_a, caches_b, slots, impl="ref")
+    out = M.write_cache_slots(cfg, caches_a, caches_b, slots)
     for run_out, run_a, run_b in zip(out, caches_a, caches_b):
         for name in run_out["self"]:
             o = np.asarray(run_out["self"][name])

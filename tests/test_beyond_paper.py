@@ -1,7 +1,6 @@
-"""Beyond-paper features: adaptive lenience, use_pallas model paths,
-trainer checkpoint/resume with a warm rollout cache."""
+"""Beyond-paper features: adaptive lenience, trainer checkpoint/resume
+with a warm rollout cache."""
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -10,7 +9,6 @@ from repro.core.lenience import (AdaptiveLenience, FixedLenience,
                                  LinearWarmupLenience, make_schedule)
 from repro.data.dataset import PromptDataset
 from repro.data.tokenizer import VOCAB_SIZE
-from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.optim.adamw import AdamWConfig
 from repro.rewards.mathgen import MathTaskConfig, generate_problems
@@ -52,32 +50,12 @@ def test_trainer_with_adaptive_lenience():
                        max_prompt_len=10)
     rl = RLConfig(algo="grpo", group_size=2, prompts_per_batch=4,
                   max_new_tokens=6, optim=AdamWConfig(lr=2e-3))
-    tr = Trainer(cfg, rl, SpecConfig(variant="spec", verify_impl="ref"), ds,
+    tr = Trainer(cfg, rl, SpecConfig(variant="spec"), ds,
                  jax.random.PRNGKey(0),
                  lenience_schedule=AdaptiveLenience(init=1.0, budget=0.05,
                                                     gain=5.0))
     ls = [tr.train_step()["lenience"] for _ in range(3)]
     assert ls[-1] != ls[0]          # the controller moved lenience
-
-
-# ------------------------------------------------------------------ pallas paths
-
-
-@pytest.mark.parametrize("family_kw", [
-    dict(num_heads=4, num_kv_heads=2),                       # gqa + flash
-    dict(num_heads=0, num_kv_heads=0, block_kind="rwkv",
-         rwkv_head_dim=16),                                  # rwkv + wkv
-])
-def test_use_pallas_matches_jnp(family_kw):
-    cfg = ModelConfig(name="p", num_layers=2, d_model=64, d_ff=128,
-                      vocab_size=64, **family_kw)
-    params = M.init_lm(jax.random.PRNGKey(0), cfg)
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 24), 3, 64)
-    pos = jnp.broadcast_to(jnp.arange(24, dtype=jnp.int32), (2, 24))
-    pos = pos.at[0, :4].set(-1)
-    a, _ = M.forward(params, cfg, tokens, pos)
-    b, _ = M.forward(params, cfg, tokens, pos, use_pallas=True)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
 
 
 # ------------------------------------------------------------------ resume
@@ -96,7 +74,7 @@ def test_trainer_checkpoint_resume(tmp_path):
                        max_prompt_len=10)
     rl = RLConfig(algo="grpo", group_size=2, prompts_per_batch=3,
                   max_new_tokens=6, optim=AdamWConfig(lr=1e-3))
-    spec = SpecConfig(variant="spec", verify_impl="ref")
+    spec = SpecConfig(variant="spec")
     tr = Trainer(cfg, rl, spec, ds, jax.random.PRNGKey(0))
     for _ in range(2):
         tr.train_step()
